@@ -1,6 +1,6 @@
-"""Kernel microbenchmarks: us/call of the Pallas kernels (interpret mode on
-CPU — correctness-path timing; TPU wall-times come from the roofline
-analysis) and their jnp oracles.
+"""Kernel microbenchmarks: us/call of the Pallas kernels in interpret mode
+(``interpret=True`` on every call — a correctness-path timing, never a
+device figure) and their jnp oracles.
 
 The refinement-scan rows are the PR-5 tentpole's A/B: the serial
 per-event admission loop vs the set-segmented parallel scan (lane-packed
@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.kernels import (auction_topk2, auction_topk2_ref, cosine_topk,
                            cosine_topk_ref, refine_events, ssd, ssd_ref)
+from repro.runtime.compile_cache import enable_compile_cache
 
 from .common import csv_line
 
@@ -84,7 +85,8 @@ def _refinement_rows():
         def kernel_chain(state=state, s3=s3, q3=q3, sl3=sl3, si3=si3):
             st = state
             for c in range(s3.shape[0]):
-                out = refine_events(st, s3[c], q3[c], sl3[c], si3[c])
+                out = refine_events(st, s3[c], q3[c], sl3[c], si3[c],
+                                    interpret=True)
                 st = out[:5] + (st[5],) + out[5:]
             return st
 
@@ -98,6 +100,7 @@ def main(argv=None):
     ap.add_argument("--json", default="BENCH_kernels.json",
                     help="perf-artifact path ('' disables)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     rows = []
 
@@ -106,7 +109,8 @@ def main(argv=None):
     qe /= np.linalg.norm(qe, axis=1, keepdims=True)
     ev /= np.linalg.norm(ev, axis=1, keepdims=True)
     rows.append(("cosine_topk_interp",
-                 _time(lambda: cosine_topk(qe, ev, k=16, bv=256)),
+                 _time(lambda: cosine_topk(qe, ev, k=16, bv=256,
+                                           interpret=True)),
                  "nq=16 nv=2048 d=64 k=16"))
     rows.append(("cosine_topk_ref",
                  _time(lambda: cosine_topk_ref(jnp.asarray(qe),
@@ -116,7 +120,8 @@ def main(argv=None):
     wm = rng.random((256, 512)).astype(np.float32)
     pr = rng.random(512).astype(np.float32)
     rows.append(("auction_topk2_interp",
-                 _time(lambda: auction_topk2(wm, pr, bn=128)),
+                 _time(lambda: auction_topk2(wm, pr, bn=128,
+                                             interpret=True)),
                  "n=256 m=512"))
     rows.append(("auction_topk2_ref",
                  _time(lambda: auction_topk2_ref(jnp.asarray(wm),
@@ -131,7 +136,8 @@ def main(argv=None):
     C = (rng.normal(size=(Bt, L, G, S)) / 4).astype(np.float32)
     D = rng.normal(size=H).astype(np.float32)
     rows.append(("ssd_interp",
-                 _time(lambda: ssd(x, dt, A, B, C, D, chunk=16)),
+                 _time(lambda: ssd(x, dt, A, B, C, D, chunk=16,
+                                   interpret=True)),
                  f"B={Bt} L={L} H={H} P={P} S={S}"))
     rows.append(("ssd_ref",
                  _time(lambda: ssd_ref(jnp.asarray(x[0]), jnp.asarray(dt[0]),

@@ -7,6 +7,7 @@ import numpy as np
 
 from repro.core import KoiosSearch, SearchParams
 from repro.data import sample_queries
+from repro.runtime.compile_cache import enable_compile_cache
 
 from .common import memory_footprint_bytes, timed, world
 
@@ -46,6 +47,7 @@ def run(dataset="opendata", n_queries=2,
 
 
 def main():
+    enable_compile_cache()
     res = run()
     for key, rows in res.items():
         for r in rows:

@@ -11,6 +11,7 @@ import numpy as np
 
 from repro.core import SearchParams, search_partition
 from repro.data import sample_queries
+from repro.runtime.compile_cache import enable_compile_cache
 
 from .common import index_for, world
 
@@ -59,6 +60,7 @@ def run(datasets=("dblp", "opendata", "twitter", "wdc"), n_queries=3,
 
 
 def main():
+    enable_compile_cache()
     print("dataset,interval,candidates,iUB%,No-EM,EM-early,EM,verified%")
     for r in run():
         print(f"{r['dataset']},{r['interval']},{r['candidates']:.0f},"

@@ -9,6 +9,7 @@ import numpy as np
 
 from repro.core import SearchParams, search_partition
 from repro.data import sample_queries
+from repro.runtime.compile_cache import enable_compile_cache
 
 from .common import index_for, world
 
@@ -49,6 +50,7 @@ def run(datasets=("dblp", "opendata"), n_queries=2, k=10, alpha=0.8):
 
 
 def main():
+    enable_compile_cache()
     print("dataset,query,|Q|,kth_semantic,kth_vanilla,intersection,"
           "semantic_gain")
     for r in run():

@@ -61,6 +61,7 @@ from repro.core import (NGramJaccardSimilarity, SearchParams,
                         baseline_plus_topk, baseline_topk, search_partition,
                         search_partition_batch)
 from repro.data import sample_queries
+from repro.runtime.compile_cache import enable_compile_cache
 
 from .common import index_for, memory_footprint_bytes, timed, world
 
@@ -216,22 +217,19 @@ def result_hash(results) -> str:
 
 
 def run_fused_ab(dataset="opendata", partitions=4, batch_size=8, k=10,
-                 alpha=0.8, verifier="hungarian", repeats=7):
+                 alpha=0.8, verifier="hungarian", repeats=7, fused="auto"):
     """Fused on-device wave schedule vs host-driven overlap at P partitions.
 
     Both arms run the identical plan decomposition; the A/B isolates what
     the wave program eliminates — per-tile refinement dispatch +
     materialization and the first R rounds' pairwise/solver round-trips.
     Host<->device dispatches and transfers are counted via
-    ``repro.runtime.instrument``; results are asserted bit-identical."""
-    import jax
-
+    ``repro.runtime.instrument``; results are asserted bit-identical.
+    ``fused='interpret'`` runs the wave off the chip."""
     from repro.core import KoiosSearch
     from repro.runtime import instrument
 
-    fused_mode = "auto" if jax.default_backend() == "tpu" else "interpret"
-    params = SearchParams(k=k, alpha=alpha, verifier=verifier,
-                          fused=fused_mode)
+    params = SearchParams(k=k, alpha=alpha, verifier=verifier, fused=fused)
     coll, sim = world(dataset)
     engine = KoiosSearch(coll, sim, params, partitions=partitions)
     queries = sample_queries(coll, batch_size, seed=11)
@@ -274,7 +272,7 @@ def run_fused_ab(dataset="opendata", partitions=4, batch_size=8, k=10,
 
 def run_sharded_ab(dataset="opendata", shards=4, batch_size=8, k=10,
                    alpha=0.8, verifier="hungarian", repeats=3,
-                   place=False):
+                   place=False, fused="auto"):
     """Sharded collection resource vs the 1-shard reference repository.
 
     Builds the SAME logical repository twice as a
@@ -292,9 +290,7 @@ def run_sharded_ab(dataset="opendata", shards=4, batch_size=8, k=10,
     from repro.runtime import instrument
     from repro.runtime.collection import ShardedCollection
 
-    fused_mode = "auto" if jax.default_backend() == "tpu" else "interpret"
-    params = SearchParams(k=k, alpha=alpha, verifier=verifier,
-                          fused=fused_mode)
+    params = SearchParams(k=k, alpha=alpha, verifier=verifier, fused=fused)
     coll, sim = world(dataset)
     devices = jax.devices() if place else None
     reference = KoiosSearch(None, sim, params,
@@ -499,8 +495,8 @@ def main(argv=None):
                            "sequential partition loop (use --partitions)")
     mode.add_argument("--fused", action="store_true",
                       help="A/B the fused on-device wave schedule vs the "
-                           "overlap schedule (use --partitions; interpret "
-                           "mode off-TPU)")
+                           "overlap schedule (use --partitions; off the "
+                           "chip add --interpret)")
     mode.add_argument("--engine", action="store_true",
                       help="A/B the continuous-batching request engine vs "
                            "the per-batch serving loop under a staggered-"
@@ -533,15 +529,20 @@ def main(argv=None):
     ap.add_argument("--verifier", default="hungarian",
                     choices=["hungarian", "auction", "hybrid"],
                     help="A/B modes only")
+    ap.add_argument("--interpret", action="store_true",
+                    help="--fused/--sharded A/Bs: run the wave programs "
+                         "off the chip, Pallas kernels in interpret mode")
     ap.add_argument("--json", default="BENCH_response_time.json",
                     help="perf-artifact path for A/B modes ('' disables)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
+    fused = "interpret" if args.interpret else "auto"
 
     if args.sharded or args.shards is not None:
         r = run_sharded_ab(args.dataset or "opendata",
                            args.shards or 4, args.batch_size,
                            k=args.k, verifier=args.verifier,
-                           place=args.place)
+                           place=args.place, fused=fused)
         print("dataset,arm,shards,devices,batch_size,"
               "mean_latency_per_query_s,speedup_vs_one_shard,"
               "transfers,result_hash,identical_topk")
@@ -587,7 +588,7 @@ def main(argv=None):
     if args.fused:
         r = run_fused_ab(args.dataset or "opendata", args.partitions,
                          args.batch_size, k=args.k,
-                         verifier=args.verifier)
+                         verifier=args.verifier, fused=fused)
         print("dataset,schedule,partitions,batch_size,"
               "mean_latency_per_query_s,speedup_vs_overlap,"
               "transfers,waves,device_rounds,result_hash,identical_topk")

@@ -8,6 +8,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.runtime.compile_cache import enable_compile_cache
+
 
 def _banner(name):
     print(f"\n===== {name} " + "=" * max(0, 60 - len(name)), flush=True)
@@ -19,7 +21,12 @@ def main(argv=None) -> None:
                     choices=[None, "pruning", "response", "parameters",
                              "quality", "kernels", "roofline", "soak"])
     ap.add_argument("--fast", action="store_true")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the fused-wave A/Bs off the chip, Pallas "
+                         "kernels in interpret mode")
     args = ap.parse_args(argv)
+    enable_compile_cache()
+    fused = "interpret" if args.interpret else "auto"
 
     t0 = time.time()
     want = lambda n: args.only in (None, n)   # noqa: E731
@@ -69,7 +76,7 @@ def main(argv=None) -> None:
         print("dataset,partitions,overlap_s,fused_s,speedup,"
               "overlap_transfers,fused_transfers,result_hash")
         rf = response_time.run_fused_ab(
-            partitions=4, batch_size=4 if args.fast else 8)
+            partitions=4, batch_size=4 if args.fast else 8, fused=fused)
         print(f"{rf['dataset']},{rf['partitions']},{rf['overlap_s']:.4f},"
               f"{rf['fused_s']:.4f},{rf['speedup']:.2f},"
               f"{rf['overlap_transfers']},{rf['fused_transfers']},"
@@ -89,7 +96,7 @@ def main(argv=None) -> None:
         print("dataset,shards,devices,one_shard_s,sharded_s,speedup,"
               "result_hash")
         rs = response_time.run_sharded_ab(
-            shards=4, batch_size=4 if args.fast else 8)
+            shards=4, batch_size=4 if args.fast else 8, fused=fused)
         print(f"{rs['dataset']},{rs['shards']},{rs['devices']},"
               f"{rs['one_shard_s']:.4f},{rs['sharded_s']:.4f},"
               f"{rs['speedup']:.2f},{rs['result_hash']}")

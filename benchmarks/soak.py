@@ -50,6 +50,7 @@ from repro.core import KoiosSearch, SearchParams
 from repro.data import sample_queries
 from repro.runtime import instrument
 from repro.runtime.collection import ShardedCollection
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.engine import AdmissionRouter, RouterPolicy
 from repro.runtime.fault import FaultEvent, FaultPlan
 
@@ -362,6 +363,7 @@ def main(argv=None):
                     help="trim the trace for CI smoke (~20s)")
     ap.add_argument("--json", default="BENCH_soak.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     n = 24 if args.fast else args.requests
 
     print("leg,requests,p50_s,p99_s,shed_rate,retries,quarantines,"

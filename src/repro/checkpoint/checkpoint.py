@@ -1,4 +1,4 @@
-"""Pytree checkpointing: msgpack (+ optional zstd), atomic, async-capable.
+"""Pytree checkpointing: zstd-compressed msgpack, atomic, async-capable.
 
 Layout-agnostic: arrays are serialized host-side (device_get) with dtype
 (incl. bfloat16 via ml_dtypes) and shape; restore returns numpy arrays that
@@ -6,10 +6,9 @@ Layout-agnostic: arrays are serialized host-side (device_get) with dtype
 uses — this is what makes elastic re-mesh restarts work (runtime/fault.py):
 a checkpoint written on a (2,16,16) mesh restores onto any other mesh.
 
-``zstandard`` is an optional dependency (requirements-dev.txt): when absent,
-checkpoints are written as raw msgpack.  ``restore`` sniffs the zstd frame
-magic, so either codec restores on any host that can decode it; the codec
-used is recorded in the checkpoint metadata by ``CheckpointManager``."""
+Checkpoints are written zstd-compressed.  ``restore`` sniffs the zstd
+frame magic, so raw-msgpack checkpoints written by older versions still
+restore."""
 from __future__ import annotations
 
 import io
@@ -19,24 +18,14 @@ import threading
 from concurrent.futures import ThreadPoolExecutor, Future
 from typing import Any, Optional
 
+import jax
 import msgpack
 import numpy as np
-
-try:
-    import zstandard as zstd
-except ImportError:  # pragma: no cover — exercised in the seed environment
-    zstd = None
-
-import jax
+import zstandard as zstd
 
 # First bytes of every zstd frame (RFC 8878) — msgpack maps never start
 # with this, so the on-disk codec is sniffable without a side channel.
 _ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"
-
-
-def default_codec() -> str:
-    """Codec ``save`` will use on this host (recorded in ckpt metadata)."""
-    return "zstd" if zstd is not None else "raw"
 
 try:
     import ml_dtypes
@@ -94,15 +83,10 @@ def _to_host(x):
 
 
 def save(path: str, tree: Any, *, level: int = 3) -> None:
-    """Atomic synchronous save (tmp file + rename).
-
-    Compresses with zstd when available, else writes raw msgpack."""
+    """Atomic synchronous save (tmp file + rename), zstd-compressed."""
     host_tree = jax.tree_util.tree_map(_to_host, tree)
     payload = msgpack.packb(_pack(host_tree), use_bin_type=True)
-    if zstd is not None:
-        comp = zstd.ZstdCompressor(level=level).compress(payload)
-    else:
-        comp = payload
+    comp = zstd.ZstdCompressor(level=level).compress(payload)
     d = os.path.dirname(os.path.abspath(path))
     os.makedirs(d, exist_ok=True)
     fd, tmp = tempfile.mkstemp(dir=d, suffix=".tmp")
@@ -119,10 +103,6 @@ def restore(path: str) -> Any:
     with open(path, "rb") as f:
         comp = f.read()
     if comp[:4] == _ZSTD_MAGIC:
-        if zstd is None:
-            raise RuntimeError(
-                f"{path} is zstd-compressed but zstandard is not installed "
-                "(pip install zstandard, see requirements-dev.txt)")
         payload = zstd.ZstdDecompressor().decompress(comp)
     else:
         payload = comp

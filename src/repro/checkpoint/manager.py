@@ -7,10 +7,10 @@ import os
 import re
 from typing import Any, Optional
 
-from .checkpoint import AsyncSaver, default_codec, restore, save
+from .checkpoint import AsyncSaver, restore, save
 
-# suffix reflects the on-disk codec: .zst when zstd-compressed, .msgpack
-# when written raw (zstandard absent); both are discovered and restored
+# new checkpoints are .zst; .msgpack (raw, written by older versions) is
+# still discovered and restored
 _PAT = re.compile(r"ckpt_(\d+)\.(zst|msgpack)$")
 _SUFFIXES = ("zst", "msgpack")
 
@@ -24,8 +24,7 @@ class CheckpointManager:
 
     def _path(self, step: int) -> str:
         """Path a new checkpoint for ``step`` will be written to."""
-        suffix = "zst" if default_codec() == "zstd" else "msgpack"
-        return os.path.join(self.dir, f"ckpt_{step:09d}.{suffix}")
+        return os.path.join(self.dir, f"ckpt_{step:09d}.zst")
 
     def _step_paths(self, step: int):
         """Existing checkpoint files for ``step`` (any codec)."""
@@ -36,8 +35,8 @@ class CheckpointManager:
     def _find_path(self, step: int):
         """Checkpoint file to restore for ``step``.
 
-        A directory can hold the same step under both codecs (run moved
-        between hosts with/without zstandard); the newest write wins."""
+        A directory can hold the same step under both codecs (a raw one
+        from an older version); the newest write wins."""
         paths = self._step_paths(step)
         if not paths:
             return None
@@ -58,7 +57,7 @@ class CheckpointManager:
     def save(self, step: int, tree: Any, metadata: Optional[dict] = None):
         meta = dict(metadata or {})
         meta["step"] = step
-        meta.setdefault("codec", default_codec())
+        meta.setdefault("codec", "zstd")
         payload = {"meta": meta, "state": tree}
         if self._saver is not None:
             self._saver.save(self._path(step), payload)

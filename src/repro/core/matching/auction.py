@@ -46,7 +46,7 @@ class AuctionResult:
 
 
 def _auction_single(w, nq, nc, eps_schedule, theta_lb, max_rounds,
-                    use_kernel: bool = False):
+                    use_kernel: bool = False, interpret: bool = False):
     """One padded weight matrix (N, M); logical sizes (nq, nc) <= (N, M).
 
     The problem is embedded in the K x K zero-padded square matrix
@@ -157,7 +157,8 @@ def _auction_single(w, nq, nc, eps_schedule, theta_lb, max_rounds,
                 # the (K, K) profit matrix never materializes in HBM.  Same
                 # first-index tie-breaking as the inline pass below.
                 from ...kernels import ops as _kops
-                w1, w2, jstar = _kops.auction_topk2(wm, prices)
+                w1, w2, jstar = _kops.auction_topk2(wm, prices,
+                                                    interpret=interpret)
             else:
                 profits = wm - prices[None, :]
                 w1 = jnp.max(profits, axis=1)
@@ -234,9 +235,10 @@ def make_eps_schedule(eps_min: float, eps_start: float = 0.25,
     return jnp.asarray(eps, dtype=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("max_rounds", "use_kernel"))
+@functools.partial(jax.jit,
+                   static_argnames=("max_rounds", "use_kernel", "interpret"))
 def auction_batch(w, nq, nc, eps_schedule, theta_lb, max_rounds: int = 5000,
-                  use_kernel: bool = False):
+                  use_kernel: bool = False, interpret: bool = False):
     """Batched verification.
 
     Args:
@@ -250,6 +252,7 @@ def auction_batch(w, nq, nc, eps_schedule, theta_lb, max_rounds: int = 5000,
         kernel (``kernels/auction_round.py``) — the TPU serving/fused-wave
         path; the default inline jnp pass is the same math (guarded by a
         parity test) and faster under CPU interpret mode.
+      interpret: run that kernel in Pallas interpret mode (tests off-TPU).
     Returns :class:`AuctionResult` of per-element score brackets.
     """
     theta = jnp.broadcast_to(
@@ -257,7 +260,7 @@ def auction_batch(w, nq, nc, eps_schedule, theta_lb, max_rounds: int = 5000,
     fn = jax.vmap(
         lambda wi, nqi, nci, ti: _auction_single(
             wi, nqi, nci, eps_schedule, ti, max_rounds,
-            use_kernel=use_kernel))
+            use_kernel=use_kernel, interpret=interpret))
     lb, ub, assign, early, rounds = fn(w, nq, nc, theta)
     return AuctionResult(lb=lb, ub=ub, assign=assign,
                          early_stopped=early, rounds=rounds)

@@ -58,7 +58,8 @@ def _build_streams(plan: "ExecutionPlan", sim, params: SearchParams,
         assert len(streams) == len(plan.queries)
         return streams
     return build_token_stream_batch(plan.queries, sim, params.alpha,
-                                    use_kernel=params.stream_use_kernel)
+                                    use_kernel=params.stream_use_kernel,
+                                    interpret=params.interpret)
 
 
 @dataclasses.dataclass
@@ -252,17 +253,16 @@ def _finish_tile(tile: _Tile, id_offset: int) -> None:
 def run_plan(plan: ExecutionPlan, sim_provider, params: SearchParams,
              schedule: str = "overlap",
              bound_exchange: Optional[Callable] = None,
-             mesh=None, streams=None) -> List[List[SearchResult]]:
+             streams=None) -> List[List[SearchResult]]:
     """Drive every tile of ``plan`` to completion; returns per-query lists
     of per-partition results (partition order), ids already globalized.
 
-    ``schedule='fused'`` resolves to the on-device wave pipeline where it
-    can run (TPU backend, or interpret mode when ``params.fused ==
-    'interpret'``, with a dense cosine provider — see
-    ``core.wave.fused_available``) and falls back to ``overlap``
-    elsewhere; all three schedules return bit-identical exact results.
-    ``mesh`` plugs the repository-shard mesh into the fused program's
-    on-device bound exchange (DESIGN.md §5).  ``streams`` optionally
+    ``schedule='fused'`` runs the on-device wave pipeline on a TPU
+    backend (or anywhere with ``params.fused == 'interpret'``) and
+    resolves to ``overlap`` for ``fused='off'`` or ``'auto'`` off-TPU;
+    a fused request the wave cannot serve raises (see
+    ``core.wave.fused_available``).  All three schedules return
+    bit-identical exact results.  ``streams`` optionally
     supplies precomputed per-query token streams (the stream-cache path,
     DESIGN.md §3.2) instead of building them here."""
     if schedule == "fused":
@@ -271,7 +271,7 @@ def run_plan(plan: ExecutionPlan, sim_provider, params: SearchParams,
             schedule = "overlap"
     plan.stats.schedule = schedule
     if schedule == "fused":
-        _run_fused(plan, sim_provider, params, bound_exchange, mesh,
+        _run_fused(plan, sim_provider, params, bound_exchange,
                    streams=streams)
     elif schedule == "overlap":
         _run_overlapped(plan, sim_provider, params, bound_exchange,
@@ -449,7 +449,7 @@ def run_fused_wave(plan: ExecutionPlan, tiles: Sequence[_Tile], streams,
 
 
 def _run_fused(plan: ExecutionPlan, sim, params: SearchParams,
-               bound_exchange: Optional[Callable], mesh=None,
+               bound_exchange: Optional[Callable],
                streams=None) -> None:
     """On-device wave pipeline (DESIGN.md §3): one device program per
     partition wave — refinement chunk scans, candidate compaction,
@@ -461,7 +461,7 @@ def _run_fused(plan: ExecutionPlan, sim, params: SearchParams,
     from .wave import _pow2, wave_runner_for
 
     streams = _build_streams(plan, sim, params, streams)
-    runner = wave_runner_for(sim, params, mesh=mesh)
+    runner = wave_runner_for(sim, params)
     B_pad = _pow2(max(1, len(plan.queries)))
     theta_dev = runner.init_theta(plan.theta0, B_pad)
     # ONE host->device payload for the whole plan: the compact stream

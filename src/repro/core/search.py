@@ -248,15 +248,14 @@ class KoiosSearch:
     """Public search API over a (possibly partitioned) repository.
 
     ``schedule`` selects the default drive order of the partition
-    scheduler: 'fused' (default — the on-device wave pipeline where it
-    can run, resolving to 'overlap' off-TPU unless ``params.fused ==
+    scheduler: 'fused' (default — the on-device wave pipeline on TPU,
+    resolving to 'overlap' off-TPU unless ``params.fused ==
     'interpret'``), 'overlap', or 'sequential'; all are exact and
     bit-identical.  ``partition_by`` picks the repository split:
     'sets' (equal set counts) or 'tokens' (greedy token-count balance —
     see :func:`partition_ranges`).  ``bound_exchange`` optionally plugs a
     mesh all-reduce-max into the per-round theta_lb exchange (see
-    ``repro.runtime.sharding.all_reduce_max``); ``mesh`` additionally
-    moves the fused schedule's exchange on-device.  ``scheduler_stats``
+    ``repro.runtime.sharding.all_reduce_max``).  ``scheduler_stats``
     holds the :class:`SchedulerStats` of the most recent call.
     ``stream_cache`` optionally plugs a
     :class:`~repro.core.token_stream.TokenStreamCache` into the one-shot
@@ -279,7 +278,7 @@ class KoiosSearch:
                  params: Optional[SearchParams] = None,
                  partitions: int = 1, schedule: str = "fused",
                  bound_exchange: Optional[Callable] = None,
-                 partition_by: str = "sets", mesh=None,
+                 partition_by: str = "sets",
                  stream_cache=None, collection=None):
         from ..runtime.collection import ShardedCollection
 
@@ -291,7 +290,6 @@ class KoiosSearch:
         self.collection = collection
         self.schedule = schedule
         self.bound_exchange = bound_exchange
-        self.mesh = mesh
         self.stream_cache = stream_cache
         self.scheduler_stats: Optional[SchedulerStats] = None
 
@@ -340,13 +338,14 @@ class KoiosSearch:
                 self.stream_cache.set_epoch(epoch.epoch)
                 streams = build_token_stream_batch_cached(
                     queries, self.sim, params.alpha, self.stream_cache,
-                    use_kernel=params.stream_use_kernel)
+                    use_kernel=params.stream_use_kernel,
+                    interpret=params.interpret)
             plan = ExecutionPlan(epoch.shards, queries,
                                  pool_coll=epoch.coll, epoch=epoch.epoch)
             per_query = run_plan(plan, self.sim, params,
                                  schedule=schedule or self.schedule,
                                  bound_exchange=self.bound_exchange,
-                                 mesh=self.mesh, streams=streams)
+                                 streams=streams)
             self.scheduler_stats = plan.stats
         finally:
             self.collection.release(epoch)

@@ -24,8 +24,6 @@ even when their vectors are degenerate.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,10 +34,21 @@ def _l2_normalize(x: jnp.ndarray, eps: float = 1e-12) -> jnp.ndarray:
     return x / jnp.maximum(n, eps)
 
 
-@functools.partial(jax.jit, static_argnames=())
-def _cosine_block(qv: jnp.ndarray, tv: jnp.ndarray) -> jnp.ndarray:
-    s = _l2_normalize(qv) @ _l2_normalize(tv).T
+def cosine_rows(qn: jnp.ndarray, tn: jnp.ndarray) -> jnp.ndarray:
+    """(m, d) x (n, d) L2-normalized rows -> (m, n) cosines in [0, 1].
+
+    The one similarity contraction of the system: the stream sweep, the
+    host verifier and the fused wave's device rounds all call it, so they
+    agree entry for entry.  Precision is pinned to HIGHEST because a
+    default float32 matmul on TPU runs a single bf16 pass, which moves
+    pairs across alpha and changes verification weights."""
+    s = jax.lax.dot_general(qn, tn, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
     return jnp.clip(s, 0.0, 1.0)
+
+
+_cosine_block = jax.jit(cosine_rows)
 
 
 @jax.jit
@@ -64,10 +73,8 @@ class EmbeddingSimilarity:
     @property
     def normalized_table(self) -> jnp.ndarray:
         """Row-L2-normalized table, computed once and kept device-resident
-        (the fused wave program and the kernel stream path gather from it
-        every call).  Row-wise normalization is subset-invariant, so
-        entries gathered from this table match the per-call
-        ``_cosine_block`` normalization bit for bit."""
+        (every similarity — stream sweep, host verifier, fused wave —
+        gathers its rows from it)."""
         t = getattr(self, "_table_n", None)
         if t is None:
             t = _l2_normalize(self.table)
@@ -81,13 +88,15 @@ class EmbeddingSimilarity:
     def pairwise(self, q_ids: np.ndarray, t_ids: np.ndarray) -> jnp.ndarray:
         q_ids = jnp.asarray(q_ids)
         t_ids = jnp.asarray(t_ids)
-        s = _cosine_block(self.table[q_ids], self.table[t_ids])
+        tn = self.normalized_table
+        s = _cosine_block(tn[q_ids], tn[t_ids])
         return self._fix_identity(s, q_ids, t_ids)
 
     def query_vs_vocab_block(self, q_ids: np.ndarray, lo: int, hi: int) -> jnp.ndarray:
         q_ids = jnp.asarray(q_ids)
         t_ids = jnp.arange(lo, hi)
-        s = _cosine_block(self.table[q_ids], self.table[lo:hi])
+        tn = self.normalized_table
+        s = _cosine_block(tn[q_ids], tn[lo:hi])
         return self._fix_identity(s, q_ids, t_ids)
 
 

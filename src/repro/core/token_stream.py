@@ -105,7 +105,8 @@ def _finalize_stream(query: np.ndarray, q_pos: np.ndarray, token: np.ndarray,
 
 
 def _build_stream_entries_kernel(stacked: np.ndarray, sim_provider,
-                                 alpha: float, block_size: int):
+                                 alpha: float, block_size: int,
+                                 interpret: bool):
     """(row, token, sim >= alpha) triples via the ``cosine_topk`` Pallas
     kernel (DESIGN.md §7) instead of the jnp provider sweep.
 
@@ -141,7 +142,7 @@ def _build_stream_entries_kernel(stacked: np.ndarray, sim_provider,
     while True:
         instrument.record("h2d:stream_kernel_dispatch")
         instrument.record("d2h:stream_materialize")
-        vals, idx = kops.cosine_topk(qe, table_n, k=k)
+        vals, idx = kops.cosine_topk(qe, table_n, k=k, interpret=interpret)
         vals = np.asarray(vals)[:len(stacked)]
         idx = np.asarray(idx)[:len(stacked)]
         if k == vocab or float(vals[:, -1].max()) < alpha:
@@ -172,7 +173,8 @@ def _build_stream_entries_kernel(stacked: np.ndarray, sim_provider,
 
 def build_token_stream_batch(queries, sim_provider, alpha: float,
                              block_size: int = 4096,
-                             use_kernel: bool = False) -> "list[TokenStream]":
+                             use_kernel: bool = False,
+                             interpret: bool = False) -> "list[TokenStream]":
     """Token streams for B queries from ONE blocked similarity sweep.
 
     The queries are stacked into a single (sum |Q_b|, |V|-block) similarity
@@ -188,6 +190,9 @@ def build_token_stream_batch(queries, sim_provider, alpha: float,
     (paper §V: a query element is returned for itself on first probe — this
     initialises bounds with the vanilla overlap and covers out-of-vocabulary
     elements).
+
+    ``use_kernel`` sweeps with the ``cosine_topk`` Pallas kernel instead
+    of the provider; ``interpret`` runs that kernel in interpret mode.
     """
     queries = [np.asarray(q, dtype=np.int32) for q in queries]
     if not queries:
@@ -203,7 +208,7 @@ def build_token_stream_batch(queries, sim_provider, alpha: float,
     # provider sweep — same gate as the fused schedule's
     if use_kernel and getattr(sim_provider, "name", None) == "cosine":
         q_rows, token, sim = _build_stream_entries_kernel(
-            stacked, sim_provider, alpha, block_size)
+            stacked, sim_provider, alpha, block_size, interpret)
         out = []
         for b, query in enumerate(queries):
             m = (q_rows >= bounds[b]) & (q_rows < bounds[b + 1])
@@ -363,7 +368,8 @@ class TokenStreamCache:
 def build_token_stream_batch_cached(queries, sim_provider, alpha: float,
                                     cache: TokenStreamCache,
                                     block_size: int = 4096,
-                                    use_kernel: bool = False
+                                    use_kernel: bool = False,
+                                    interpret: bool = False
                                     ) -> "list[TokenStream]":
     """Cache-aware :func:`build_token_stream_batch`: hits skip the sweep,
     misses build in ONE stacked sweep and populate the cache.
@@ -394,7 +400,8 @@ def build_token_stream_batch_cached(queries, sim_provider, alpha: float,
     if build_idx:
         built = build_token_stream_batch(
             [queries[i] for i in build_idx], sim_provider, alpha,
-            block_size=block_size, use_kernel=use_kernel)
+            block_size=block_size, use_kernel=use_kernel,
+            interpret=interpret)
         for i, stream in zip(build_idx, built):
             cache.put(keys[i], stream)
             out[i] = stream

@@ -182,15 +182,16 @@ class SearchParams:
     # report exact SO for the returned top-k (extra verifications)
     exact_scores: bool = True
     # --- fused wave execution (DESIGN.md §3) ---
-    # 'auto' = run the fused schedule on TPU, fall back to overlap
-    # elsewhere; 'interpret' = force the fused wave program off-TPU
-    # (Pallas interpret mode — tests/CI); 'off' = never fuse
+    # 'auto' = a fused-schedule request runs the wave program on TPU (and
+    # raises there if it cannot) and resolves to overlap on other
+    # backends; 'interpret' = run the wave program on any backend (tests
+    # off the chip), Pallas kernels in interpret mode; 'off' = never fuse
     fused: str = "auto"
     # device verification rounds executed inside each wave program before
     # the host drive loop takes over (R in DESIGN.md §3)
     wave_rounds: int = 2
     # generate token streams with the cosine_topk Pallas kernel instead of
-    # the jnp provider sweep (interpret mode off-TPU; bit-identical streams)
+    # the jnp provider sweep (interpret mode only with fused='interpret')
     stream_use_kernel: bool = False
     # refinement admission schedule (DESIGN.md §2): 'segmented' = the
     # set-segmented parallel scan (rank levels of chunk-wide vectorized
@@ -206,6 +207,12 @@ class SearchParams:
         assert self.fused in ("auto", "interpret", "off")
         assert self.wave_rounds >= 0
         assert self.refine_layout in ("serial", "segmented")
+
+    @property
+    def interpret(self) -> bool:
+        """Whether the search path's Pallas kernels run in interpret mode
+        — only when the caller asked for it with ``fused='interpret'``."""
+        return self.fused == "interpret"
 
 
 @dataclasses.dataclass

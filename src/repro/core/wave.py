@@ -19,22 +19,24 @@ schedule round-trips through the host (DESIGN.md §9 item 6, resolved):
            (carry, chunk) -> carry step from ``core.refinement``, set-
            segmented admission with in-trace within-set ranks, vmapped
            over the wave's queries);
-  Stage B  candidate compaction by prefix-sum mask
-           (``kernels.refine_verify.compact_indices``);
-  Stage C  theta_lb update + on-device bound exchange
-           (``runtime.sharding.all_reduce_max_traced`` — `lax.pmax`
-           over the repository shard axes, identity without a mesh);
+  Stage B  candidate compaction (:func:`compact_indices`, a stable
+           sort of the survivor mask);
+  Stage C  theta_lb update from the refinement bounds;
   Stage D  the first R auction/Hungarian verification rounds with
            Lemma-8 dual-bound aborts, mirroring one
            ``PostprocessState.next_request``/``apply`` cycle per round
            (top-ub batch selection, weight recompute on the normalized
-           table, bracket application, UB-filter drops), with a bound
-           exchange after every round.
+           table, bracket application, UB-filter drops), with a theta
+           refresh after every round.
 
-Waves chain through a donated theta carry: wave p+1 consumes wave p's
-on-device theta output, so the scheduler dispatches every wave before
-materializing any (JAX async dispatch) and the host sees device data
-exactly once per wave.  The host drive loop then resumes from
+A wave program runs on one device: its shard's, or the default one.
+Shards share theta_lb between programs, not inside one: waves chain
+through a donated theta carry (wave p+1 consumes wave p's on-device
+theta output, hopping devices when shards are placed), and the
+scheduler's ``bound_exchange`` hook all-reduces the host-side bounds
+over a mesh at wave boundaries.  The scheduler dispatches every wave
+before materializing any (JAX async dispatch) and the host sees device
+data exactly once per wave.  The host drive loop then resumes from
 ``PostprocessState.from_wave`` for whatever verification the R device
 rounds did not finish — the host path stays the bit-identical oracle.
 
@@ -57,13 +59,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.ref import event_ranks_ref
-from ..kernels.refine_verify import candidate_weights, compact_indices
 from ..runtime import instrument
-from ..runtime.sharding import _round_down_f32, all_reduce_max_traced
+from ..runtime.sharding import _round_down_f32
 from .matching.auction import _auction_single, make_eps_schedule
 from .matching.hungarian import _hungarian_padded
 from .refinement import (refine_carry_init, refine_chunk_step,
                          refine_finalize)
+from .similarity import cosine_rows
 from .types import SearchParams
 from .types import pow2 as _pow2
 
@@ -111,22 +113,78 @@ def expand_events_traced(tok, qp, sm, indptr, posting_set, posting_slot,
             slot.reshape(n_chunks, chunk), sim.reshape(n_chunks, chunk))
 
 
-def fused_available(params: SearchParams, sim_provider) -> bool:
-    """Whether the fused schedule can run here (else: overlap fallback).
+@jax.jit
+def compact_indices(mask: jnp.ndarray):
+    """Survivor indices of a boolean mask, ascending, -1 beyond the count
+    (Stage B's candidate compaction).
 
-    Requires a dense cosine embedding-table provider (the wave recomputes
-    verification weights on-device from the normalized table) and either
-    a TPU backend or an explicit opt-in to Pallas interpret mode
-    (``params.fused == 'interpret'`` — tests/CI off-TPU)."""
+    mask: (n,) bool.  Returns (idx (n,) int32, count () int32) with
+    ``idx[:count]`` == ``mask.nonzero()[0]`` and ``idx[count:] == -1``.
+    One stable sort puts survivors first in index order.  It is plain XLA
+    and vmaps over the wave batch; a Pallas form would need an in-kernel
+    prefix sum, which Mosaic does not lower.
+    """
+    n = mask.shape[0]
+    order = jnp.argsort(~mask, stable=True).astype(jnp.int32)
+    count = jnp.sum(mask, dtype=jnp.int32)
+    idx = jnp.where(jnp.arange(n, dtype=jnp.int32) < count, order,
+                    jnp.int32(-1))
+    return idx, count
+
+
+def candidate_weights(table_n: jnp.ndarray, query_tok: jnp.ndarray,
+                      cand_tok: jnp.ndarray, cand_sizes: jnp.ndarray,
+                      nq: jnp.ndarray, alpha) -> jnp.ndarray:
+    """Alpha-thresholded verification weights for one candidate batch.
+
+    table_n: (vocab, d) row-L2-normalized embedding table.  Entries come
+      from the same contraction (``similarity.cosine_rows``) over the same
+      normalized rows as the host verifier's ``pairwise``, so the device
+      rounds and the host continuation see equal weights.
+    query_tok: (nq_pad,) int32, -1 padding;  cand_tok: (vb, c_pad) int32,
+      -1 padding;  cand_sizes: (vb,) logical |C|;  nq: logical |Q|.
+    Returns (vb, nq_pad, c_pad) float32, zero outside the logical block.
+    """
+    qv = table_n[jnp.clip(query_tok, 0, None)]         # (nq_pad, d)
+    tv = table_n[jnp.clip(cand_tok, 0, None)]          # (vb, c_pad, d)
+    vb, c_pad, d = tv.shape
+    s = cosine_rows(qv, tv.reshape(vb * c_pad, d))     # (nq_pad, vb*c_pad)
+    s = s.reshape(-1, vb, c_pad).transpose(1, 0, 2)
+    q_valid = query_tok >= 0
+    t_valid = cand_tok >= 0
+    same = (query_tok[None, :, None] == cand_tok[:, None, :]) \
+        & q_valid[None, :, None] & t_valid[:, None, :]
+    s = jnp.where(same, 1.0, s)
+    w = jnp.where(s >= alpha, s, 0.0)
+    row_ok = jnp.arange(query_tok.shape[0]) < nq
+    col_ok = jnp.arange(cand_tok.shape[1])[None, :] < cand_sizes[:, None]
+    return jnp.where(row_ok[None, :, None] & col_ok[:, None, :], w, 0.0)
+
+
+def fused_available(params: SearchParams, sim_provider) -> bool:
+    """Whether a request for the fused schedule runs the wave program.
+
+    ``params.fused='off'`` never fuses.  ``'auto'`` fuses on a TPU backend
+    and resolves to the overlap schedule elsewhere (callers record the
+    resolved name: ``SchedulerStats.schedule``, ``RequestEngine.schedule``).
+    ``'interpret'`` fuses on any backend (tests off the chip): Pallas
+    kernels run in interpret mode, and the wave's auction rounds take
+    their inline jnp form.  Wherever the wave is to run, a provider it
+    cannot serve raises instead of quietly turning into host waves: the
+    wave recomputes verification weights on device from a dense cosine
+    embedding table."""
     if params.fused == "off":
         return False
-    if getattr(sim_provider, "name", None) != "cosine":
+    if params.fused == "auto" and jax.default_backend() != "tpu":
         return False
-    if getattr(sim_provider, "table", None) is None:
-        return False
-    if jax.default_backend() == "tpu":
-        return True
-    return params.fused == "interpret"
+    if (getattr(sim_provider, "name", None) != "cosine"
+            or getattr(sim_provider, "table", None) is None):
+        raise ValueError(
+            f"the fused schedule needs a dense cosine embedding-table "
+            f"provider, got {type(sim_provider).__name__}; request "
+            f"schedule='overlap' or SearchParams(fused='off') to serve it "
+            f"on host waves")
+    return True
 
 
 class WaveConfig(NamedTuple):
@@ -148,8 +206,9 @@ class WaveConfig(NamedTuple):
     verifier: str
     refine_layout: str
     alpha: float
-    interpret: bool
-    use_kernel: bool
+    use_kernel: bool                 # auction round top-2 via the Pallas
+    #                                  kernel (compiled; off in interpret
+    #                                  mode, where the jnp pass is faster)
     max_rounds: int = 5000
 
 
@@ -180,7 +239,7 @@ _WAVE_CHUNK_GUARD = (1, 2)
 
 
 @functools.lru_cache(maxsize=None)
-def _wave_fn(cfg: WaveConfig, mesh):
+def _wave_fn(cfg: WaveConfig):
     """Build (and cache) the jitted wave program for one static config.
 
     The theta carry (argument 6) is donated: waves chain through it, so
@@ -288,14 +347,12 @@ def _wave_fn(cfg: WaveConfig, mesh):
         S, ub0, seen, alive, th_ref, pruned_ref = jax.vmap(refine)(
             st_tok, st_q, st_sim, nqs)
 
-        # ---- Stage B: candidate compaction (prefix-sum mask kernel) ----
+        # ---- Stage B: candidate compaction ----
         surv = seen & alive
-        surv_idx, surv_cnt = jax.vmap(
-            lambda m: compact_indices(m, interpret=cfg.interpret))(surv)
+        surv_idx, surv_cnt = jax.vmap(compact_indices)(surv)
 
-        # ---- Stage C: theta update + on-device bound exchange ----
+        # ---- Stage C: theta update ----
         theta = jnp.maximum(theta, th_ref)
-        theta = all_reduce_max_traced(theta, mesh)
 
         # ---- Stage D: first R verification rounds ----
         lb, ub, live = S, ub0, surv
@@ -304,11 +361,10 @@ def _wave_fn(cfg: WaveConfig, mesh):
 
         def round_step(carry, _):
             lb, ub, live, verified, theta, c_post, c_early, c_full = carry
-            lb, ub, live, verified, th_q, dp, de, df = jax.vmap(
+            lb, ub, live, verified, theta, dp, de, df = jax.vmap(
                 lambda l, u, lv, vf, t, q, n: one_round(
                     l, u, lv, vf, t, q, n, table_n, set_tok, sizes32, eps)
             )(lb, ub, live, verified, theta, qtok, nqs)
-            theta = all_reduce_max_traced(th_q, mesh)
             return (lb, ub, live, verified, theta,
                     c_post + dp, c_early + de, c_full + df), None
 
@@ -325,7 +381,7 @@ def _wave_fn(cfg: WaveConfig, mesh):
     return jax.jit(fn, donate_argnums=(5,))
 
 
-# Engine-lifetime runner reuse (DESIGN.md §3.2): keyed by provider/mesh
+# Engine-lifetime runner reuse (DESIGN.md §3.2): keyed by provider
 # identity + the full (hashable, frozen) params.  Bounded in practice by
 # the handful of provider/params combinations a process serves; entries
 # hold only the eps schedule and the compiled-program cache key — ALL
@@ -336,18 +392,17 @@ def _wave_fn(cfg: WaveConfig, mesh):
 _RUNNER_CACHE: dict = {}
 
 
-def wave_runner_for(sim_provider, params: SearchParams,
-                    mesh=None) -> "WaveRunner":
-    """The shared :class:`WaveRunner` of a (provider, params, mesh)
-    triple — cross-request reuse of the eps schedule and compiled wave
-    programs; collection operands are borrowed per-shard at launch."""
-    key = (id(sim_provider), params, id(mesh))
+def wave_runner_for(sim_provider, params: SearchParams) -> "WaveRunner":
+    """The shared :class:`WaveRunner` of a (provider, params) pair —
+    cross-request reuse of the eps schedule and compiled wave programs;
+    collection operands are borrowed per-shard at launch."""
+    key = (id(sim_provider), params)
     hit = _RUNNER_CACHE.get(key)
     if hit is None:
-        # pin the provider (and mesh) so their ids cannot be recycled by
-        # the allocator while the cache entry is alive
-        hit = _RUNNER_CACHE[key] = (
-            WaveRunner(sim_provider, params, mesh=mesh), sim_provider, mesh)
+        # pin the provider so its id cannot be recycled by the allocator
+        # while the cache entry is alive
+        hit = _RUNNER_CACHE[key] = (WaveRunner(sim_provider, params),
+                                    sim_provider)
     return hit[0]
 
 
@@ -432,15 +487,13 @@ class WaveRunner:
 
     The runner holds no per-plan state — every launch threads its carry
     explicitly — so ONE runner serves every plan/request that shares a
-    (provider, params, mesh) triple; obtain it via
+    (provider, params) pair; obtain it via
     :func:`wave_runner_for` (the request engine and the fused schedule
     both do)."""
 
-    def __init__(self, sim_provider, params: SearchParams,
-                 mesh=None):
+    def __init__(self, sim_provider, params: SearchParams):
         self.params = params
-        self.mesh = mesh
-        self.interpret = jax.default_backend() != "tpu"
+        self.use_kernel = not params.interpret
         self.sim = sim_provider
         self.eps = make_eps_schedule(params.auction_eps)
 
@@ -505,8 +558,8 @@ class WaveRunner:
             verifier=self.params.verifier,
             refine_layout=self.params.refine_layout,
             alpha=float(self.params.alpha),
-            interpret=self.interpret, use_kernel=not self.interpret)
-        _wave_fn(cfg, self.mesh)(
+            use_kernel=self.use_kernel)
+        _wave_fn(cfg)(
             put(np.full((B_pad, n_tuples), -1, np.int32)),
             put(np.zeros((B_pad, n_tuples), np.int32)),
             put(np.zeros((B_pad, n_tuples), np.float32)),
@@ -537,10 +590,9 @@ class WaveRunner:
         the wave runs on its device: the shared stream operands get a
         per-device committed copy and the theta carry hops to the
         shard's device — that hop IS the cross-shard bound exchange of
-        the carry-chained drive (an on-device all-reduce via the mesh is
-        the alternative exchange mode; placed shards use the carry
-        chain).  Unplaced shards take the identical code path with
-        every placement a no-op — the degenerate single-device case."""
+        the carry-chained drive.  Unplaced shards take the identical
+        code path with every placement a no-op — the degenerate
+        single-device case."""
         set_tok, sizes32, c_pad = index.wave_operands()
         indptr_dev, pset_dev, pslot_dev = index.csr_arrays()
         table_n = index.table_for(self.sim)
@@ -581,8 +633,8 @@ class WaveRunner:
             verifier=self.params.verifier,
             refine_layout=self.params.refine_layout,
             alpha=float(self.params.alpha),
-            interpret=self.interpret, use_kernel=not self.interpret)
-        fn = _wave_fn(cfg, self.mesh)
+            use_kernel=self.use_kernel)
+        fn = _wave_fn(cfg)
         instrument.record(f"h2d:wave_dispatch[s{getattr(index, 'sid', 0)}]")
         out = fn(stream_ops.tok, stream_ops.q_pos, stream_ops.sim,
                  stream_ops.qtok, stream_ops.nqs, theta_dev,

@@ -33,36 +33,42 @@ def _kernel(qe_ref, ev_ref, vals_ref, idx_ref, *, k: int, bv: int,
 
     @pl.when(step == 0)
     def _init():
-        vals_ref[...] = jnp.full_like(vals_ref, _NEG)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
+        vals_ref[...] = jnp.full(vals_ref.shape, _NEG, jnp.float32)
+        idx_ref[...] = jnp.zeros(idx_ref.shape, jnp.int32)
 
-    qe = qe_ref[...]                       # (nq, d)
-    ev = ev_ref[...]                       # (bv, d)
     scores = jax.lax.dot_general(
-        qe, ev, (((1,), (1,)), ((), ())),
+        qe_ref[...], ev_ref[...], (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)          # (nq, bv)
+    cols = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     base = step * bv
-    cand_idx = base + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    scores = jnp.where(cand_idx < nv_real, scores, _NEG)
+    scores = jnp.where(base + cols < nv_real, scores, _NEG)
+    kcols = jax.lax.broadcasted_iota(jnp.int32, vals_ref.shape, 1)
 
-    comb_v = jnp.concatenate([vals_ref[...], scores], axis=1)
-    comb_i = jnp.concatenate([idx_ref[...], cand_idx], axis=1)
-    nq = comb_v.shape[0]
-    out_v = jnp.zeros((nq, k), jnp.float32)
-    out_i = jnp.zeros((nq, k), jnp.int32)
-
+    # Merge the running top-k (old) with the tile's scores (new) by k
+    # max+mask passes.  Ties go to the old list, then to the lower
+    # column: the first-index argmax over the concatenation [old | new].
+    # Everything is a lane-wise select or a row reduction — no gather or
+    # scatter, which Mosaic does not lower.
     def select(j, st):
-        cv, ci, ov, oi = st
-        m = jnp.max(cv, axis=1)
-        a = jnp.argmax(cv, axis=1)
-        picked = jnp.take_along_axis(ci, a[:, None], axis=1)
-        ov = jax.lax.dynamic_update_slice(ov, m[:, None], (0, j))
-        oi = jax.lax.dynamic_update_slice(oi, picked, (0, j))
-        cv = cv.at[jnp.arange(nq), a].set(_NEG)
-        return cv, ci, ov, oi
+        cv, ci, sv, ov, oi = st
+        m_old = jnp.max(cv, axis=1, keepdims=True)
+        a_old = jnp.argmax(cv, axis=1).astype(jnp.int32)[:, None]
+        m_new = jnp.max(sv, axis=1, keepdims=True)
+        a_new = jnp.argmax(sv, axis=1).astype(jnp.int32)[:, None]
+        take_old = m_old >= m_new
+        hit_old = kcols == a_old
+        i_old = jnp.sum(jnp.where(hit_old, ci, 0), axis=1, keepdims=True)
+        ov = jnp.where(kcols == j, jnp.where(take_old, m_old, m_new), ov)
+        oi = jnp.where(kcols == j, jnp.where(take_old, i_old, base + a_new),
+                       oi)
+        cv = jnp.where(take_old & hit_old, _NEG, cv)
+        sv = jnp.where(~take_old & (cols == a_new), _NEG, sv)
+        return cv, ci, sv, ov, oi
 
-    _, _, out_v, out_i = jax.lax.fori_loop(
-        0, k, select, (comb_v, comb_i, out_v, out_i))
+    cv, ci = vals_ref[...], idx_ref[...]
+    _, _, _, out_v, out_i = jax.lax.fori_loop(
+        0, k, select, (cv, ci, scores, cv, ci))
     vals_ref[...] = out_v
     idx_ref[...] = out_i
 

@@ -130,23 +130,6 @@ def cosine_topk_ref(qe: jnp.ndarray, ev: jnp.ndarray, k: int):
     return vals, idx.astype(jnp.int32)
 
 
-def compact_indices_ref(mask: jnp.ndarray):
-    """Prefix-sum compaction oracle: survivor indices ascending, -1 pad.
-
-    mask: (n,) bool.  Returns (idx (n,) int32, count () int32) with
-    idx[:count] == mask.nonzero()[0] and idx[count:] == -1.
-    """
-    n = mask.shape[0]
-    m = mask.astype(jnp.int32)
-    ps = jnp.cumsum(m)
-    total = ps[-1] if n else jnp.int32(0)
-    iota = jnp.arange(n, dtype=jnp.int32)
-    pos = jnp.where(m > 0, ps - 1, total + iota - ps)
-    idx = jnp.full((n,), -1, jnp.int32).at[pos].set(
-        jnp.where(m > 0, iota, jnp.int32(-1)))
-    return idx, total.astype(jnp.int32)
-
-
 def auction_topk2_ref(wm: jnp.ndarray, prices: jnp.ndarray):
     """Per-row best/second-best profit and best column (one auction round's
     heavy pass).  wm: (n, m); prices: (m,).  Returns (w1, w2, jstar)."""

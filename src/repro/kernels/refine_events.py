@@ -12,17 +12,18 @@ order is bit-identical to the serial per-event loop (cross-set events
 commute), while the sequential dependency chain shrinks from one step
 per event to one per level.
 
-State gathers/scatters are dynamic scalar ``pl.load``/``pl.store``
-pairs guarded by ``pl.when`` — the same pattern as
-``refine_verify._compact_kernel`` (dynamic scalar stores lower on
-Mosaic where a vector scatter would not).  VMEM budget: the carry is
-O(num_sets * q_words + total_slots) int32/uint32 lanes — a few hundred
-KB at repository-partition sizes, far under the ~16 MB VMEM budget.
+State gathers/scatters are dynamic scalar ref reads/writes
+(``ref[pl.ds(i, 1), pl.ds(j, 1)]``) guarded by ``pl.when``.  VMEM
+budget: the carry is O(num_sets * q_words + total_slots) int32/uint32
+lanes — a few hundred KB at repository-partition sizes.
 
-The pure-jnp oracle is ``ref.refine_events_packed_ref`` — the SAME
-function the production segmented layout runs — and ``ops.
-refine_events`` dispatches with interpret mode off-TPU
-(tests/test_kernels.py asserts bit-parity).
+Interpret mode only.  Mosaic refuses the compiled kernel: a scalar
+``vector.load`` at a dynamic lane offset of a VMEM ref fails with
+"cannot statically prove that index in dimension 1 is a multiple of
+128".  The served path runs the jnp form
+(``ref.refine_events_packed_ref`` — the oracle here, bit-identical), so
+:func:`refine_events` raises unless ``interpret=True``.  A compiled form
+needs the carry in SMEM or a lane-vectorized admission.
 """
 from __future__ import annotations
 
@@ -35,12 +36,11 @@ from jax.experimental import pallas as pl
 
 def _scal(ref, *idx):
     """Scalar load from a 2-D ref at dynamic indices."""
-    return pl.load(ref, tuple(pl.dslice(i, 1) for i in idx))[0, 0]
+    return ref[tuple(pl.ds(i, 1) for i in idx)][0, 0]
 
 
 def _store(ref, val, *idx):
-    pl.store(ref, tuple(pl.dslice(i, 1) for i in idx),
-             val.reshape(1, 1))
+    ref[tuple(pl.ds(i, 1) for i in idx)] = val.reshape(1, 1)
 
 
 def _refine_events_kernel(set_ref, q_ref, slot_ref, sim_ref, alive_ref,
@@ -117,7 +117,14 @@ def refine_events(state, c_set, c_q, c_slot, c_sim,
     slot_matched) — the per-set carry minus theta, ``alive`` read-only.
     Returns the mutated fields (S, l, T, d, seen, qmatched, qseen,
     slot_matched), bit-identical to ``ref.refine_events_packed_ref``.
+    Raises ``NotImplementedError`` unless ``interpret`` (see the module
+    docstring).
     """
+    if not interpret:
+        raise NotImplementedError(
+            "refine_events has no compiled form: Mosaic cannot lower its "
+            "dynamic-lane scalar VMEM accesses.  Pass interpret=True, or "
+            "use ref.refine_events_packed_ref (the served path's form).")
     S, l, T, d, seen, alive, qmatched, qseen, slot_matched = state
     W, L = c_set.shape
     n = S.shape[0]

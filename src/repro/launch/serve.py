@@ -7,11 +7,13 @@ coalesced into the next partition wave with whatever else has arrived
 served through the LRU token-stream cache and pow2 shape buckets, and
 responded to with its TRUE admit->respond latency — the historical
 ``serve_batch`` reported one amortized number for every query in the
-batch.  ``--fused`` drives each wave's partition groups as fused
-on-device programs (DESIGN.md §3.1); ``--mesh-bounds`` runs the theta_lb
-exchange as a real all-reduce-max over the repository mesh (DESIGN.md
-§5).  ``--per-query`` keeps the per-query one-shot loop as the A/B
-baseline (bit-identical results).  ``--deadline-ms``/``--shed`` exercise
+batch.  Each wave's partition groups run as fused on-device programs
+(DESIGN.md §3.1) on a TPU backend; elsewhere the engine serves host
+waves, unless ``--interpret`` runs the fused programs with Pallas in
+interpret mode.  ``--mesh-bounds`` runs the theta_lb exchange as a real
+all-reduce-max over the repository mesh (DESIGN.md §5).  ``--per-query``
+keeps the per-query one-shot loop as the A/B baseline (bit-identical
+results).  ``--deadline-ms``/``--shed`` exercise
 the fault-tolerant serving plane (DESIGN.md §6): per-request deadlines
 with deadline-aware shedding, and the summary reports p50/p99 latency,
 deadline-met ratio, and shed/retry/failed accounting.
@@ -42,10 +44,11 @@ import numpy as np
 from ..core import (EmbeddingSimilarity, KoiosSearch, SearchParams)
 from ..data import (EmbeddingTableProvider, dataset_preset, make_embeddings,
                     sample_queries)
+from ..runtime.compile_cache import enable_compile_cache
 from ..runtime.engine import RequestEngine
 
 
-def _response_dict(r) -> dict:
+def response_dict(r) -> dict:
     """One EngineResponse -> the serving-API response payload."""
     return {
         "ids": r.result.ids.tolist(),
@@ -62,7 +65,7 @@ def _response_dict(r) -> dict:
     }
 
 
-def _served_hash(results) -> str:
+def served_hash(results) -> str:
     """Order-sensitive digest of the SERVED responses (ids + scores) —
     the restart-recovery parity check: equal hashes mean bit-identical
     served results, whatever process lifetimes produced them."""
@@ -102,7 +105,7 @@ class SearchServer:
     through an :class:`~repro.runtime.engine.AdmissionRouter` fleet."""
 
     def __init__(self, coll, sim, params: SearchParams, partitions: int,
-                 schedule: str = "overlap", bound_exchange=None, mesh=None,
+                 schedule: str = "overlap", bound_exchange=None,
                  stream_cache_bytes: int = 64 << 20, replicas: int = 1,
                  shards: int = 0, place: bool = False,
                  shed_deadlines: bool = False, fault_plan=None,
@@ -120,10 +123,10 @@ class SearchServer:
         self.one_shot = KoiosSearch(None, sim, params,
                                     schedule=schedule,
                                     bound_exchange=bound_exchange,
-                                    mesh=mesh, collection=self.collection)
+                                    collection=self.collection)
         engine_kwargs = dict(
             schedule="fused" if schedule == "fused" else "wave",
-            bound_exchange=bound_exchange, mesh=mesh,
+            bound_exchange=bound_exchange,
             stream_cache_bytes=stream_cache_bytes,
             shed_deadlines=shed_deadlines)
         if fault_plan is not None and replicas > 1:
@@ -142,7 +145,7 @@ class SearchServer:
         queries = [np.asarray(q, np.int32) for q in queries]
         if batched:
             responses = self.engine.serve(queries, deadlines=deadlines)
-            return [_response_dict(r) for r in responses]
+            return [response_dict(r) for r in responses]
         out = []
         for q in queries:
             t0 = time.monotonic()
@@ -197,13 +200,13 @@ def main(argv=None):
                          "one-shot path (A/B baseline for the engine)")
     sched = ap.add_mutually_exclusive_group()
     sched.add_argument("--sequential", action="store_true",
-                       help="one-shot baseline schedule for --per-query; "
-                            "the engine's host waves are unaffected "
-                            "(bit-identical results either way)")
-    sched.add_argument("--fused", action="store_true",
-                       help="serve with fused on-device wave programs "
-                            "(DESIGN.md §3) — interpret mode off-TPU; "
-                            "bit-identical results")
+                       help="serve host waves (and use the sequential "
+                            "one-shot schedule for --per-query) instead of "
+                            "the fused device waves; bit-identical results")
+    sched.add_argument("--interpret", action="store_true",
+                       help="run the fused wave programs off the chip, "
+                            "Pallas kernels in interpret mode (without it "
+                            "a non-TPU backend serves host waves)")
     ap.add_argument("--mesh-bounds", action="store_true",
                     help="run the theta_lb exchange as an all-reduce-max "
                          "over a device mesh (DESIGN.md §5)")
@@ -225,25 +228,21 @@ def main(argv=None):
                     help="skip the first N requests of the trace, keeping "
                          "global request numbering (restart resume)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     bound_exchange = None
-    mesh = None
     if args.mesh_bounds:
         from ..runtime.sharding import bound_exchange_for
         from .mesh import bound_exchange_mesh
-        mesh = bound_exchange_mesh()
-        bound_exchange = bound_exchange_for(mesh)
+        bound_exchange = bound_exchange_for(bound_exchange_mesh())
 
     print(f"[serve] building corpus ({args.dataset} @ {args.scale})")
     coll = dataset_preset(args.dataset, scale=args.scale, seed=0)
     emb = make_embeddings(coll.vocab_size, dim=args.dim, seed=0)
     sim = EmbeddingTableProvider(emb)
-    import jax
-    fused_mode = "auto" if jax.default_backend() == "tpu" else (
-        "interpret" if args.fused else "auto")
-    params = SearchParams(k=args.k, alpha=args.alpha, fused=fused_mode)
-    schedule = ("sequential" if args.sequential
-                else "fused" if args.fused else "overlap")
+    params = SearchParams(k=args.k, alpha=args.alpha,
+                          fused="interpret" if args.interpret else "auto")
+    schedule = "sequential" if args.sequential else "fused"
     collection = None
     if args.snapshot_dir:
         from ..runtime.collection import ShardedCollection
@@ -254,7 +253,7 @@ def main(argv=None):
                   f"{collection.epoch} from {args.snapshot_dir}")
     server = SearchServer(coll, sim, params, args.partitions,
                           schedule=schedule,
-                          bound_exchange=bound_exchange, mesh=mesh,
+                          bound_exchange=bound_exchange,
                           replicas=args.replicas, shards=args.shards,
                           place=args.place, shed_deadlines=args.shed,
                           collection=collection)
@@ -289,7 +288,7 @@ def main(argv=None):
                 server.engine.submit(
                     q, arrival=t_arr,
                     deadline=t_arr + dl if dl else None)
-            results = [_response_dict(r)
+            results = [response_dict(r)
                        for r in sorted(server.engine.drain(),
                                        key=lambda r: r.rid)]
         else:
@@ -320,19 +319,19 @@ def main(argv=None):
                   + (f", snapshotted to {args.snapshot_dir}"
                      if args.snapshot_dir else ""))
             if args.kill_after_update:
-                print(f"[serve] served_hash={_served_hash(served_pre)} "
+                print(f"[serve] served_hash={served_hash(served_pre)} "
                       f"requests={len(served_pre)} epoch=0")
                 print("[serve] killed after update (exit 17)")
                 return 17
     if not args.per_query:
         if served_pre:
-            print(f"[serve] pre_update_hash={_served_hash(served_pre)} "
+            print(f"[serve] pre_update_hash={served_hash(served_pre)} "
                   f"requests={len(served_pre)}")
         if served_post:
-            print(f"[serve] post_update_hash={_served_hash(served_post)} "
+            print(f"[serve] post_update_hash={served_hash(served_post)} "
                   f"requests={len(served_post)}")
         print(f"[serve] served_hash="
-              f"{_served_hash(served_pre + served_post)} "
+              f"{served_hash(served_pre + served_post)} "
               f"requests={len(served_pre) + len(served_post)} "
               f"epoch={server.collection.epoch}")
     if not args.per_query:

@@ -133,8 +133,11 @@ class RequestEngine:
     ``schedule``: ``"wave"`` drives host waves (works on any backend;
     ``"overlap"``/``"sequential"`` are accepted aliases — at wave
     granularity they coincide), ``"fused"`` runs each wave's
-    per-partition groups as fused device programs where available
-    (``core.wave.fused_available``; falls back to host waves).  Results
+    per-partition groups as fused device programs: always on TPU (a
+    provider the wave cannot serve raises), anywhere with
+    ``params.fused='interpret'``; ``fused='off'``, or ``'auto'`` off-TPU,
+    resolves to host waves (``core.wave.fused_available``).
+    ``self.schedule`` is the resolved name.  Results
     are bit-identical across all of them and to the one-shot
     ``KoiosSearch.search_batch`` (tests/test_engine.py).
 
@@ -154,7 +157,7 @@ class RequestEngine:
                  params: Optional[SearchParams] = None,
                  partitions: int = 1, schedule: str = "wave",
                  partition_by: str = "sets",
-                 bound_exchange: Optional[Callable] = None, mesh=None,
+                 bound_exchange: Optional[Callable] = None,
                  stream_cache_bytes: int = 64 << 20,
                  max_wave_requests: int = 64,
                  max_pending: Optional[int] = None,
@@ -181,7 +184,6 @@ class RequestEngine:
         self._epoch = collection.pin()
         self.coll = self._epoch.coll
         self.bound_exchange = bound_exchange
-        self.mesh = mesh
         self.clock = clock
         self._sleep = sleep
         self.max_wave_requests = int(max_wave_requests)
@@ -199,8 +201,7 @@ class RequestEngine:
         if schedule == "fused":
             from ..core.wave import fused_available, wave_runner_for
             if fused_available(self.params, sim_provider):
-                self._runner = wave_runner_for(sim_provider, self.params,
-                                               mesh=mesh)
+                self._runner = wave_runner_for(sim_provider, self.params)
             else:
                 schedule = "wave"
         self.schedule = schedule
@@ -330,7 +331,8 @@ class RequestEngine:
             seen.add(key)
         streams = build_token_stream_batch_cached(
             queries, self.sim, self.params.alpha, self.stream_cache,
-            use_kernel=self.params.stream_use_kernel)
+            use_kernel=self.params.stream_use_kernel,
+            interpret=self.params.interpret)
         t_stream = self.clock()
         qis, new_tiles = self.plan.add_queries(queries)
         for t in new_tiles:
@@ -654,7 +656,8 @@ class RequestEngine:
         from ..core.wave import _WAVE_CHUNK_GUARD
         streams = build_token_stream_batch_cached(
             sample, self.sim, self.params.alpha, self.stream_cache,
-            use_kernel=self.params.stream_use_kernel)
+            use_kernel=self.params.stream_use_kernel,
+            interpret=self.params.interpret)
         chunk = self.params.chunk_size
         counts = [s.inv.posting_counts() for s in self.partitions]
         bs = 1
@@ -759,7 +762,7 @@ class AdmissionRouter:
 
     Every replica serves the SAME :class:`ShardedCollection` resource —
     per-shard device operands are uploaded once and borrowed by all, and
-    identical (provider, params, mesh) triples share compiled wave
+    identical (provider, params) pairs share compiled wave
     programs through ``wave_runner_for`` — so a replica costs one plan +
     one verifier pool + one stream cache, not another copy of the
     repository.  The router admits requests with a global request id,

@@ -28,7 +28,7 @@ None.  Activations/batch: batch dim over (pod, data); KV caches: batch over
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional, Sequence
+from typing import Any, Sequence
 
 import jax
 import numpy as np
@@ -256,49 +256,31 @@ def _round_down_f32(values):
 
 @functools.lru_cache(maxsize=None)
 def _amax_fn(mesh: Mesh, present: tuple):
-    from jax.experimental.shard_map import shard_map
-
-    @functools.partial(shard_map, mesh=mesh, in_specs=P(), out_specs=P())
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(),
+                       out_specs=P())
     def _amax(v):
         for a in present:
             v = jax.lax.pmax(v, a)
         return v
 
-    return _amax
-
-
-def all_reduce_max_traced(values, mesh: Optional[Mesh],
-                          axes: Sequence[str] = ("pod", "data")):
-    """In-trace theta_lb exchange for the fused wave program (DESIGN.md §3).
-
-    The same all-reduce-max as :func:`all_reduce_max`, but callable from
-    *inside* a jit trace (shard_map composes under jit), so the wave
-    program exchanges bounds on-device between verification rounds with
-    no host round-trip.  ``values`` stays float32 throughout — there is
-    no float64 narrowing to guard, so no round-down is needed.  With no
-    mesh (or none of the axes present) it is the identity, which keeps
-    the single-process CPU path mesh-free."""
-    if mesh is None:
-        return values
-    present = tuple(a for a in axes if a in mesh.axis_names)
-    if not present:
-        return values
-    return _amax_fn(mesh, present)(values)
+    return jax.jit(_amax)
 
 
 def all_reduce_max(values, mesh: Mesh, axes: Sequence[str] = ("pod", "data")):
     """All-reduce-max of a replicated bound vector over the repository
     shard axes (DESIGN.md §5).
 
-    The partition scheduler's theta_lb exchange: every shard contributes
-    its per-query lower bounds and receives the global max, so a bound
-    raised anywhere prunes candidates everywhere.  ``values`` is a (B,)
-    array (one slot per in-flight query), replicated across the mesh; axes
-    absent from the mesh are skipped, so the same call works on the
-    production (pod, data, model) mesh, the single-pod (data, model) mesh,
-    and the single-device smoke mesh.  The shard_map trace is cached per
-    (mesh, axes) — this runs once per verification round.  Returns a host
-    ndarray (float32, rounded toward -inf so the bound stays certified).
+    The partition scheduler's theta_lb exchange, called at each exchange
+    point (between waves, after verification rounds): every shard
+    contributes its per-query lower bounds and receives the global max,
+    so a bound raised anywhere prunes candidates everywhere.  ``values``
+    is a (B,) array (one slot per in-flight query), replicated across the
+    mesh; axes absent from the mesh are skipped, so the same call works
+    on the production (pod, data, model) mesh, the single-pod (data,
+    model) mesh, and the single-device smoke mesh.  The jitted shard_map
+    is cached per (mesh, axes) — this runs once per verification round.
+    Returns a host ndarray (float32, rounded toward -inf so the bound
+    stays certified).
     """
     vals = _round_down_f32(values)
     present = tuple(a for a in axes if a in mesh.axis_names)

@@ -1,7 +1,6 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS manipulation here — smoke tests and
 benches must see the single real CPU device; only launch/dryrun.py (run as a
 separate process) forces the 512-device host platform."""
-import _hypothesis_compat  # noqa: F401 — installs a hypothesis shim if absent
 import numpy as np
 import pytest
 

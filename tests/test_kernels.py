@@ -1,14 +1,14 @@
-"""Per-kernel allclose vs the ref.py oracles (interpret mode on CPU),
-with shape/dtype sweeps + hypothesis randomization."""
+"""Per-kernel allclose vs the ref.py oracles (interpret mode, requested
+by every call), with shape/dtype sweeps + hypothesis randomization."""
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import (auction_topk2, auction_topk2_ref, compact_indices,
-                           compact_indices_ref, cosine_topk, cosine_topk_ref,
-                           ssd, ssd_ref)
+from repro.core.wave import compact_indices
+from repro.kernels import (auction_topk2, auction_topk2_ref, cosine_topk,
+                           cosine_topk_ref, ssd, ssd_ref)
 
 
 def _unit(rng, n, d, dtype=np.float32):
@@ -26,7 +26,7 @@ def _unit(rng, n, d, dtype=np.float32):
 def test_cosine_topk_shapes(nq, nv, d, k, bv):
     rng = np.random.default_rng(0)
     qe, ev = _unit(rng, nq, d), _unit(rng, nv, d)
-    vals, idx = cosine_topk(qe, ev, k=k, bv=bv)
+    vals, idx = cosine_topk(qe, ev, k=k, bv=bv, interpret=True)
     rvals, ridx = cosine_topk_ref(jnp.asarray(qe), jnp.asarray(ev), k)
     np.testing.assert_allclose(np.asarray(vals), np.asarray(rvals),
                                atol=1e-5, rtol=1e-5)
@@ -40,7 +40,7 @@ def test_cosine_topk_shapes(nq, nv, d, k, bv):
 def test_cosine_topk_dtypes(dtype):
     rng = np.random.default_rng(1)
     qe, ev = _unit(rng, 4, 16, dtype), _unit(rng, 64, 16, dtype)
-    vals, _ = cosine_topk(qe, ev, k=4, bv=16)
+    vals, _ = cosine_topk(qe, ev, k=4, bv=16, interpret=True)
     rvals, _ = cosine_topk_ref(jnp.asarray(qe, jnp.float32),
                                jnp.asarray(ev, jnp.float32), 4)
     np.testing.assert_allclose(np.asarray(vals), np.asarray(rvals),
@@ -54,7 +54,7 @@ def test_cosine_topk_property(seed, nq, nv, k):
     k = min(k, nv)
     rng = np.random.default_rng(seed)
     qe, ev = _unit(rng, nq, 8), _unit(rng, nv, 8)
-    vals, _ = cosine_topk(qe, ev, k=k, bv=8)
+    vals, _ = cosine_topk(qe, ev, k=k, bv=8, interpret=True)
     rvals, _ = cosine_topk_ref(jnp.asarray(qe), jnp.asarray(ev), k)
     np.testing.assert_allclose(np.asarray(vals), np.asarray(rvals),
                                atol=1e-5)
@@ -66,7 +66,7 @@ def test_auction_topk2_shapes(n, m, bn):
     rng = np.random.default_rng(2)
     wm = rng.random((n, m)).astype(np.float32)
     prices = rng.random(m).astype(np.float32)
-    w1, w2, j = auction_topk2(wm, prices, bn=bn)
+    w1, w2, j = auction_topk2(wm, prices, bn=bn, interpret=True)
     rw1, rw2, rj = auction_topk2_ref(jnp.asarray(wm), jnp.asarray(prices))
     np.testing.assert_allclose(np.asarray(w1), np.asarray(rw1), atol=1e-6)
     np.testing.assert_allclose(np.asarray(w2), np.asarray(rw2), atol=1e-6)
@@ -80,7 +80,7 @@ def test_auction_topk2_property(seed, n, m):
     wm = np.where(rng.random((n, m)) > 0.5, rng.random((n, m)), 0.0)
     wm = wm.astype(np.float32)
     prices = (rng.random(m) * 2).astype(np.float32)
-    w1, w2, j = auction_topk2(wm, prices, bn=8)
+    w1, w2, j = auction_topk2(wm, prices, bn=8, interpret=True)
     rw1, rw2, rj = auction_topk2_ref(jnp.asarray(wm), jnp.asarray(prices))
     np.testing.assert_allclose(np.asarray(w1), np.asarray(rw1), atol=1e-6)
     np.testing.assert_allclose(np.asarray(w2), np.asarray(rw2), atol=1e-6)
@@ -104,7 +104,7 @@ def test_ssd_vs_ref(L, chunk, H, G):
     rng = np.random.default_rng(3)
     Bt, P, S = 2, 4, 8
     x, dt, A, B, C, D = _ssd_inputs(rng, Bt, L, H, P, G, S)
-    y = ssd(x, dt, A, B, C, D, chunk=chunk)
+    y = ssd(x, dt, A, B, C, D, chunk=chunk, interpret=True)
     yr = np.stack([np.asarray(ssd_ref(jnp.asarray(x[b]), jnp.asarray(dt[b]),
                                       jnp.asarray(A), jnp.asarray(B[b]),
                                       jnp.asarray(C[b]), jnp.asarray(D)))
@@ -118,7 +118,7 @@ def test_ssd_property(seed, Bt):
     rng = np.random.default_rng(seed)
     L, H, P, G, S = 8, 2, 4, 2, 4
     x, dt, A, B, C, D = _ssd_inputs(rng, Bt, L, H, P, G, S)
-    y = ssd(x, dt, A, B, C, D, chunk=4)
+    y = ssd(x, dt, A, B, C, D, chunk=4, interpret=True)
     yr = np.stack([np.asarray(ssd_ref(jnp.asarray(x[b]), jnp.asarray(dt[b]),
                                       jnp.asarray(A), jnp.asarray(B[b]),
                                       jnp.asarray(C[b]), jnp.asarray(D)))
@@ -143,7 +143,8 @@ def test_flash_attention_vs_ref(S, bq, bk, causal):
     q = rng.normal(size=(B, H, S, d)).astype(np.float32)
     k = rng.normal(size=(B, H, S, d)).astype(np.float32)
     v = rng.normal(size=(B, H, S, d)).astype(np.float32)
-    out = flash_attention(q, k, v, bq=bq, bk=bk, causal=causal)
+    out = flash_attention(q, k, v, bq=bq, bk=bk, causal=causal,
+                          interpret=True)
     ref_out = flash_attention_ref(jnp.asarray(q), jnp.asarray(k),
                                   jnp.asarray(v), causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
@@ -157,7 +158,8 @@ def test_flash_attention_property(seed, S, causal):
     q = rng.normal(size=(1, 1, S, 8)).astype(np.float32)
     k = rng.normal(size=(1, 1, S, 8)).astype(np.float32)
     v = rng.normal(size=(1, 1, S, 8)).astype(np.float32)
-    out = flash_attention(q, k, v, bq=8, bk=8, causal=causal)
+    out = flash_attention(q, k, v, bq=8, bk=8, causal=causal,
+                          interpret=True)
     ref_out = flash_attention_ref(jnp.asarray(q), jnp.asarray(k),
                                   jnp.asarray(v), causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
@@ -170,10 +172,8 @@ def test_flash_attention_property(seed, S, causal):
 def test_compact_indices_vs_ref(n, p):
     rng = np.random.default_rng(n)
     mask = rng.random(n) < p
-    idx, cnt = compact_indices(mask)
-    ridx, rcnt = compact_indices_ref(jnp.asarray(mask))
-    assert np.array_equal(np.asarray(idx), np.asarray(ridx))
-    assert int(cnt) == int(rcnt) == int(mask.sum())
+    idx, cnt = compact_indices(jnp.asarray(mask))
+    assert int(cnt) == int(mask.sum())
     # the contract the wave program relies on: ascending survivor ids,
     # -1 beyond the count — exactly mask.nonzero()[0]
     assert np.array_equal(np.asarray(idx)[:int(cnt)], np.nonzero(mask)[0])
@@ -195,8 +195,9 @@ def test_compact_indices_vmap_under_jit():
 def test_compact_indices_property(seed, n):
     rng = np.random.default_rng(seed)
     mask = rng.random(n) < rng.random()
-    idx, cnt = compact_indices(mask)
+    idx, cnt = compact_indices(jnp.asarray(mask))
     assert np.array_equal(np.asarray(idx)[:int(cnt)], np.nonzero(mask)[0])
+    assert np.all(np.asarray(idx)[int(cnt):] == -1)
 
 
 # ------------------------------------------- auction round kernel (fused-in)
@@ -216,7 +217,7 @@ def test_auction_batch_kernel_parity():
                             jnp.asarray(nc), eps, jnp.float32(-1e30))
     ker_res = auction_batch(jnp.asarray(w), jnp.asarray(nq),
                             jnp.asarray(nc), eps, jnp.float32(-1e30),
-                            use_kernel=True)
+                            use_kernel=True, interpret=True)
     assert np.array_equal(np.asarray(ref_res.lb), np.asarray(ker_res.lb))
     assert np.array_equal(np.asarray(ref_res.ub), np.asarray(ker_res.ub))
     assert np.array_equal(np.asarray(ref_res.assign),
@@ -260,12 +261,26 @@ def test_refine_events_vs_ref(seed, n_events):
         want = refine_events_packed_ref(
             state, jnp.asarray(s3[c]), jnp.asarray(q3[c]),
             jnp.asarray(sl3[c]), jnp.asarray(si3[c]))
-        got = refine_events(state, s3[c], q3[c], sl3[c], si3[c])
+        got = refine_events(state, s3[c], q3[c], sl3[c], si3[c],
+                            interpret=True)
         for a, b in zip(want, got):
             assert np.array_equal(np.asarray(a), np.asarray(b))
         # thread the carry (alive stays all-true between chunks here)
         state = want[:5] + (state[5],) + want[5:]
     assert bool(np.asarray(state[4]).any())      # something was admitted
+
+
+def test_refine_events_compiled_raises():
+    """Mosaic refuses the admission kernel, so a compiled call must fail
+    loudly at dispatch rather than first on the chip."""
+    from repro.kernels import refine_events
+
+    from repro.core.refinement import refine_carry_init
+
+    (s3, q3, sl3, si3, _snow), num_sets, total_slots = _refine_chunks(0, 40)
+    state = refine_carry_init(num_sets, 1, total_slots)[:-1]
+    with pytest.raises(NotImplementedError, match="interpret=True"):
+        refine_events(state, s3[0], q3[0], sl3[0], si3[0])
 
 
 @settings(max_examples=5, deadline=None)
@@ -284,6 +299,7 @@ def test_refine_events_property(seed, n_events):
     want = refine_events_packed_ref(
         state, jnp.asarray(s3[0]), jnp.asarray(q3[0]),
         jnp.asarray(sl3[0]), jnp.asarray(si3[0]))
-    got = refine_events(state, s3[0], q3[0], sl3[0], si3[0])
+    got = refine_events(state, s3[0], q3[0], sl3[0], si3[0],
+                        interpret=True)
     for a, b in zip(want, got):
         assert np.array_equal(np.asarray(a), np.asarray(b))

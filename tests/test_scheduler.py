@@ -66,9 +66,13 @@ def test_fused_matches_overlap_and_sequential_bitwise(small_world, verifier,
         assert np.array_equal(b.ub, c.ub)
 
 
-def test_fused_falls_back_to_overlap_off_tpu(small_world):
-    """Without the interpret opt-in the fused schedule must resolve to
-    overlap on a CPU backend (and still return exact results)."""
+def test_fused_falls_back_to_overlap_off_tpu(small_world, monkeypatch):
+    """``fused='auto'`` resolves a fused request to overlap off-TPU, and
+    says so (exact results either way).  On a TPU backend nothing falls
+    back quietly: a fused request the wave cannot serve raises, and only
+    ``fused='off'`` resolves it to overlap."""
+    from repro.core import NGramJaccardSimilarity
+
     coll, sim = small_world
     params = SearchParams(k=5, alpha=0.8, chunk_size=64, verify_batch=8)
     engine = KoiosSearch(coll, sim, params, partitions=2)   # schedule=fused
@@ -80,10 +84,23 @@ def test_fused_falls_back_to_overlap_off_tpu(small_world):
     assert np.array_equal(r_fused.ids, r_seq.ids)
     assert np.array_equal(r_fused.lb, r_seq.lb)
 
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ngram = NGramJaccardSimilarity(
+        (np.random.default_rng(0).random((coll.vocab_size, 64)) > 0.7)
+        .astype(np.float32))
+    with pytest.raises(ValueError, match="fused schedule"):
+        KoiosSearch(coll, ngram, params, partitions=2).search(q)
+    off = KoiosSearch(coll, ngram, dataclasses.replace(params, fused="off"),
+                      partitions=2)
+    off.search(q)
+    assert off.scheduler_stats.schedule == "overlap"
+
 
 def test_fused_with_mesh_exchange_identical(small_world):
-    """The fused schedule with the on-device all-reduce-max bound exchange
-    (single-device mesh: identity) changes no result."""
+    """The fused schedule with the mesh all-reduce-max bound exchange at
+    its exchange points (single-device mesh: identity) changes no
+    result."""
     from repro.launch.mesh import bound_exchange_mesh
     from repro.runtime.sharding import bound_exchange_for
 
@@ -92,7 +109,7 @@ def test_fused_with_mesh_exchange_identical(small_world):
     params = SearchParams(k=5, alpha=0.8, chunk_size=64, verify_batch=8,
                           fused="interpret")
     host = KoiosSearch(coll, sim, params, partitions=4)
-    meshed = KoiosSearch(coll, sim, params, partitions=4, mesh=mesh,
+    meshed = KoiosSearch(coll, sim, params, partitions=4,
                          bound_exchange=bound_exchange_for(mesh))
     queries = sample_queries(coll, 3, seed=41)
     for a, b in zip(host.search_batch(queries, schedule="fused"),

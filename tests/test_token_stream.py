@@ -82,7 +82,7 @@ def test_kernel_stream_parity(small_world):
     for alpha in (0.8, 0.95):
         provider = build_token_stream_batch(queries, sim, alpha)
         kernel = build_token_stream_batch(queries, sim, alpha,
-                                          use_kernel=True)
+                                          use_kernel=True, interpret=True)
         for a, b in zip(provider, kernel):
             assert np.array_equal(a.q_pos, b.q_pos)
             assert np.array_equal(a.token, b.token)
@@ -90,8 +90,9 @@ def test_kernel_stream_parity(small_world):
 
 
 def test_kernel_stream_end_to_end(small_world):
-    """A full engine run with ``stream_use_kernel`` returns bit-identical
-    results (the stream feeds every downstream bound)."""
+    """A full engine run with ``stream_use_kernel`` (interpret mode, asked
+    for with ``fused='interpret'``) returns bit-identical results (the
+    stream feeds every downstream bound)."""
     from repro.core import KoiosSearch, SearchParams
 
     coll, sim = small_world
@@ -100,7 +101,8 @@ def test_kernel_stream_end_to_end(small_world):
                                                verify_batch=8), partitions=2)
     kern = KoiosSearch(coll, sim, SearchParams(k=5, alpha=0.8, chunk_size=64,
                                                verify_batch=8,
-                                               stream_use_kernel=True),
+                                               stream_use_kernel=True,
+                                               fused="interpret"),
                        partitions=2)
     for a, b in zip(base.search_batch(queries), kern.search_batch(queries)):
         assert np.array_equal(a.ids, b.ids)
