@@ -139,10 +139,12 @@ def hungarian_score(w: jnp.ndarray) -> jnp.ndarray:
 
 @functools.partial(jax.jit, static_argnames=())
 def _hungarian_padded(w: jnp.ndarray, nq: jnp.ndarray, nc: jnp.ndarray):
-    cost = _pad_to_square_cost(w, nq, nc)
-    # only the nq logical rows can carry weight; augmenting just those is
-    # exact (see _solve_square_min) and much cheaper when |Q| << |C|
-    total, col4row, _, _ = _solve_square_min(cost, n_aug=nq)
+    with jax.named_scope("solver"):
+        cost = _pad_to_square_cost(w, nq, nc)
+        # only the nq logical rows can carry weight; augmenting just
+        # those is exact (see _solve_square_min) and much cheaper when
+        # |Q| << |C|
+        total, col4row, _, _ = _solve_square_min(cost, n_aug=nq)
     return -total, col4row
 
 

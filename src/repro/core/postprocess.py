@@ -41,6 +41,7 @@ from .matching.hungarian import hungarian_batch
 from .types import (SearchParams, SearchResult, SearchStats, SetCollection,
                     pad_ids_pow2, pow2)
 from ..runtime import instrument
+from ..runtime.instrument import span
 
 
 def _pad_pow2(n: int, lo: int = 8) -> int:
@@ -165,8 +166,9 @@ class VerifierPool:
         c_in = pad_ids_pow2(c_cat, lo=256)
         instrument.record("h2d:pairwise_dispatch")
         instrument.record("d2h:weights_materialize")
-        s = np.asarray(self.sim.pairwise(q_in, c_in))[:len(q_cat),
-                                                      :len(c_cat)]
+        s_dev = self.sim.pairwise(q_in, c_in)
+        with span("koios.device_wait", what="weights"):
+            s = np.asarray(s_dev)[:len(q_cat), :len(c_cat)]
         s = np.where(s >= self.params.alpha, s, 0.0).astype(np.float32)
         out = []
         for ri, ts in enumerate(toks):
@@ -195,40 +197,43 @@ class VerifierPool:
             key = (_pad_pow2(nq), self._c_pad)
             groups.setdefault(key, []).append(i)
         for (nq_pad, c_pad), idxs in groups.items():
-            rows = sum(len(entries[i][0]) for i in idxs)
-            # pow2 row padding above verify_batch: cross-query rounds shrink
-            # as queries finish, and an exact-fit B would recompile the
-            # solver every round (single-query batches stay <= verify_batch,
-            # i.e. exactly the historical shape)
-            B = _pad_pow2(rows, self.params.verify_batch)
-            w = np.zeros((B, nq_pad, c_pad), np.float32)
-            nqs = np.zeros(B, np.int32)
-            ncs = np.zeros(B, np.int32)
-            thetas = np.full(B, -np.inf, np.float32)
-            spans = {}
-            r = 0
-            for i in idxs:
-                mats, nq, theta = entries[i]
-                for m in mats:
-                    w[r, :m.shape[0], :m.shape[1]] = m
-                    nqs[r] = nq
-                    ncs[r] = m.shape[1]
-                    thetas[r] = theta
-                    r += 1
-                spans[i] = (r - len(mats), r)
+            with span("koios.verify.pack"):
+                rows = sum(len(entries[i][0]) for i in idxs)
+                # pow2 row padding above verify_batch: cross-query rounds
+                # shrink as queries finish, and an exact-fit B would
+                # recompile the solver every round (single-query batches
+                # stay <= verify_batch, i.e. exactly the historical shape)
+                B = _pad_pow2(rows, self.params.verify_batch)
+                w = np.zeros((B, nq_pad, c_pad), np.float32)
+                nqs = np.zeros(B, np.int32)
+                ncs = np.zeros(B, np.int32)
+                thetas = np.full(B, -np.inf, np.float32)
+                spans = {}
+                r = 0
+                for i in idxs:
+                    mats, nq, theta = entries[i]
+                    for m in mats:
+                        w[r, :m.shape[0], :m.shape[1]] = m
+                        nqs[r] = nq
+                        ncs[r] = m.shape[1]
+                        thetas[r] = theta
+                        r += 1
+                    spans[i] = (r - len(mats), r)
             yield w, nqs, ncs, thetas, spans
 
     def _exact_grouped(self, entries) -> List[np.ndarray]:
         """Exact SO per entry via shape-grouped ``hungarian_batch``."""
         out: List[Optional[np.ndarray]] = [None] * len(entries)
         for w, nqs, ncs, _thetas, spans in self._grouped(entries):
-            instrument.record("h2d:solver_dispatch")
-            instrument.record("d2h:solver_materialize")
-            so, _ = hungarian_batch(jnp.asarray(w), jnp.asarray(nqs),
-                                    jnp.asarray(ncs))
-            so = np.asarray(so)
-            for i, (lo, hi) in spans.items():
-                out[i] = so[lo:hi].copy()
+            with span("koios.verify.solve"):
+                instrument.record("h2d:solver_dispatch")
+                instrument.record("d2h:solver_materialize")
+                so, _ = hungarian_batch(jnp.asarray(w), jnp.asarray(nqs),
+                                        jnp.asarray(ncs))
+                with span("koios.device_wait", what="solver"):
+                    so = np.asarray(so)
+                for i, (lo, hi) in spans.items():
+                    out[i] = so[lo:hi].copy()
         return out
 
     # ------------------------------------------------------------- verify
@@ -239,7 +244,8 @@ class VerifierPool:
         Brackets are exact (lb == ub == SO) unless early-terminated, in
         which case ub < theta_lb certifies exclusion (Lemma 8).
         """
-        all_mats = self.weights_for_requests(requests)
+        with span("koios.verify.weights"):
+            all_mats = self.weights_for_requests(requests)
         entries = [(mats, len(r.query), float(r.theta_lb))
                    for mats, r in zip(all_mats, requests)]
 
@@ -251,21 +257,23 @@ class VerifierPool:
 
         outcomes: List[Optional[VerifyOutcome]] = [None] * len(requests)
         for w, nqs, ncs, thetas, spans in self._grouped(entries):
-            instrument.record("h2d:solver_dispatch")
-            instrument.record("d2h:solver_materialize")
-            res = auction_batch(jnp.asarray(w), jnp.asarray(nqs),
-                                jnp.asarray(ncs), self.eps_schedule,
-                                jnp.asarray(thetas))
-            lb_all = np.asarray(res.lb)
-            ub_all = np.asarray(res.ub)
-            early_all = np.asarray(res.early_stopped)
-            for i, (lo, hi) in spans.items():
-                out = VerifyOutcome(lb=lb_all[lo:hi].copy(),
-                                    ub=ub_all[lo:hi].copy(),
-                                    early=early_all[lo:hi].copy())
-                out.n_early = int(out.early.sum())
-                out.n_full = int((~out.early).sum())
-                outcomes[i] = out
+            with span("koios.verify.solve"):
+                instrument.record("h2d:solver_dispatch")
+                instrument.record("d2h:solver_materialize")
+                res = auction_batch(jnp.asarray(w), jnp.asarray(nqs),
+                                    jnp.asarray(ncs), self.eps_schedule,
+                                    jnp.asarray(thetas))
+                with span("koios.device_wait", what="solver"):
+                    lb_all = np.asarray(res.lb)
+                    ub_all = np.asarray(res.ub)
+                    early_all = np.asarray(res.early_stopped)
+                for i, (lo, hi) in spans.items():
+                    out = VerifyOutcome(lb=lb_all[lo:hi].copy(),
+                                        ub=ub_all[lo:hi].copy(),
+                                        early=early_all[lo:hi].copy())
+                    out.n_early = int(out.early.sum())
+                    out.n_full = int((~out.early).sum())
+                    outcomes[i] = out
 
         # exact fallback for brackets that straddle theta_lb (cannot decide);
         # hybrid mode also tightens any non-degenerate bracket so downstream
@@ -472,18 +480,19 @@ def drive_states(pool: VerifierPool, states: Sequence[PostprocessState],
     before the states emit their next requests.  Single-query
     post-processing, the batched pipeline, and the partition scheduler are
     all this loop with different state lists."""
-    reqs = {i: st.next_request() for i, st in enumerate(states)}
-    while True:
-        active = [i for i, r in reqs.items() if r is not None]
-        if not active:
-            break
-        outs = pool.verify_requests([reqs[i] for i in active])
-        for i, out in zip(active, outs):
-            states[i].apply(out)
-        if round_hook is not None:
-            round_hook(len(active))
-        for i in active:
-            reqs[i] = states[i].next_request()
+    with span("koios.verify"):
+        reqs = {i: st.next_request() for i, st in enumerate(states)}
+        while True:
+            active = [i for i, r in reqs.items() if r is not None]
+            if not active:
+                break
+            outs = pool.verify_requests([reqs[i] for i in active])
+            for i, out in zip(active, outs):
+                states[i].apply(out)
+            if round_hook is not None:
+                round_hook(len(active))
+            for i in active:
+                reqs[i] = states[i].next_request()
 
 
 def run_postprocess(coll: SetCollection, query: np.ndarray, sim_provider,

@@ -46,6 +46,7 @@ from .refinement import _dispatch_refinement, _materialize_refinement
 from .token_stream import build_token_stream_batch, expand_to_events
 from .types import (SearchParams, SearchResult, SearchStats, SetCollection)
 from ..runtime import instrument
+from ..runtime.instrument import span
 
 
 def _build_streams(plan: "ExecutionPlan", sim, params: SearchParams,
@@ -248,6 +249,13 @@ def _finish_tile(tile: _Tile, id_offset: int) -> None:
     tile.result = SearchResult(
         ids=(r.ids + id_offset).astype(np.int32),
         lb=r.lb, ub=r.ub, stats=r.stats)
+    st = r.stats
+    instrument.record("filter:candidates", st.candidates)
+    instrument.record("filter:pruned_refinement", st.pruned_refinement)
+    instrument.record("filter:pruned_postprocess", st.pruned_postprocess)
+    instrument.record("filter:no_em", st.pruned_no_em)
+    instrument.record("filter:em_early", st.pruned_em_early)
+    instrument.record("filter:em_full", st.exact_matches)
 
 
 def run_plan(plan: ExecutionPlan, sim_provider, params: SearchParams,
@@ -422,30 +430,34 @@ def run_fused_wave(plan: ExecutionPlan, tiles: Sequence[_Tile], streams,
     queries = [plan.queries[t.qi] for t in tiles]
     wave_streams = [streams[t.qi] for t in tiles]
     theta0 = np.asarray([theta[t.qi] for t in tiles], np.float64)
-    theta_dev = runner.init_theta(theta0, _pow2(max(1, len(queries))))
-    launch, theta_dev = runner.launch_wave(index, queries, wave_streams,
-                                           theta_dev)
+    with span("koios.wave.launch"):
+        theta_dev = runner.init_theta(theta0, _pow2(max(1, len(queries))))
+        launch, theta_dev = runner.launch_wave(index, queries, wave_streams,
+                                               theta_dev)
     plan.stats.waves += 1
     plan.stats.device_rounds += launch.cfg.rounds
-    out = runner.materialize(launch)
-    instrument.record("d2h:theta_materialize")
-    theta_out = np.maximum(theta0, np.asarray(theta_dev,
-                                              np.float64)[:len(queries)])
+    with span("koios.device_wait", what="wave"):
+        out = runner.materialize(launch)
+        instrument.record("d2h:theta_materialize")
+        theta_out = np.maximum(theta0, np.asarray(theta_dev,
+                                                  np.float64)[:len(queries)])
     live = []
-    for row, t in enumerate(tiles):
-        # theta carries fold the on-device exchange back in (monotone)
-        theta[t.qi] = max(theta[t.qi], float(theta_out[row]))
-        if _wave_tile_state(t, row, launch, out, plan.queries[t.qi],
-                            theta_out[row], params):
-            live.append(t)
+    with span("koios.resume"):
+        for row, t in enumerate(tiles):
+            # theta carries fold the on-device exchange back in (monotone)
+            theta[t.qi] = max(theta[t.qi], float(theta_out[row]))
+            if _wave_tile_state(t, row, launch, out, plan.queries[t.qi],
+                                theta_out[row], params):
+                live.append(t)
     drive_states(pool, [t.state for t in live],
                  round_hook=lambda n: _count_round(plan, n))
-    for t in live:
-        _finish_tile(t, t.index.id_offset)
-    for t in tiles:
-        if len(t.result.lb) >= params.k:
-            theta[t.qi] = max(theta[t.qi],
-                              float(t.result.lb[params.k - 1]))
+    with span("koios.finish"):
+        for t in live:
+            _finish_tile(t, t.index.id_offset)
+        for t in tiles:
+            if len(t.result.lb) >= params.k:
+                theta[t.qi] = max(theta[t.qi],
+                                  float(t.result.lb[params.k - 1]))
 
 
 def _run_fused(plan: ExecutionPlan, sim, params: SearchParams,

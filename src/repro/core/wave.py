@@ -344,12 +344,14 @@ def _wave_fn(cfg: WaveConfig):
                 st, cap, alpha, cfg.k, cfg.ub_mode)
             return S, ub, seen, alive, th, jnp.sum(killed) + killed_f
 
-        S, ub0, seen, alive, th_ref, pruned_ref = jax.vmap(refine)(
-            st_tok, st_q, st_sim, nqs)
+        with jax.named_scope("wave.refine"):
+            S, ub0, seen, alive, th_ref, pruned_ref = jax.vmap(refine)(
+                st_tok, st_q, st_sim, nqs)
 
         # ---- Stage B: candidate compaction ----
-        surv = seen & alive
-        surv_idx, surv_cnt = jax.vmap(compact_indices)(surv)
+        with jax.named_scope("wave.compact"):
+            surv = seen & alive
+            surv_idx, surv_cnt = jax.vmap(compact_indices)(surv)
 
         # ---- Stage C: theta update ----
         theta = jnp.maximum(theta, th_ref)
@@ -368,11 +370,12 @@ def _wave_fn(cfg: WaveConfig):
             return (lb, ub, live, verified, theta,
                     c_post + dp, c_early + de, c_full + df), None
 
-        (lb, ub, live, verified, theta, c_post, c_early, c_full), _ = \
-            jax.lax.scan(round_step,
-                         (lb, ub, live, verified, theta,
-                          zeros, zeros, zeros),
-                         None, length=cfg.rounds)
+        with jax.named_scope("wave.verify"):
+            (lb, ub, live, verified, theta, c_post, c_early, c_full), _ = \
+                jax.lax.scan(round_step,
+                             (lb, ub, live, verified, theta,
+                              zeros, zeros, zeros),
+                             None, length=cfg.rounds)
 
         return (surv_idx, surv_cnt, lb, ub, live, verified,
                 jnp.sum(seen, axis=1), pruned_ref,

@@ -58,7 +58,7 @@ from ..core.types import (QueryValidationError, SearchParams, SearchResult,
                           SearchStats, validate_query)
 from .fault import (FaultConfig, FaultPlan, FleetMonitor, ReplicaCrash,
                     TransientVerifierError)
-from .instrument import EngineCounters, RequestTrace, record
+from .instrument import EngineCounters, RequestTrace, record, span
 
 
 def _void_result() -> SearchResult:
@@ -315,37 +315,37 @@ class RequestEngine:
         """Coalesce queued requests into the in-flight cohort: fetch or
         build their streams (one stacked sweep for all of a step's
         misses) and absorb them into the plan mid-flight."""
-        room = self.max_wave_requests - len(self._inflight)
-        if room <= 0 or not self._queue:
-            return
-        self._queue.sort(key=_Request.priority)
-        joiners, self._queue = self._queue[:room], self._queue[room:]
-        queries = [r.query for r in joiners]
-        # per-request hit attribution: a duplicate of a query earlier in
-        # the same join is served without a sweep too (matches the cache
-        # counters' accounting of duplicate misses)
-        hits, seen = [], set()
-        for q in queries:
-            key = self.stream_cache.key(q, self.params.alpha, self.sim)
-            hits.append(self.stream_cache.contains(key) or key in seen)
-            seen.add(key)
-        streams = build_token_stream_batch_cached(
-            queries, self.sim, self.params.alpha, self.stream_cache,
-            use_kernel=self.params.stream_use_kernel,
-            interpret=self.params.interpret)
-        t_stream = self.clock()
-        qis, new_tiles = self.plan.add_queries(queries)
-        for t in new_tiles:
-            self._tiles.setdefault(t.qi, {})[t.pi] = t
-        self._streams.extend(streams)
-        self._theta.extend([0.0] * len(joiners))
-        for req, qi, hit in zip(joiners, qis, hits):
-            req.qi = qi
-            req.epoch = self._epoch.epoch
-            req.pending = list(range(len(self.partitions)))
-            req.trace.t_stream = t_stream
-            req.trace.stream_hit = bool(hit)
-            self._inflight[req.rid] = req
+        with span("koios.join"):
+            room = self.max_wave_requests - len(self._inflight)
+            if room <= 0 or not self._queue:
+                return
+            self._queue.sort(key=_Request.priority)
+            joiners, self._queue = self._queue[:room], self._queue[room:]
+            queries = [r.query for r in joiners]
+            # per-request hit attribution: a duplicate of a query earlier in
+            # the same join is served without a sweep too (matches the cache
+            # counters' accounting of duplicate misses)
+            hits, seen = [], set()
+            for q in queries:
+                key = self.stream_cache.key(q, self.params.alpha, self.sim)
+                hits.append(self.stream_cache.contains(key) or key in seen)
+                seen.add(key)
+            with span("koios.stream"):
+                streams = build_token_stream_batch_cached(
+                    queries, self.sim, self.params.alpha, self.stream_cache,
+                    use_kernel=self.params.stream_use_kernel,
+                    interpret=self.params.interpret)
+            qis, new_tiles = self.plan.add_queries(queries)
+            for t in new_tiles:
+                self._tiles.setdefault(t.qi, {})[t.pi] = t
+            self._streams.extend(streams)
+            self._theta.extend([0.0] * len(joiners))
+            for req, qi, hit in zip(joiners, qis, hits):
+                req.qi = qi
+                req.epoch = self._epoch.epoch
+                req.pending = list(range(len(self.partitions)))
+                req.trace.stream_hit = bool(hit)
+                self._inflight[req.rid] = req
 
     # -------------------------------------------------------------- waves
     def _run_wave_tiles(self, tiles) -> None:
@@ -354,9 +354,10 @@ class RequestEngine:
             for t in tiles:
                 by_pi.setdefault(t.pi, []).append(t)
             for pi in sorted(by_pi):
-                run_fused_wave(self.plan, by_pi[pi], self._streams,
-                               self._theta, self.pool, self.params,
-                               self._runner)
+                with span("koios.wave", shard=pi, B=len(by_pi[pi])):
+                    run_fused_wave(self.plan, by_pi[pi], self._streams,
+                                   self._theta, self.pool, self.params,
+                                   self._runner)
         else:
             run_wave(self.plan, tiles, self._streams, self._theta,
                      self.pool, self.params)
@@ -376,6 +377,12 @@ class RequestEngine:
         step's responses.  Each step heartbeats into the attached
         :class:`FleetMonitor` (the router's health plane) and fires any
         :class:`FaultPlan` events addressed to this replica+step."""
+        with span("koios.step", step=self._step_no + 1) as sp:
+            out = self._step()
+            sp.annotate(wave=self._last_wave)
+        return out
+
+    def _step(self) -> List[EngineResponse]:
         t_enter = self.clock()
         self._step_no += 1
         self._last_wave = 0
@@ -534,16 +541,17 @@ class RequestEngine:
 
     # ------------------------------------------------------------ respond
     def _respond(self, req: _Request, t_done: float) -> None:
-        result = merge_topk([req.parts[pi] for pi in sorted(req.parts)],
-                            self.params.k)
-        req.trace.t_respond = t_done
-        self.counters.observe_respond(req.trace)
-        self._completed.append(EngineResponse(
-            rid=req.rid, result=result,
-            latency_s=req.trace.latency_s, queue_s=req.trace.queue_s,
-            waves=req.trace.waves, stream_hit=req.trace.stream_hit,
-            deadline_met=req.trace.deadline_met, epoch=req.epoch))
-        self._retire(req)
+        with span("koios.respond"):
+            result = merge_topk([req.parts[pi] for pi in sorted(req.parts)],
+                                self.params.k)
+            req.trace.t_respond = t_done
+            self.counters.observe_respond(req.trace)
+            self._completed.append(EngineResponse(
+                rid=req.rid, result=result,
+                latency_s=req.trace.latency_s, queue_s=req.trace.queue_s,
+                waves=req.trace.waves, stream_hit=req.trace.stream_hit,
+                deadline_met=req.trace.deadline_met, epoch=req.epoch))
+            self._retire(req)
 
     def _retire(self, req: _Request) -> None:
         """Release a joined request's plan/stream/tile state."""

@@ -1,4 +1,5 @@
-"""Host<->device dispatch accounting + request-engine counters.
+"""Host<->device dispatch accounting, program spans, request-engine
+counters.
 
 The fused wave program's whole point is eliminating host round-trips
 (DESIGN.md §3 / §9 item 6 resolution), so the benchmark needs a number
@@ -9,8 +10,19 @@ search path calls :func:`record` with an event tag.  Outside a
 hot path pays nothing).
 
 Tags follow ``<direction>:<site>``: ``h2d`` = a program dispatch,
-``d2h`` = a blocking device-to-host materialization.  The A/B in
-``benchmarks/response_time.py --fused`` reports the per-direction sums.
+``d2h`` = a blocking device-to-host materialization; ``filter:<stage>``
+counts a finished tile's candidates per filter stage (the paper's
+Tables II/IV/V funnel).  The benchmark (``bench/run.py``) copies the
+counter into every result; its per-layer metrics read the tags.
+
+:func:`span` marks a phase of the host's work.  Each span is a
+``jax.profiler.TraceAnnotation`` (so a profiler trace shows it on the
+host plane, on the device trace's clock) and, inside ``counting()``,
+three counter entries: ``span_n:<name>`` (entries), ``span_ns:<name>``
+(total nanoseconds) and ``self_ns:<name>`` (nanoseconds not covered by
+a child span of the same thread).  Every program span name starts with
+``koios.`` and is listed in :data:`SPANS`.  With no counter and no
+profiler a span costs two checks and returns a shared no-op.
 
 :class:`EngineCounters` is the request engine's per-request / per-wave
 instrumentation (DESIGN.md §3.2): true admit->respond latencies (the
@@ -22,10 +34,23 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import threading
+import time
 from collections import Counter
 from typing import Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 _ACTIVE: Optional[Counter] = None
+_clock = time.perf_counter_ns
+_local = threading.local()
+
+# every program span, outermost first (PERF.md §3 names the metric that
+# reads each)
+SPANS = ("koios.step", "koios.join", "koios.stream", "koios.wave",
+         "koios.wave.launch", "koios.device_wait", "koios.resume",
+         "koios.verify", "koios.verify.weights", "koios.verify.pack",
+         "koios.verify.solve", "koios.finish", "koios.respond")
 
 
 def record(event: str, n: int = 1) -> None:
@@ -47,6 +72,74 @@ def counting() -> Iterator[Counter]:
         _ACTIVE = prev
 
 
+class _Span:
+    """One entered span: the annotation and, inside ``counting()``, its
+    timing against the thread's stack of open spans."""
+
+    __slots__ = ("name", "attrs", "ann", "counts", "t0", "child")
+
+    def __init__(self, name: str, attrs: dict):
+        self.name, self.attrs = name, attrs
+
+    def __enter__(self) -> "_Span":
+        self.ann = None
+        if TraceAnnotation.is_enabled():
+            self.ann = TraceAnnotation(self.name, **self.attrs)
+            self.ann.__enter__()
+        self.counts = _ACTIVE
+        if self.counts is not None:
+            stack = getattr(_local, "stack", None)
+            if stack is None:
+                stack = _local.stack = []
+            stack.append(self)
+            self.child = 0
+            self.t0 = _clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self.counts is not None:
+            dt = _clock() - self.t0
+            stack = _local.stack
+            stack.pop()
+            if stack:
+                stack[-1].child += dt
+            c, n = self.counts, self.name
+            c["span_n:" + n] += 1
+            c["span_ns:" + n] += dt
+            c["self_ns:" + n] += dt - self.child
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+
+    def annotate(self, **attrs) -> None:
+        """Attach attributes known only inside the span."""
+        if self.ann is not None:
+            self.ann.set_metadata(**attrs)
+
+
+class _Off:
+    """The span of a process with no counter and no profiler."""
+
+    def __enter__(self) -> "_Off":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+    def annotate(self, **attrs) -> None:
+        pass
+
+
+_OFF = _Off()
+
+
+def span(name: str, **attrs):
+    """Context manager marking a phase of host work (module docstring).
+    Use per phase or per round, never per candidate."""
+    if _ACTIVE is None and not TraceAnnotation.is_enabled():
+        return _OFF
+    return _Span(name, attrs)
+
+
 def totals(counts: Counter) -> dict:
     """Per-direction sums plus the grand total of a counter's events."""
     h2d = sum(v for k, v in counts.items() if k.startswith("h2d:"))
@@ -62,7 +155,6 @@ class RequestTrace:
 
     rid: int
     t_admit: float
-    t_stream: float = 0.0          # stream ready (cache hit or built)
     t_first_wave: float = 0.0      # first wave that included the request
     t_respond: float = 0.0
     stream_hit: bool = False

@@ -1,0 +1,159 @@
+"""Program spans and counters (``repro.runtime.instrument``): nesting and
+self time, the off path, the names the benchmark's trace reducer and
+metrics rely on, and the spans and filter counters of one engine run."""
+import importlib.util
+import pathlib
+import re
+
+import pytest
+
+from repro.core import SearchParams
+from repro.data import sample_queries
+from repro.runtime import instrument
+from repro.runtime.engine import RequestEngine
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+SRC = ROOT / "src" / "repro"
+_spec = importlib.util.spec_from_file_location(
+    "bench_trace_reduce", ROOT / "bench" / "trace_reduce.py")
+trace_reduce = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(trace_reduce)
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    """An injected nanosecond clock: ``clock.t`` is what the next read
+    returns."""
+    class Clock:
+        t = 0
+
+        def __call__(self):
+            return self.t
+
+    c = Clock()
+    monkeypatch.setattr(instrument, "_clock", c)
+    return c
+
+
+def test_nested_spans_count_entries_totals_and_self_time(clock):
+    with instrument.counting() as c:
+        with instrument.span("koios.step"):           # 0 .. 100
+            clock.t = 10
+            with instrument.span("koios.wave", shard=0, B=2):  # 10 .. 70
+                clock.t = 15
+                with instrument.span("koios.device_wait", what="wave"):
+                    clock.t = 40                      # 15 .. 40
+                with instrument.span("koios.verify"):  # 40 .. 60
+                    clock.t = 45
+                    with instrument.span("koios.device_wait",
+                                         what="solver"):
+                        clock.t = 50                  # 45 .. 50
+                    clock.t = 60
+                clock.t = 70
+            clock.t = 100
+    assert c["span_n:koios.step"] == 1
+    assert c["span_n:koios.device_wait"] == 2
+    assert c["span_ns:koios.step"] == 100
+    assert c["self_ns:koios.step"] == 100 - 60
+    assert c["span_ns:koios.wave"] == 60
+    assert c["self_ns:koios.wave"] == 60 - 25 - 20
+    assert c["span_ns:koios.verify"] == 20
+    assert c["self_ns:koios.verify"] == 20 - 5
+    assert c["span_ns:koios.device_wait"] == 25 + 5
+    assert c["self_ns:koios.device_wait"] == 30
+    # self times add up to the outermost span, exactly
+    assert sum(v for k, v in c.items() if k.startswith("self_ns:")) == 100
+
+
+def test_a_span_still_closes_and_counts_when_its_block_raises(clock):
+    with instrument.counting() as c:
+        with pytest.raises(RuntimeError):
+            with instrument.span("koios.step"):
+                clock.t = 7
+                raise RuntimeError("boom")
+        with instrument.span("koios.join"):
+            clock.t = 9
+    assert c["span_ns:koios.step"] == 7
+    # the failed span left the stack: the next one is not its child
+    assert c["self_ns:koios.step"] == 7
+    assert c["span_ns:koios.join"] == 2
+
+
+def test_nothing_is_recorded_outside_counting(monkeypatch):
+    reads = []
+    monkeypatch.setattr(instrument, "_clock",
+                        lambda: reads.append(1) or 0)
+    sp = instrument.span("koios.step", step=1)
+    # no counter and no profiler: the shared no-op, no clock read
+    assert sp is instrument.span("koios.join")
+    with sp as inner:
+        inner.annotate(wave=3)
+        instrument.record("filter:candidates", 5)
+    assert reads == []
+    with instrument.counting() as c:
+        pass
+    with instrument.span("koios.step"):
+        instrument.record("h2d:solver_dispatch")
+    assert not c and reads == []
+
+
+def test_span_keys_stay_out_of_the_transfer_totals(clock):
+    with instrument.counting() as c:
+        for name in instrument.SPANS:
+            with instrument.span(name):
+                clock.t += 3
+        instrument.record("h2d:solver_dispatch")
+    keys = [k for k in c if "koios." in k]
+    assert len(keys) == 3 * len(instrument.SPANS)
+    assert not [k for k in keys if k.startswith(("h2d:", "d2h:"))]
+    assert instrument.totals(c)["total"] == 1
+
+
+def _span_literals():
+    pat = re.compile(r"""\bspan\(\s*["']([^"']+)["']""")
+    found = {}
+    for path in SRC.rglob("*.py"):
+        for name in pat.findall(path.read_text()):
+            found.setdefault(name, path.name)
+    return found
+
+
+def test_program_span_names_are_listed_and_apart_from_the_harness():
+    used = _span_literals()
+    assert set(used) == set(instrument.SPANS), used
+    for name in instrument.SPANS:
+        assert name.startswith("koios."), name
+        assert name not in trace_reduce.HOST_SPANS
+        assert name != trace_reduce.WINDOW
+
+
+def test_engine_step_yields_every_span_and_the_filter_funnel(small_world):
+    """One ``serve()`` on the fused schedule (interpret mode) under
+    ``counting()``: every program span is entered, the self times nest
+    inside the steps, and the filter counters equal the tiles'
+    ``SearchStats`` (summed into each response by the merge).  No
+    verification round runs in the wave, so the host continuation
+    verifies."""
+    coll, sim = small_world
+    params = SearchParams(k=5, alpha=0.8, chunk_size=64, verify_batch=8,
+                          fused="interpret", wave_rounds=0)
+    eng = RequestEngine(coll, sim, params, partitions=2, schedule="fused")
+    assert eng.schedule == "fused"
+    queries = sample_queries(coll, 4, seed=5)
+    with instrument.counting() as c:
+        out = eng.serve(queries)
+    assert len(out) == len(queries) and all(r.served for r in out)
+    for name in instrument.SPANS:
+        assert c[f"span_n:{name}"] > 0, name
+    selfs = sum(v for k, v in c.items() if k.startswith("self_ns:"))
+    assert selfs <= c["span_ns:koios.step"]
+    assert c["span_n:koios.step"] >= eng.counters.steps
+    stats = [r.result.stats for r in out]
+    for key, field in [("candidates", "candidates"),
+                       ("pruned_refinement", "pruned_refinement"),
+                       ("pruned_postprocess", "pruned_postprocess"),
+                       ("no_em", "pruned_no_em"),
+                       ("em_early", "pruned_em_early"),
+                       ("em_full", "exact_matches")]:
+        assert c[f"filter:{key}"] == sum(getattr(s, field) for s in stats)
+    assert c["filter:candidates"] > 0 and c["filter:em_full"] > 0
