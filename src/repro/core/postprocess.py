@@ -14,8 +14,11 @@ Survivors of the refinement carry bounds [lb, ub].  We repeatedly:
   5. stop when no unverified live set has ub > theta_lb; the answer is the
      top-k by lb.
 
-Verification recomputes the (|Q| x |C|) similarity block on the fly (MXU)
-instead of caching refinement similarities — see DESIGN.md §9 item 7.
+Verification recomputes each (|Q| x |C|) weight block on the device from
+the provider's table (``similarity.verify_weights``, the function the
+fused wave's device rounds call too) instead of caching refinement
+similarities.  The host packs token ids only; no similarity or weight
+block comes back to the host, only the solver's outputs.
 
 Multi-query serving (the batched pipeline): the loop above is factored into
 a :class:`PostprocessState` state machine that *requests* verification
@@ -38,8 +41,9 @@ import jax.numpy as jnp
 
 from .matching.auction import auction_batch, make_eps_schedule
 from .matching.hungarian import hungarian_batch
+from .similarity import device_weights
 from .types import (SearchParams, SearchResult, SearchStats, SetCollection,
-                    pad_ids_pow2, pow2)
+                    pow2)
 from ..runtime import instrument
 from ..runtime.instrument import span
 
@@ -47,6 +51,11 @@ from ..runtime.instrument import span
 def _pad_pow2(n: int, lo: int = 8) -> int:
     """Solver-batch bucket rounding (shared pow2 with an 8 floor)."""
     return pow2(n, lo)
+
+
+def _rows(spans: dict) -> int:
+    """Logical solver rows of a packed batch."""
+    return sum(hi - lo for lo, hi in spans.values())
 
 
 def _kth(x: np.ndarray, mask: np.ndarray, kk: int) -> float:
@@ -80,7 +89,8 @@ class VerifierPool:
     """Shared batched exact-SO verification across any number of queries.
 
     Every call packs all requests' (query, candidate-set) pairs into padded
-    weight tensors and runs one solver call per distinct padded shape —
+    token-id tensors, builds their weights on the device and runs one
+    solver call per distinct padded shape —
     the multi-query generalisation of the paper's verification thread pool.
     Shape grouping (pow2-padded |Q| and |C|) keeps the jit cache small AND
     guarantees each row reproduces its single-request numerics exactly.
@@ -103,133 +113,80 @@ class VerifierPool:
         # bucket — the dominant host<->device round-trip count of the
         # fused schedule's continuation (DESIGN.md §3.3).  The fused
         # wave pays the same cover for its dense operands
-        # (``wave._partition_operands``).
+        # (``ShardedCollection.wave_operands``).
         self._c_pad = _pad_pow2(
             int(coll.set_sizes.max()) if coll.num_sets else 1)
-
-    # ---------------------------------------------------------- weights
-    # Cap on the candidate tokens one fused pairwise call may cover: the
-    # fused matrix computes all requests' rows against all requests'
-    # columns, so its waste grows with the number of requests fused —
-    # chunking bounds that while typical serving batches still fuse into
-    # one dispatch.
-    _FUSE_TOKEN_CAP = 16384
-
-    def weights_for_requests(self, requests: Sequence[VerifyRequest]
-                             ) -> List[List[np.ndarray]]:
-        """Alpha-thresholded (|Q_r|, |C_i|) weight blocks per request,
-        fusing as many requests as the token cap allows per ``pairwise``
-        dispatch (typically all of them)."""
-        all_toks = [[self.coll.get_set(int(i)) for i in r.ids]
-                    for r in requests]
-        sizes = [sum(len(t) for t in ts) for ts in all_toks]
-        out: List[List[np.ndarray]] = []
-        lo = 0
-        while lo < len(requests):
-            hi, tot = lo + 1, sizes[lo]
-            while hi < len(requests) and tot + sizes[hi] <= self._FUSE_TOKEN_CAP:
-                tot += sizes[hi]
-                hi += 1
-            out.extend(self._fused_weights(requests[lo:hi],
-                                           all_toks[lo:hi]))
-            lo = hi
-        return out
-
-    def _fused_weights(self, requests: Sequence[VerifyRequest], toks
-                       ) -> List[List[np.ndarray]]:
-        """One ``pairwise`` dispatch for a run of requests.
-
-        All queries' elements stack into the row axis and all candidate
-        sets' tokens into the column axis; each request then slices its own
-        (rows, per-set columns) blocks.  Every element is the same
-        independent d-dim dot product as a per-set call, so the blocks are
-        bit-identical to per-request (and per-set) weight computation.
-        """
-        assert all(ts for ts in toks), "empty verification request"
-        q_cuts = np.zeros(len(requests) + 1, np.int64)
-        np.cumsum([len(r.query) for r in requests], out=q_cuts[1:])
-        c_cuts = np.zeros(len(requests) + 1, np.int64)
-        np.cumsum([sum(len(t) for t in ts) for ts in toks], out=c_cuts[1:])
-        q_cat = np.concatenate([np.asarray(r.query, np.int32)
-                                for r in requests])
-        c_cat = np.concatenate([t for ts in toks for t in ts])
-        # pow2 row/col buckets: the fused pairwise shape is otherwise a
-        # function of the round's request mix, and steady-state serving
-        # (arbitrary cohort coalitions) would compile a fresh program per
-        # composition.  Rows/cols of the similarity are independent
-        # (row-wise normalize, per-pair dots), so pad entries change no
-        # retained value — the slice drops them before use.
-        # coarse floors (32 rows / 256 cols) keep the whole bucket grid
-        # small enough to warm at engine startup; the extra pad work is
-        # one tiny matmul block
-        q_in = pad_ids_pow2(q_cat, lo=32)
-        c_in = pad_ids_pow2(c_cat, lo=256)
-        instrument.record("h2d:pairwise_dispatch")
-        instrument.record("d2h:weights_materialize")
-        s_dev = self.sim.pairwise(q_in, c_in)
-        with span("koios.device_wait", what="weights"):
-            s = np.asarray(s_dev)[:len(q_cat), :len(c_cat)]
-        s = np.where(s >= self.params.alpha, s, 0.0).astype(np.float32)
-        out = []
-        for ri, ts in enumerate(toks):
-            block = s[q_cuts[ri]:q_cuts[ri + 1], c_cuts[ri]:c_cuts[ri + 1]]
-            cuts = np.zeros(len(ts) + 1, np.int64)
-            np.cumsum([len(t) for t in ts], out=cuts[1:])
-            out.append([block[:, cuts[i]:cuts[i + 1]]
-                        for i in range(len(ts))])
-        return out
-
-    def weights_for(self, query: np.ndarray, ids) -> List[np.ndarray]:
-        """Weight blocks of one (query, candidate batch) pair."""
-        return self.weights_for_requests(
-            [VerifyRequest(np.asarray(query, np.int32), np.asarray(ids),
-                           float("-inf"))])[0]
+        self._alpha = np.float32(params.alpha)
 
     # ---------------------------------------------------- batch building
-    def _grouped(self, entries):
-        """Pack entries = [(mats, nq, theta), ...] into padded solver
-        batches, one per distinct (nq_pad, c_pad) shape.  Yields
-        (w, nqs, ncs, thetas, spans) with spans[i] = row range of entry i.
-        Rows are independent under vmap, so batch composition never
-        changes a row's result."""
+    def _candidate_tokens(self, ids: np.ndarray, B: int):
+        """(B, c_pad) int32 token ids of candidate sets ``ids`` (one row
+        each, -1 padding) and their (B,) logical sizes."""
+        indptr = self.coll.set_indptr
+        starts = indptr[ids]
+        lens = (indptr[ids + 1] - starts).astype(np.int32)
+        row = np.repeat(np.arange(len(ids)), lens)
+        first = np.repeat(np.cumsum(lens) - lens, lens)
+        col = np.arange(len(row)) - first
+        c_tok = np.full((B, self._c_pad), -1, np.int32)
+        c_tok[row, col] = self.coll.set_tokens[np.repeat(starts, lens) + col]
+        ncs = np.zeros(B, np.int32)
+        ncs[:len(ids)] = lens
+        return c_tok, ncs
+
+    def _grouped(self, requests: Sequence[VerifyRequest]):
+        """Pack requests into padded solver batches, one per distinct
+        (nq_pad, c_pad) shape, and launch each batch's weight program.
+        Yields (w, nqs, ncs, thetas, spans) with w the (B, nq_pad, c_pad)
+        device weights, nqs/ncs the (B,) logical sizes on the device,
+        thetas the (B,) host thresholds and spans[i] = row range of
+        request i.  Rows are independent under vmap, so batch composition
+        never changes a row's result."""
         groups: dict = {}
-        for i, (mats, nq, _theta) in enumerate(entries):
-            key = (_pad_pow2(nq), self._c_pad)
+        for i, r in enumerate(requests):
+            key = _pad_pow2(len(r.query))
             groups.setdefault(key, []).append(i)
-        for (nq_pad, c_pad), idxs in groups.items():
+        for nq_pad, idxs in groups.items():
             with span("koios.verify.pack"):
-                rows = sum(len(entries[i][0]) for i in idxs)
+                rows = sum(len(requests[i].ids) for i in idxs)
                 # pow2 row padding above verify_batch: cross-query rounds
                 # shrink as queries finish, and an exact-fit B would
                 # recompile the solver every round (single-query batches
                 # stay <= verify_batch, i.e. exactly the historical shape)
                 B = _pad_pow2(rows, self.params.verify_batch)
-                w = np.zeros((B, nq_pad, c_pad), np.float32)
+                q_tok = np.full((B, nq_pad), -1, np.int32)
                 nqs = np.zeros(B, np.int32)
-                ncs = np.zeros(B, np.int32)
                 thetas = np.full(B, -np.inf, np.float32)
                 spans = {}
                 r = 0
                 for i in idxs:
-                    mats, nq, theta = entries[i]
-                    for m in mats:
-                        w[r, :m.shape[0], :m.shape[1]] = m
-                        nqs[r] = nq
-                        ncs[r] = m.shape[1]
-                        thetas[r] = theta
-                        r += 1
-                    spans[i] = (r - len(mats), r)
-            yield w, nqs, ncs, thetas, spans
+                    req = requests[i]
+                    n, nq = len(req.ids), len(req.query)
+                    q_tok[r:r + n, :nq] = req.query
+                    nqs[r:r + n] = nq
+                    thetas[r:r + n] = req.theta_lb
+                    spans[i] = (r, r + n)
+                    r += n
+                ids = np.concatenate([np.asarray(requests[i].ids, np.int64)
+                                      for i in idxs])
+            with span("koios.verify.weights"):
+                c_tok, ncs = self._candidate_tokens(ids, B)
+                nqs_d, ncs_d = jnp.asarray(nqs), jnp.asarray(ncs)
+                instrument.record("verify:device_weight_rows", rows)
+                w = device_weights(self.sim.row_blocks, self.sim.block_table,
+                                   q_tok, c_tok, nqs_d, ncs_d, self._alpha)
+            yield w, nqs_d, ncs_d, thetas, spans
 
-    def _exact_grouped(self, entries) -> List[np.ndarray]:
-        """Exact SO per entry via shape-grouped ``hungarian_batch``."""
-        out: List[Optional[np.ndarray]] = [None] * len(entries)
-        for w, nqs, ncs, _thetas, spans in self._grouped(entries):
+    def _exact_grouped(self, requests: Sequence[VerifyRequest]
+                       ) -> List[np.ndarray]:
+        """Exact SO per request via shape-grouped ``hungarian_batch``."""
+        out: List[Optional[np.ndarray]] = [None] * len(requests)
+        for w, nqs, ncs, _thetas, spans in self._grouped(requests):
             with span("koios.verify.solve"):
                 instrument.record("h2d:solver_dispatch")
                 instrument.record("d2h:solver_materialize")
-                so, _ = hungarian_batch(jnp.asarray(w), jnp.asarray(nqs),
-                                        jnp.asarray(ncs))
+                instrument.record("verify:solver_rows", _rows(spans))
+                so, _ = hungarian_batch(w, nqs, ncs)
                 with span("koios.device_wait", what="solver"):
                     so = np.asarray(so)
                 for i, (lo, hi) in spans.items():
@@ -244,24 +201,19 @@ class VerifierPool:
         Brackets are exact (lb == ub == SO) unless early-terminated, in
         which case ub < theta_lb certifies exclusion (Lemma 8).
         """
-        with span("koios.verify.weights"):
-            all_mats = self.weights_for_requests(requests)
-        entries = [(mats, len(r.query), float(r.theta_lb))
-                   for mats, r in zip(all_mats, requests)]
-
         if self.params.verifier == "hungarian":
             return [VerifyOutcome(lb=so, ub=so.copy(),
                                   early=np.zeros(len(so), bool),
                                   n_full=len(so))
-                    for so in self._exact_grouped(entries)]
+                    for so in self._exact_grouped(requests)]
 
         outcomes: List[Optional[VerifyOutcome]] = [None] * len(requests)
-        for w, nqs, ncs, thetas, spans in self._grouped(entries):
+        for w, nqs, ncs, thetas, spans in self._grouped(requests):
             with span("koios.verify.solve"):
                 instrument.record("h2d:solver_dispatch")
                 instrument.record("d2h:solver_materialize")
-                res = auction_batch(jnp.asarray(w), jnp.asarray(nqs),
-                                    jnp.asarray(ncs), self.eps_schedule,
+                instrument.record("verify:solver_rows", _rows(spans))
+                res = auction_batch(w, nqs, ncs, self.eps_schedule,
                                     jnp.asarray(thetas))
                 with span("koios.device_wait", what="solver"):
                     lb_all = np.asarray(res.lb)
@@ -287,8 +239,9 @@ class VerifierPool:
             if amb.any():
                 fallback.append((i, amb))
         if fallback:
-            sub = [( [entries[i][0][j] for j in amb.nonzero()[0]],
-                    entries[i][1], float("-inf")) for i, amb in fallback]
+            sub = [VerifyRequest(requests[i].query,
+                                 np.asarray(requests[i].ids)[amb],
+                                 float("-inf")) for i, amb in fallback]
             for (i, amb), so in zip(fallback, self._exact_grouped(sub)):
                 out = outcomes[i]
                 out.lb[amb] = so
@@ -308,9 +261,6 @@ class Verifier:
         self.query = np.asarray(query, dtype=np.int32)
         self.stats_em_early = 0
         self.stats_em_full = 0
-
-    def weight_matrix(self, set_id: int) -> np.ndarray:
-        return self.pool.weights_for(self.query, [set_id])[0]
 
     def verify(self, ids, theta_lb: float):
         out = self.pool.verify_requests(

@@ -17,6 +17,8 @@ provide both:
 Both expose the interface the search engine needs:
   - ``pairwise(q_ids, t_ids)``        -> dense sim block
   - ``query_vs_vocab_block(q_ids, lo, hi)`` -> sim block against vocab slice
+  - ``row_blocks(table, q_tok, c_tok)`` over ``block_table`` -> per-row
+    sim blocks, the input of :func:`verify_weights`
 
 Identity pairs are clamped to exactly 1.0 (Def. 1) which also implements the
 paper's out-of-vocabulary rule: identical tokens count with similarity one
@@ -51,8 +53,8 @@ def cosine_rows(qn: jnp.ndarray, tn: jnp.ndarray) -> jnp.ndarray:
 _cosine_block = jax.jit(cosine_rows)
 
 
-@jax.jit
-def _jaccard_block(qv: jnp.ndarray, tv: jnp.ndarray) -> jnp.ndarray:
+def jaccard_rows(qv: jnp.ndarray, tv: jnp.ndarray) -> jnp.ndarray:
+    """(m, g) x (n, g) binary incidence rows -> (m, n) Jaccard."""
     inter = qv @ tv.T
     qa = jnp.sum(qv, axis=-1, keepdims=True)
     tb = jnp.sum(tv, axis=-1, keepdims=True)
@@ -60,15 +62,83 @@ def _jaccard_block(qv: jnp.ndarray, tv: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(union > 0, inter / jnp.maximum(union, 1.0), 0.0)
 
 
+_jaccard_block = jax.jit(jaccard_rows)
+
+
+def cosine_row_blocks(table_n, q_tok, c_tok) -> jnp.ndarray:
+    """Per-row cosine blocks over a normalized table: (B, n) non-negative
+    candidate token ids against (B, m) query ids, or against one (m,)
+    query every row shares (one contraction, the query rows gathered
+    once) -> (B, m, n)."""
+    tv = table_n[c_tok]
+    if q_tok.ndim == 1:
+        B, n, d = tv.shape
+        s = cosine_rows(table_n[q_tok], tv.reshape(B * n, d))
+        return s.reshape(-1, B, n).transpose(1, 0, 2)
+    return jax.vmap(cosine_rows)(table_n[q_tok], tv)
+
+
+def jaccard_row_blocks(table, q_tok, c_tok) -> jnp.ndarray:
+    """Per-row n-gram Jaccard blocks over an incidence table, clipped to
+    [0, 1] as :meth:`NGramJaccardSimilarity.pairwise` clips them; a (m,)
+    query is shared by every row."""
+    q_tok = jnp.broadcast_to(q_tok, c_tok.shape[:1] + q_tok.shape[-1:])
+    return jnp.clip(jax.vmap(jaccard_rows)(table[q_tok], table[c_tok]),
+                    0.0, 1.0)
+
+
+def verify_weights(row_blocks, table, q_tok, c_tok, nqs, ncs, alpha
+                   ) -> jnp.ndarray:
+    """Alpha-thresholded verification weights, one block per row.
+
+    The one weight construction of the system: the fused wave's device
+    rounds and the host continuation's solver batches both call it.
+    ``row_blocks(table, q, c)`` is the provider's per-row similarity
+    block (:func:`cosine_row_blocks`, :func:`jaccard_row_blocks`).
+    q_tok: (B, nq_pad) int32, or (nq_pad,) when every row verifies the
+    same query (the wave's rounds); c_tok: (B, c_pad) int32; both -1
+    padded.  nqs (B,) or scalar, ncs (B,): logical |Q| and |C|.  Returns
+    (B, nq_pad, c_pad) float32: identical tokens fixed to 1.0 (Def. 1),
+    entries below alpha zeroed, zero outside each row's logical block.
+    A row's weights depend on its own tokens only, never on the rows
+    beside it.
+    """
+    # the contraction sees at least one 128-lane tile of columns: on TPU
+    # that is the layout's own padding, and on the CPU it keeps every
+    # entry on the matmul kernel of a wide block, so no entry depends on
+    # how narrow the collection's sets are
+    c_pad = c_tok.shape[1]
+    c_in = jnp.pad(jnp.maximum(c_tok, 0), ((0, 0), (0, max(0, 128 - c_pad))))
+    s = row_blocks(table, jnp.maximum(q_tok, 0), c_in)[:, :, :c_pad]
+    q_tok = jnp.broadcast_to(q_tok, c_tok.shape[:1] + q_tok.shape[-1:])
+    same = (q_tok[:, :, None] == c_tok[:, None, :]) \
+        & (q_tok >= 0)[:, :, None] & (c_tok >= 0)[:, None, :]
+    s = jnp.where(same, 1.0, s)
+    w = jnp.where(s >= alpha, s, 0.0)
+    row_ok = jnp.arange(q_tok.shape[1])[None, :] < jnp.reshape(nqs, (-1, 1))
+    col_ok = jnp.arange(c_pad)[None, :] < ncs[:, None]
+    return jnp.where(row_ok[:, :, None] & col_ok[:, None, :], w, 0.0)
+
+
+# the host continuation's weight program (its own module in a trace)
+device_weights = jax.jit(verify_weights, static_argnums=0)
+
+
 class EmbeddingSimilarity:
     """Cosine similarity over a (vocab, dim) embedding table."""
 
     name = "cosine"
+    row_blocks = staticmethod(cosine_row_blocks)
 
     def __init__(self, table: np.ndarray):
         assert table.ndim == 2
         self.table = jnp.asarray(table, dtype=jnp.float32)
         self.vocab_size, self.dim = table.shape
+
+    @property
+    def block_table(self) -> jnp.ndarray:
+        """The table ``row_blocks`` gathers from."""
+        return self.normalized_table
 
     @property
     def normalized_table(self) -> jnp.ndarray:
@@ -128,11 +198,17 @@ class NGramJaccardSimilarity:
     """
 
     name = "ngram_jaccard"
+    row_blocks = staticmethod(jaccard_row_blocks)
 
     def __init__(self, incidence: np.ndarray):
         assert incidence.ndim == 2
         self.table = jnp.asarray(incidence, dtype=jnp.float32)
         self.vocab_size, self.dim = incidence.shape
+
+    @property
+    def block_table(self) -> jnp.ndarray:
+        """The table ``row_blocks`` gathers from."""
+        return self.table
 
     def _fix_identity(self, s, q_ids, t_ids):
         same = q_ids[:, None] == t_ids[None, :]

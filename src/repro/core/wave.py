@@ -65,7 +65,7 @@ from .matching.auction import _auction_single, make_eps_schedule
 from .matching.hungarian import _hungarian_padded
 from .refinement import (refine_carry_init, refine_chunk_step,
                          refine_finalize)
-from .similarity import cosine_rows
+from .similarity import cosine_row_blocks, verify_weights
 from .types import SearchParams
 from .types import pow2 as _pow2
 
@@ -130,35 +130,6 @@ def compact_indices(mask: jnp.ndarray):
     idx = jnp.where(jnp.arange(n, dtype=jnp.int32) < count, order,
                     jnp.int32(-1))
     return idx, count
-
-
-def candidate_weights(table_n: jnp.ndarray, query_tok: jnp.ndarray,
-                      cand_tok: jnp.ndarray, cand_sizes: jnp.ndarray,
-                      nq: jnp.ndarray, alpha) -> jnp.ndarray:
-    """Alpha-thresholded verification weights for one candidate batch.
-
-    table_n: (vocab, d) row-L2-normalized embedding table.  Entries come
-      from the same contraction (``similarity.cosine_rows``) over the same
-      normalized rows as the host verifier's ``pairwise``, so the device
-      rounds and the host continuation see equal weights.
-    query_tok: (nq_pad,) int32, -1 padding;  cand_tok: (vb, c_pad) int32,
-      -1 padding;  cand_sizes: (vb,) logical |C|;  nq: logical |Q|.
-    Returns (vb, nq_pad, c_pad) float32, zero outside the logical block.
-    """
-    qv = table_n[jnp.clip(query_tok, 0, None)]         # (nq_pad, d)
-    tv = table_n[jnp.clip(cand_tok, 0, None)]          # (vb, c_pad, d)
-    vb, c_pad, d = tv.shape
-    s = cosine_rows(qv, tv.reshape(vb * c_pad, d))     # (nq_pad, vb*c_pad)
-    s = s.reshape(-1, vb, c_pad).transpose(1, 0, 2)
-    q_valid = query_tok >= 0
-    t_valid = cand_tok >= 0
-    same = (query_tok[None, :, None] == cand_tok[:, None, :]) \
-        & q_valid[None, :, None] & t_valid[:, None, :]
-    s = jnp.where(same, 1.0, s)
-    w = jnp.where(s >= alpha, s, 0.0)
-    row_ok = jnp.arange(query_tok.shape[0]) < nq
-    col_ok = jnp.arange(cand_tok.shape[1])[None, :] < cand_sizes[:, None]
-    return jnp.where(row_ok[None, :, None] & col_ok[:, None, :], w, 0.0)
 
 
 def fused_available(params: SearchParams, sim_provider) -> bool:
@@ -263,10 +234,11 @@ def _wave_fn(cfg: WaveConfig):
         _, sel = jax.lax.top_k(jnp.where(need, ub, _NEGINF), vb)
         valid = jnp.take(need, sel)
 
-        # -- weights: same per-entry math as the host pool (bit-equal) --
+        # -- weights: the host pool's weight function (bit-equal) --
         toks = set_tok[sel]
         ncs_b = jnp.where(valid, sizes32[sel], 0)
-        w = candidate_weights(table_n, qt, toks, sizes32[sel], nq, alpha)
+        w = verify_weights(cosine_row_blocks, table_n, qt, toks, nq,
+                           sizes32[sel], alpha)
         nqs_b = jnp.where(valid, nq, 0)
         th_b = jnp.where(valid, th, _NEGINF)
 

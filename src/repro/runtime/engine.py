@@ -605,10 +605,11 @@ class RequestEngine:
         the trace can coalesce), sweeps the SHARD-LOCAL fused wave-config
         grid (every shard x cohort bucket x the sample's pow2 event-chunk
         buckets plus a 2x guard bucket — steady-state queries landing one
-        bucket above the sample still hit a compiled program), and sweeps
-        the fused-verification pairwise pow2 grid, so steady-state
-        serving — sharded or not — triggers zero recompiles
-        (tests/test_recompile.py).  Standard request-engine startup
+        bucket above the sample still hit a compiled program), so
+        steady-state serving — sharded or not — triggers zero recompiles
+        (tests/test_recompile.py).  The verifier's weight program shares
+        its (B, nq_pad, c_pad) keys with the solver, which the cohorts
+        warm.  Standard request-engine startup
         practice; ``reset_counters`` wipes the warmup's traces from the
         metrics (the stream cache keeps its entries — that is warmup
         working as intended)."""
@@ -624,22 +625,6 @@ class RequestEngine:
                     break
                 bs = min(2 * bs, len(sample))
             self._warmup_wave_grid(sample)
-        # verification weight dispatch: the fused pairwise shape is
-        # (pow2 rows, pow2 cols) — sweep the grid the pool can emit
-        from ..core.postprocess import _pad_pow2
-        q_hi = _pad_pow2(max((sum(len(q) for q in sample), 32)), 32)
-        c_hi = min(VerifierPool._FUSE_TOKEN_CAP,
-                   _pad_pow2(self.params.verify_batch
-                             * max(int(self.coll.set_sizes.max()), 1)
-                             * max(len(sample), 1), 256))
-        qb = 32
-        while qb <= q_hi:
-            cb = 256
-            while cb <= c_hi:
-                self.sim.pairwise(np.zeros(qb, np.int32),
-                                  np.zeros(cb, np.int32))
-                cb *= 2
-            qb *= 2
         if reset_counters:
             self.counters = EngineCounters()
             # scheduler-side counters (waves/rounds/...) are warmup work

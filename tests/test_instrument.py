@@ -5,9 +5,11 @@ import importlib.util
 import pathlib
 import re
 
+import numpy as np
 import pytest
 
 from repro.core import SearchParams
+from repro.core.postprocess import VerifierPool, VerifyRequest
 from repro.data import sample_queries
 from repro.runtime import instrument
 from repro.runtime.engine import RequestEngine
@@ -157,3 +159,51 @@ def test_engine_step_yields_every_span_and_the_filter_funnel(small_world):
                        ("em_full", "exact_matches")]:
         assert c[f"filter:{key}"] == sum(getattr(s, field) for s in stats)
     assert c["filter:candidates"] > 0 and c["filter:em_full"] > 0
+
+
+@pytest.mark.parametrize("verifier", ["hungarian", "auction", "hybrid"])
+def test_a_pool_round_builds_its_weights_on_the_device(small_world,
+                                                       verifier):
+    """One ``verify_requests`` round: every solver row's weights come from
+    the device weight program (``verify:device_weight_rows`` equals the
+    solver rows), and the only device-to-host copies are the solver's
+    outputs: no similarity or weight block reaches the host."""
+    coll, sim = small_world
+    pool = VerifierPool(coll, sim, SearchParams(k=5, alpha=0.8,
+                                                verify_batch=8,
+                                                verifier=verifier))
+    queries = sample_queries(coll, 3, seed=5)
+    reqs = [VerifyRequest(q, np.arange(10 * i, 10 * i + 5 + 3 * i), th)
+            for i, (q, th) in enumerate(zip(queries, (-np.inf, 1.0, 2.0)))]
+    with instrument.counting() as c:
+        outs = pool.verify_requests(reqs)
+    rows = sum(len(r.ids) for r in reqs)
+    assert c["verify:device_weight_rows"] == c["verify:solver_rows"]
+    assert c["verify:device_weight_rows"] >= rows
+    if verifier == "hungarian":
+        assert c["verify:device_weight_rows"] == rows
+    assert sum(o.n_full + o.n_early for o in outs) == rows
+    assert "d2h:weights_materialize" not in c
+    assert "h2d:pairwise_dispatch" not in c
+    assert {k for k in c if k.startswith("d2h:")} == \
+        {"d2h:solver_materialize"}
+    assert c["span_n:koios.verify.weights"] == c["h2d:solver_dispatch"]
+    assert c["span_n:koios.device_wait"] == c["h2d:solver_dispatch"]
+
+
+def test_the_served_continuation_builds_every_weight_block_on_the_device(
+        small_world):
+    """The fused engine's host continuation (no device rounds, so the pool
+    verifies everything): ``verify:device_weight_rows`` equals the solver
+    rows and no weight block is copied to the host."""
+    coll, sim = small_world
+    params = SearchParams(k=5, alpha=0.8, chunk_size=64, verify_batch=8,
+                          fused="interpret", wave_rounds=0)
+    eng = RequestEngine(coll, sim, params, partitions=2, schedule="fused")
+    with instrument.counting() as c:
+        out = eng.serve(sample_queries(coll, 4, seed=5))
+    assert all(r.served for r in out)
+    assert c["verify:device_weight_rows"] > 0
+    assert c["verify:device_weight_rows"] == c["verify:solver_rows"]
+    assert not [k for k in c if "weights" in k and k.startswith("d2h:")]
+    assert "h2d:pairwise_dispatch" not in c

@@ -102,10 +102,11 @@ def test_engine_sweep_compiles_olog(small_world):
 def _jit_cache_sizes():
     from repro.core.matching.auction import auction_batch
     from repro.core.matching.hungarian import hungarian_batch
-    from repro.core.similarity import _cosine_block
+    from repro.core.similarity import _cosine_block, device_weights
 
     return (_run_refinement._cache_size(), auction_batch._cache_size(),
-            hungarian_batch._cache_size(), _cosine_block._cache_size())
+            hungarian_batch._cache_size(), _cosine_block._cache_size(),
+            device_weights._cache_size())
 
 
 def test_engine_steady_state_zero_recompiles(small_world):

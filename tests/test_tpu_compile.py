@@ -100,3 +100,20 @@ def test_fused_wave_program_compiles(shape):
             shape((TOTAL_SLOTS + 1,), i32))
     text = _wave_fn(cfg).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_verifier_weight_program_then_solver_compile(shape):
+    """The host continuation's solver batch at a closed cohort's widths
+    (32 queries x a verify batch of 32 rows): the weight program, then
+    the exact solver on the device array it returns."""
+    from repro.core.matching.hungarian import hungarian_batch
+    from repro.core.similarity import cosine_row_blocks, device_weights
+
+    B, nq_pad, i32 = 1024, 32, jnp.int32
+    w = device_weights.lower(
+        cosine_row_blocks, shape((VOCAB, DIM)), shape((B, nq_pad), i32),
+        shape((B, C_PAD), i32), shape((B,), i32), shape((B,), i32),
+        shape((), jnp.float32)).compile()
+    assert w.as_text()
+    hungarian_batch.lower(shape((B, nq_pad, C_PAD)), shape((B,), i32),
+                          shape((B,), i32)).compile()
