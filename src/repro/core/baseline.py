@@ -19,8 +19,7 @@ import dataclasses
 import numpy as np
 import jax.numpy as jnp
 
-from .matching.hungarian import hungarian_batch
-from .postprocess import Verifier, _pad_pow2
+from .postprocess import Verifier
 from .refinement import run_refinement
 from .search import KoiosIndex, merge_topk
 from .token_stream import build_token_stream, expand_to_events

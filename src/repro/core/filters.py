@@ -43,6 +43,10 @@ def compute_iub(S, l, T, d, cap, s_now, seen, mode: str):
     return jnp.where(seen, ub, jnp.float32(3.4e38))
 
 
-def prune_mask(iub, theta_lb, seen, alive):
-    """Sets killed by the UB filter this round (strict <: ties survive)."""
-    return alive & seen & (iub < theta_lb)
+def prune_mask(ub, theta_lb, live, lb):
+    """Live sets the UB filter drops (strict <: ties survive).  A set whose
+    own lower bound reaches theta_lb survives too: lb and ub are float32
+    sums in different orders, so its ub can round below the lb that raised
+    theta_lb.  The one rule for refinement, the wave's rounds and the host
+    continuation; plain operators, so it takes jnp and numpy arrays."""
+    return live & (ub < theta_lb) & (lb < theta_lb)

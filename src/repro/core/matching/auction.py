@@ -55,7 +55,7 @@ def _auction_single(w, nq, nc, eps_schedule, theta_lb, max_rounds,
     (the historical square/perfect formulation) costs O(K - nq) extra
     rounds per phase, the auction analogue of the square-padding cost the
     nq-bounded Hungarian augmentation already eliminated
-    (``hungarian._solve_square_min(n_aug=nq)``).  Soundness of the
+    (``hungarian._solve_rect_min(n_aug=nq)``).  Soundness of the
     nq-row form:
 
       * lb is the score of a feasible (optional) matching, so lb <= SO

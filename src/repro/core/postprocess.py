@@ -37,25 +37,29 @@ import dataclasses
 from typing import List, Optional, Sequence
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
+from .filters import prune_mask
 from .matching.auction import auction_batch, make_eps_schedule
 from .matching.hungarian import hungarian_batch
 from .similarity import device_weights
 from .types import (SearchParams, SearchResult, SearchStats, SetCollection,
-                    pow2)
+                    lane_cover, pow2)
 from ..runtime import instrument
 from ..runtime.instrument import span
 
 
-def _pad_pow2(n: int, lo: int = 8) -> int:
-    """Solver-batch bucket rounding (shared pow2 with an 8 floor)."""
-    return pow2(n, lo)
-
-
-def _rows(spans: dict) -> int:
-    """Logical solver rows of a packed batch."""
-    return sum(hi - lo for lo, hi in spans.values())
+def _record_solve(spans: dict, padded_cells: int) -> None:
+    """Count one solver batch: its dispatch and output copy, its logical
+    rows, and the cells of the block the solver works on (batch padding
+    included; the Hungarian's (R, max(R, C)) rectangle, the auction's
+    square)."""
+    instrument.record("h2d:solver_dispatch")
+    instrument.record("d2h:solver_materialize")
+    instrument.record("verify:solver_rows",
+                      sum(hi - lo for lo, hi in spans.values()))
+    instrument.record("verify:solver_cells_padded", padded_cells)
 
 
 def _kth(x: np.ndarray, mask: np.ndarray, kk: int) -> float:
@@ -92,8 +96,9 @@ class VerifierPool:
     token-id tensors, builds their weights on the device and runs one
     solver call per distinct padded shape —
     the multi-query generalisation of the paper's verification thread pool.
-    Shape grouping (pow2-padded |Q| and |C|) keeps the jit cache small AND
-    guarantees each row reproduces its single-request numerics exactly.
+    Shape grouping (|Q| and |C| padded by ``lane_cover``) keeps the jit
+    cache small AND guarantees each row reproduces its single-request
+    numerics exactly.
     """
 
     def __init__(self, coll: SetCollection, sim_provider,
@@ -103,19 +108,19 @@ class VerifierPool:
         self.params = params
         self.eps_schedule = make_eps_schedule(params.auction_eps)
         # Collection-level candidate pad: every solver row is padded to
-        # the pow2 cover of the LARGEST set in the pool's collection —
-        # a composition-independent constant, so (a) an entry's padded
-        # shape never depends on which other requests share its round
-        # (the auction is NOT bitwise padding-invariant, so a
-        # composition-dependent c_pad would break search ==
+        # the cover (``lane_cover``) of the LARGEST set in the pool's
+        # collection — a composition-independent constant, so (a) an
+        # entry's padded shape never depends on which other requests
+        # share its round (the auction is NOT bitwise padding-invariant,
+        # so a composition-dependent c_pad would break search ==
         # search_batch), and (b) rounds collapse to one solver dispatch
         # per nq bucket instead of one per observed candidate-width
         # bucket — the dominant host<->device round-trip count of the
         # fused schedule's continuation (DESIGN.md §3.3).  The fused
         # wave pays the same cover for its dense operands
         # (``ShardedCollection.wave_operands``).
-        self._c_pad = _pad_pow2(
-            int(coll.set_sizes.max()) if coll.num_sets else 1)
+        self._c_pad = lane_cover(
+            int(coll.set_sizes.max()) if coll.num_sets else 1, 8)
         self._alpha = np.float32(params.alpha)
 
     # ---------------------------------------------------- batch building
@@ -144,7 +149,7 @@ class VerifierPool:
         never changes a row's result."""
         groups: dict = {}
         for i, r in enumerate(requests):
-            key = _pad_pow2(len(r.query))
+            key = lane_cover(len(r.query), 8)
             groups.setdefault(key, []).append(i)
         for nq_pad, idxs in groups.items():
             with span("koios.verify.pack"):
@@ -153,7 +158,7 @@ class VerifierPool:
                 # shrink as queries finish, and an exact-fit B would
                 # recompile the solver every round (single-query batches
                 # stay <= verify_batch, i.e. exactly the historical shape)
-                B = _pad_pow2(rows, self.params.verify_batch)
+                B = pow2(rows, self.params.verify_batch)
                 q_tok = np.full((B, nq_pad), -1, np.int32)
                 nqs = np.zeros(B, np.int32)
                 thetas = np.full(B, -np.inf, np.float32)
@@ -173,6 +178,8 @@ class VerifierPool:
                 c_tok, ncs = self._candidate_tokens(ids, B)
                 nqs_d, ncs_d = jnp.asarray(nqs), jnp.asarray(ncs)
                 instrument.record("verify:device_weight_rows", rows)
+                instrument.record("verify:solver_cells_logical",
+                                  int(nqs.astype(np.int64) @ ncs))
                 w = device_weights(self.sim.row_blocks, self.sim.block_table,
                                    q_tok, c_tok, nqs_d, ncs_d, self._alpha)
             yield w, nqs_d, ncs_d, thetas, spans
@@ -182,13 +189,13 @@ class VerifierPool:
         """Exact SO per request via shape-grouped ``hungarian_batch``."""
         out: List[Optional[np.ndarray]] = [None] * len(requests)
         for w, nqs, ncs, _thetas, spans in self._grouped(requests):
-            with span("koios.verify.solve"):
-                instrument.record("h2d:solver_dispatch")
-                instrument.record("d2h:solver_materialize")
-                instrument.record("verify:solver_rows", _rows(spans))
-                so, _ = hungarian_batch(w, nqs, ncs)
+            B, R, C = w.shape
+            with span("koios.verify.solve", c_pad=C):
+                _record_solve(spans, B * R * max(R, C))
+                so, _, steps = hungarian_batch(w, nqs, ncs)
                 with span("koios.device_wait", what="solver"):
-                    so = np.asarray(so)
+                    so, steps = jax.device_get((so, steps))
+                instrument.record("verify:solver_steps", int(steps))
                 for i, (lo, hi) in spans.items():
                     out[i] = so[lo:hi].copy()
         return out
@@ -209,10 +216,9 @@ class VerifierPool:
 
         outcomes: List[Optional[VerifyOutcome]] = [None] * len(requests)
         for w, nqs, ncs, thetas, spans in self._grouped(requests):
-            with span("koios.verify.solve"):
-                instrument.record("h2d:solver_dispatch")
-                instrument.record("d2h:solver_materialize")
-                instrument.record("verify:solver_rows", _rows(spans))
+            B, R, C = w.shape
+            with span("koios.verify.solve", c_pad=C):
+                _record_solve(spans, B * max(R, C) ** 2)
                 res = auction_batch(w, nqs, ncs, self.eps_schedule,
                                     jnp.asarray(thetas))
                 with span("koios.device_wait", what="solver"):
@@ -336,9 +342,8 @@ class PostprocessState:
                     "post-processing failed to converge"
                 self.theta_lb = max(self.theta_lb,
                                     _kth(self.lb, self.live, k))
-                # UB filter (sets that can no longer reach the top-k;
-                # strict < keeps ties, which is always safe)
-                drop = self.live & (self.ub < self.theta_lb)
+                # UB filter (sets that can no longer reach the top-k)
+                drop = prune_mask(self.ub, self.theta_lb, self.live, self.lb)
                 self.stats.pruned_postprocess += int((drop
                                                       & ~self.verified).sum())
                 self.live &= ~drop

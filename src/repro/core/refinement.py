@@ -167,7 +167,7 @@ def _chunk_filter_pass(state, s_now, cap, k: int, ub_mode: str):
     S, l, T, d, seen, alive, qmatched, qseen, slot_matched, theta_lb = state
     theta_lb = jnp.maximum(theta_lb, kth_largest(S, k))
     iub = compute_iub(S, l, T, d, cap, s_now, seen, ub_mode)
-    killed = prune_mask(iub, theta_lb, seen, alive)
+    killed = prune_mask(iub, theta_lb, seen & alive, S)
     alive = alive & ~killed
     n_killed = jnp.sum(killed)
     return (S, l, T, d, seen, alive, qmatched, qseen, slot_matched,
@@ -182,7 +182,7 @@ def refine_finalize(state, cap, alpha, k: int, ub_mode: str):
     s_final = alpha if ub_mode == "paper" else jnp.float32(0.0)
     ub_final = compute_iub(S, l, T, d, cap, s_final, seen, ub_mode)
     theta_lb = jnp.maximum(theta_lb, kth_largest(S, k))
-    killed = prune_mask(ub_final, theta_lb, seen, alive)
+    killed = prune_mask(ub_final, theta_lb, seen & alive, S)
     alive = alive & ~killed
     return S, ub_final, seen, alive, theta_lb, jnp.sum(killed)
 

@@ -27,6 +27,20 @@ def pow2(n: int, lo: int = 1) -> int:
     return p
 
 
+def lane_cover(n: int, lo: int = 1) -> int:
+    """The pad of a set-size axis: ``pow2(n, lo)`` up to 128, the next
+    multiple of 128 (one TPU lane tile) above it.  Equal to ``pow2`` for
+    every n <= 256, so short-set deployments keep their shapes; a corpus
+    whose largest set is a little over a power of two (DBLP's 514) pads
+    to 640 instead of 1024.  The candidate cover (``VerifierPool``,
+    ``Shard.wave_operands``) and the host solver's row pad use it; the
+    wave's own query pad stays ``pow2`` (its stream operands and bitmask
+    words are keyed on it)."""
+    if n <= 128:
+        return pow2(n, lo)
+    return -(-n // 128) * 128
+
+
 def slot_dtype(total_slots: int):
     """Narrowest integer dtype for flat token-array slots (and posting
     ids): int32 whenever the repository fits — always at bench scales —

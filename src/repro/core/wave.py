@@ -61,8 +61,9 @@ import numpy as np
 from ..kernels.ref import event_ranks_ref
 from ..runtime import instrument
 from ..runtime.sharding import _round_down_f32
+from .filters import prune_mask
 from .matching.auction import _auction_single, make_eps_schedule
-from .matching.hungarian import _hungarian_padded
+from .matching.hungarian import hungarian_batch
 from .refinement import (refine_carry_init, refine_chunk_step,
                          refine_finalize)
 from .similarity import cosine_row_blocks, verify_weights
@@ -225,7 +226,7 @@ def _wave_fn(cfg: WaveConfig):
         PostprocessState.apply (DESIGN.md §3)."""
         # -- filter pass (theta refresh, UB filter, No-EM, batch pick) --
         th = jnp.maximum(th, _masked_kth(lb, live, cfg.k))
-        drop = live & (ub < th)
+        drop = prune_mask(ub, th, live, lb)
         n_drop = jnp.sum(drop & ~verified)
         live = live & ~drop
         theta_ub = _masked_kth(ub, live, cfg.k)
@@ -244,7 +245,7 @@ def _wave_fn(cfg: WaveConfig):
 
         # -- solve (Lemma-8 dual aborts) --
         if cfg.verifier == "hungarian":
-            so, _ = jax.vmap(_hungarian_padded)(w, nqs_b, ncs_b)
+            so, _, _ = hungarian_batch(w, nqs_b, ncs_b)
             out_lb, out_ub = so, so
             early = jnp.zeros((vb,), bool)
             settle = valid                   # exact: every row settles

@@ -78,7 +78,7 @@ import numpy as np
 
 from ..core.inverted_index import InvertedIndex
 from ..core.search import KoiosIndex, partition_ranges
-from ..core.types import SetCollection, assert_int32, pow2
+from ..core.types import SetCollection, assert_int32, lane_cover
 from . import instrument
 
 
@@ -137,9 +137,10 @@ class Shard(KoiosIndex):
         return cached
 
     def wave_operands(self):
-        """Dense (num_sets, pow2(max set size)) token matrix + int32 set
-        sizes + the pow2 column pad — the fused wave's verification
-        operands, built and uploaded once per shard lifetime.
+        """Dense (num_sets, c_pad) token matrix + int32 set sizes + the
+        column pad ``c_pad``, the cover (``lane_cover``) of the shard's
+        largest set — the fused wave's verification operands, built and
+        uploaded once per shard lifetime.
 
         On a size-skewed shard one outlier set inflates ``c_pad`` for
         every row — token-balanced sharding (``by='tokens'``) keeps
@@ -149,7 +150,7 @@ class Shard(KoiosIndex):
         if cached is None:
             coll = self.coll
             sizes = coll.set_sizes
-            c_pad = pow2(int(sizes.max()) if len(sizes) else 1)
+            c_pad = lane_cover(int(sizes.max()) if len(sizes) else 1)
             dense = np.full((coll.num_sets, c_pad), -1, np.int32)
             if coll.total_tokens:
                 rows = np.repeat(np.arange(coll.num_sets), sizes)

@@ -12,7 +12,10 @@ hot path pays nothing).
 Tags follow ``<direction>:<site>``: ``h2d`` = a program dispatch,
 ``d2h`` = a blocking device-to-host materialization; ``filter:<stage>``
 counts a finished tile's candidates per filter stage (the paper's
-Tables II/IV/V funnel).  The benchmark (``bench/run.py``) copies the
+Tables II/IV/V funnel); ``verify:<quantity>`` counts the host verifier's
+solver work (``verify:solver_rows``, ``verify:solver_cells_logical`` and
+``verify:solver_cells_padded``, ``verify:solver_steps``: the exact
+solver's lockstep scan iterations).  The benchmark (``bench/run.py``) copies the
 counter into every result; its per-layer metrics read the tags.
 
 :func:`span` marks a phase of the host's work.  Each span is a

@@ -20,3 +20,14 @@ def small_world():
 @pytest.fixture(scope="session")
 def default_params():
     return SearchParams(k=5, alpha=0.8, chunk_size=64, verify_batch=8)
+
+
+@pytest.fixture(scope="session")
+def dblp_world():
+    """A DBLP-shaped repository: medium-to-large sets (average ~60, largest
+    260, six sets over 256), so the cover rule pads candidates to 384
+    where a power of two would pad to 512."""
+    coll = make_collection(num_sets=300, vocab_size=3000, avg_size=60,
+                           max_size=260, zipf_a=1.05, seed=0)
+    emb = make_embeddings(3000, dim=16, cluster_size=4.0, seed=0)
+    return coll, EmbeddingSimilarity(emb)

@@ -8,6 +8,8 @@ from repro.core import (EmbeddingSimilarity, InvertedIndex, KoiosSearch,
                         SearchParams, build_token_stream,
                         build_token_stream_batch, expand_to_events)
 from repro.data import make_collection, make_embeddings, sample_queries
+from repro.runtime.engine import RequestEngine
+from test_verify_weights import _reference_so
 
 
 @pytest.mark.parametrize("verifier", ["hungarian", "auction", "hybrid"])
@@ -28,6 +30,40 @@ def test_search_batch_bit_identical(small_world, verifier, partitions):
         assert np.array_equal(rs.lb, rb.lb)          # bit-identical floats
         assert np.array_equal(rs.ub, rb.ub)
         assert rs.stats.as_dict() == rb.stats.as_dict()
+
+
+def test_fused_engine_on_a_dblp_shaped_corpus_matches_the_reference(
+        dblp_world):
+    """Large sets through the fused engine (interpret mode): the
+    candidates pad to the 384 cover, the served batch agrees with a
+    float64 NumPy/SciPy reference in top-k ids and scores, and each
+    query searched alone gives the batch's answer bit for bit."""
+    coll, sim = dblp_world
+    params = SearchParams(k=5, alpha=0.8, verify_batch=8, fused="interpret")
+    sizes = coll.set_sizes
+    order = np.argsort(sizes, kind="stable")
+    picks = [order[-1], order[np.searchsorted(sizes[order], 130)],
+             order[len(order) // 2]]
+    queries = [coll.get_set(int(i)) for i in picks]
+    eng = RequestEngine(coll, sim, params, schedule="fused")
+    assert eng.schedule == "fused" and eng.pool._c_pad == 384
+    assert eng.partitions[0].wave_operands()[2] == 384
+    served = eng.serve(queries)
+    search = KoiosSearch(coll, sim, params)
+    for q, r in zip(queries, served):
+        assert r.status == "ok"
+        ref = np.array([_reference_so(coll, sim, q, i, params.alpha)
+                        for i in range(coll.num_sets)])
+        # sets sharing no similar token are never candidates
+        top = np.sort(ref[ref > 0])[::-1][:params.k]
+        ids, lb = r.result.ids, r.result.lb.astype(np.float64)
+        assert len(ids) == len(top) > 0
+        np.testing.assert_allclose(lb, ref[ids], rtol=0, atol=1e-4)
+        np.testing.assert_allclose(lb, top, rtol=0, atol=1e-4)
+        alone = search.search(q, schedule="fused")
+        assert np.array_equal(alone.ids, r.result.ids)
+        assert np.array_equal(alone.lb, r.result.lb)
+        assert np.array_equal(alone.ub, r.result.ub)
 
 
 def test_search_batch_k_override(small_world):

@@ -4,7 +4,9 @@ import jax.numpy as jnp
 from hypothesis import given, settings, strategies as st
 from scipy.optimize import linear_sum_assignment
 
-from repro.core.filters import compute_iub, kth_largest
+from repro.core import SearchParams, SearchStats
+from repro.core.filters import compute_iub, kth_largest, prune_mask
+from repro.core.postprocess import PostprocessState, VerifyOutcome
 
 
 def _oracle(w):
@@ -95,3 +97,37 @@ def test_compute_iub_modes():
     assert abs(float(paper[0]) - (1.0 + 3 * 0.9)) < 1e-6
     assert abs(float(sound[0]) - (1.5 + 2 * 0.9)) < 1e-6
     assert float(paper[1]) > 1e30 and float(sound[1]) > 1e30  # unseen
+
+
+def test_prune_mask_keeps_a_set_whose_lb_reaches_theta():
+    """A bound that rounds below the set's own lower bound does not prune
+    it; a set below theta on both bounds goes, and ties survive."""
+    lb = jnp.asarray([5.0000005, 4.0, 5.0, 4.0], jnp.float32)
+    ub = jnp.asarray([5.0, 4.5, 6.0, 5.0000005], jnp.float32)
+    theta = jnp.float32(5.0000005)
+    killed = prune_mask(ub, theta, jnp.ones(4, bool), lb)
+    assert np.asarray(killed).tolist() == [False, True, False, False]
+
+
+def test_the_set_that_raised_theta_survives_its_rounded_matching_score():
+    """The k-th set's refinement lower bound (a float32 sum in stream
+    order) lies an ulp above its float32 matching score.  Theta is raised
+    to that lower bound; the exact ub that comes back is below it.  The
+    set stays in the answer instead of being dropped by its own bound."""
+    lb_ref = np.float32(66.398903)
+    exact = np.nextafter(lb_ref, np.float32(0), dtype=np.float32)
+    params = SearchParams(k=2, verify_batch=8)
+    st = PostprocessState(np.arange(3, dtype=np.int32),
+                          surv_ids=np.array([7, 9]),
+                          surv_lb=np.array([80.0, lb_ref]),
+                          surv_ub=np.array([80.0, 70.0]), theta_lb0=0.0,
+                          params=params, stats=SearchStats())
+    scores = {7: np.float32(80.0), 9: exact}
+    while (req := st.next_request()) is not None:
+        assert st.theta_lb == float(lb_ref)
+        so = np.array([scores[i] for i in req.ids], np.float32)
+        st.apply(VerifyOutcome(lb=so, ub=so.copy(),
+                               early=np.zeros(len(so), bool),
+                               n_full=len(so)))
+    res = st.result()
+    assert res.ids.tolist() == [7, 9]

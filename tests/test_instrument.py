@@ -189,6 +189,38 @@ def test_a_pool_round_builds_its_weights_on_the_device(small_world,
         {"d2h:solver_materialize"}
     assert c["span_n:koios.verify.weights"] == c["h2d:solver_dispatch"]
     assert c["span_n:koios.device_wait"] == c["h2d:solver_dispatch"]
+    logical = sum(len(r.query) * int(coll.set_sizes[r.ids].sum())
+                  for r in reqs)
+    assert c["verify:solver_cells_logical"] >= logical
+    if verifier == "hungarian":
+        assert c["verify:solver_cells_logical"] == logical
+    assert 0 < c["verify:solver_cells_logical"] \
+        <= c["verify:solver_cells_padded"]
+
+
+def test_a_hungarian_round_counts_its_solver_steps_in_the_solver_copy(
+        small_world):
+    """The exact solver's lockstep scan count comes back with its scores:
+    ``verify:solver_steps`` is positive (rows without an edge at or
+    above alpha are not augmented), one solver copy per dispatch and
+    no other device-to-host record; the padded cells are the solver's
+    (B, R, max(R, C)) rectangles."""
+    coll, sim = small_world
+    pool = VerifierPool(coll, sim, SearchParams(k=5, alpha=0.8,
+                                                verify_batch=8))
+    queries = sample_queries(coll, 3, seed=6)
+    reqs = [VerifyRequest(q, np.arange(7 * i, 7 * i + 6), -np.inf)
+            for i, q in enumerate(queries)]
+    with instrument.counting() as c:
+        pool.verify_requests(reqs)
+    rows = sum(len(q) for q in queries)
+    assert c["verify:solver_steps"] > 0
+    assert c["verify:solver_steps"] <= rows * pool._c_pad
+    assert {k for k in c if k.startswith(("d2h:", "h2d:"))} == \
+        {"d2h:solver_materialize", "h2d:solver_dispatch"}
+    assert c["d2h:solver_materialize"] == c["h2d:solver_dispatch"]
+    assert c["span_n:koios.device_wait"] == c["h2d:solver_dispatch"]
+    assert c["verify:solver_cells_padded"] % pool._c_pad == 0
 
 
 def test_the_served_continuation_builds_every_weight_block_on_the_device(

@@ -139,6 +139,50 @@ def test_engine_steady_state_zero_recompiles(small_world):
     assert _jit_cache_sizes() == before
 
 
+def test_lane_cover_is_pow2_up_to_256_then_lane_multiples():
+    """The cover of a set-size axis keeps every pow2 shape up to 256 (so
+    short-set deployments compile what they did) and above it grows by
+    whole 128-lane tiles."""
+    from repro.core.types import lane_cover, pow2
+
+    for n in range(1, 257):
+        assert lane_cover(n) == pow2(n)
+        assert lane_cover(n, 8) == pow2(n, 8)
+    assert [lane_cover(n) for n in (257, 384, 385, 514, 640, 1024)] == \
+        [384, 384, 512, 640, 640, 1024]
+    assert all(lane_cover(n) % 128 == 0 for n in range(129, 4097))
+    assert all(n <= lane_cover(n) < n + 128 for n in range(129, 4097))
+
+
+def test_engine_steady_state_zero_recompiles_at_the_lane_cover(dblp_world):
+    """The steady-state invariant on a corpus whose largest set (260) the
+    cover pads to 384, not 512: the pool and every shard's dense operands
+    take the cover of their own largest set, and after one pass a second
+    pass of varying batch sizes compiles nothing."""
+    from repro.core.types import lane_cover
+    from repro.runtime.engine import RequestEngine
+
+    coll, sim = dblp_world
+    params = SearchParams(k=5, alpha=0.8, verify_batch=8)
+    pool = sample_queries(coll, 6, seed=3)
+    batches = [pool[:bs] for bs in (3, 4, 2)]
+
+    def serve_all():
+        eng = RequestEngine(coll, sim, params, partitions=2)
+        for batch in batches:
+            eng.serve(batch)
+        return eng
+
+    eng = serve_all()
+    assert eng.pool._c_pad == 384
+    for shard in eng.partitions:
+        assert shard.wave_operands()[2] == lane_cover(
+            int(shard.coll.set_sizes.max()))
+    before = _jit_cache_sizes()
+    serve_all()
+    assert _jit_cache_sizes() == before
+
+
 def test_fused_engine_steady_state_zero_recompiles(small_world):
     """Same invariant through the fused device-wave engine: wave configs
     depend only on pow2-padded shapes, so a steady-state sweep of batch

@@ -117,3 +117,23 @@ def test_verifier_weight_program_then_solver_compile(shape):
     assert w.as_text()
     hungarian_batch.lower(shape((B, nq_pad, C_PAD)), shape((B,), i32),
                           shape((B,), i32)).compile()
+
+
+def test_rectangular_solver_compiles_at_dblp_widths(shape):
+    """The exact solver on its (R, max(R, C)) rectangle at DBLP's widths:
+    256-row blocks of 256 query rows against the 640 cover of a 514-token
+    largest set, and the weight program that feeds it."""
+    from repro.core.matching.hungarian import hungarian_batch
+    from repro.core.similarity import cosine_row_blocks, device_weights
+    from repro.core.types import lane_cover
+
+    B, nq_pad, c_pad, i32 = 256, 256, lane_cover(514), jnp.int32
+    assert c_pad == 640
+    device_weights.lower(
+        cosine_row_blocks, shape((25159, DIM)), shape((B, nq_pad), i32),
+        shape((B, c_pad), i32), shape((B,), i32), shape((B,), i32),
+        shape((), jnp.float32)).compile()
+    text = hungarian_batch.lower(shape((B, nq_pad, c_pad)), shape((B,), i32),
+                                 shape((B,), i32)).compile().as_text()
+    assert "f32[256,256,640]" in text
+    assert "1024,1024" not in text and "640,640" not in text
