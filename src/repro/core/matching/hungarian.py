@@ -96,17 +96,19 @@ def _solve_rect_min(cost: jnp.ndarray, n_aug=None):
 
         init = (shortest, path, SR, SC, jnp.int32(-1),
                 jnp.int32(cur_row), jnp.float32(0.0), jnp.int32(0))
-        shortest, path, SR, SC, sink, _, min_val, n = jax.lax.while_loop(
-            scan_cond, scan_body, init)
+        with jax.named_scope("solver.scan"):
+            shortest, path, SR, SC, sink, _, min_val, n = \
+                jax.lax.while_loop(scan_cond, scan_body, init)
 
         # --- dual update ----------------------------------------------------
-        u = u + jnp.where(
-            SR,
-            jnp.where(rows == cur_row,
-                      min_val,
-                      min_val - shortest[jnp.clip(col4row, 0, C - 1)]),
-            0.0)
-        v = v - jnp.where(SC, min_val - shortest, 0.0)
+        with jax.named_scope("solver.dual"):
+            u = u + jnp.where(
+                SR,
+                jnp.where(rows == cur_row,
+                          min_val,
+                          min_val - shortest[jnp.clip(col4row, 0, C - 1)]),
+                0.0)
+            v = v - jnp.where(SC, min_val - shortest, 0.0)
 
         # --- augment along the alternating path -----------------------------
         def aug_cond(s):
@@ -121,8 +123,9 @@ def _solve_rect_min(cost: jnp.ndarray, n_aug=None):
             col4row = col4row.at[i].set(j)
             return row4col, col4row, nxt, i == cur_row
 
-        row4col, col4row, _, _ = jax.lax.while_loop(
-            aug_cond, aug_body, (row4col, col4row, sink, ~active))
+        with jax.named_scope("solver.augment"):
+            row4col, col4row, _, _ = jax.lax.while_loop(
+                aug_cond, aug_body, (row4col, col4row, sink, ~active))
         scans = scans.at[cur_row].set(n)
         return u, v, row4col, col4row, scans
 

@@ -33,9 +33,9 @@ def lane_cover(n: int, lo: int = 1) -> int:
     every n <= 256, so short-set deployments keep their shapes; a corpus
     whose largest set is a little over a power of two (DBLP's 514) pads
     to 640 instead of 1024.  The candidate cover (``VerifierPool``,
-    ``Shard.wave_operands``) and the host solver's row pad use it; the
-    wave's own query pad stays ``pow2`` (its stream operands and bitmask
-    words are keyed on it)."""
+    ``Shard.wave_operands``), the host solver's row pad and the fused
+    wave's query-row pad (``wave.cohort_pads``) use it; the wave's
+    stream-tuple pad and refinement bitmask words stay ``pow2``."""
     if n <= 128:
         return pow2(n, lo)
     return -(-n // 128) * 128

@@ -67,7 +67,7 @@ from .matching.hungarian import hungarian_batch
 from .refinement import (refine_carry_init, refine_chunk_step,
                          refine_finalize)
 from .similarity import cosine_row_blocks, verify_weights
-from .types import SearchParams
+from .types import SearchParams, lane_cover
 from .types import pow2 as _pow2
 
 _NEGINF = jnp.float32(-jnp.inf)
@@ -169,7 +169,8 @@ class WaveConfig(NamedTuple):
     n_chunks: int
     chunk: int
     n_tuples: int                    # pow2 stream-tuple pad (Stage A0 input)
-    nq_pad: int
+    nq_pad: int                      # query-row pad of the rounds' blocks
+    #                                  (lane_cover of the cohort's max |Q|)
     c_pad: int
     B: int
     verify_batch: int
@@ -182,6 +183,16 @@ class WaveConfig(NamedTuple):
     #                                  kernel (compiled; off in interpret
     #                                  mode, where the jnp pass is faster)
     max_rounds: int = 5000
+
+
+def solver_cells(cfg: WaveConfig) -> int:
+    """Cells of the blocks one wave's verification rounds solve, padding
+    included: rounds x B x vb blocks, each the Hungarian's (nq_pad,
+    max(nq_pad, c_pad)) rectangle or the auction's square of the larger
+    side, as the host pool counts ``verify:solver_cells_padded``."""
+    R, C = cfg.nq_pad, cfg.c_pad
+    block = R * max(R, C) if cfg.verifier == "hungarian" else max(R, C) ** 2
+    return cfg.rounds * cfg.B * min(cfg.verify_batch, cfg.num_sets) * block
 
 
 def _masked_kth(x, mask, k: int):
@@ -208,6 +219,22 @@ _WAVE_VB_CAP = 16
 # together with the next bucket up (x2), so live streams one pow2 step
 # heavier than the warmup sample still hit a compiled program.
 _WAVE_CHUNK_GUARD = (1, 2)
+
+
+def cohort_pads(queries: Sequence[np.ndarray], streams
+                ) -> "tuple[int, int, int]":
+    """(n_tuples, nq_pad, q_words) of a cohort's waves: the keys that
+    ``WaveRunner.stream_operands`` builds and ``RequestEngine``'s warm-up
+    compiles, from one rule.
+
+    The stream-tuple pad and the refinement's bitmask words stay pow2.
+    Only the verification rounds read the query rows, so they take the
+    set-size axes' ``lane_cover``: a 514-token query solves 640-row
+    blocks, not 1024-row ones (the same as pow2 for every |Q| <= 256)."""
+    t_pad = _pow2(max([len(s) for s in streams] or [1]) or 1)
+    nq_max = max([len(q) for q in queries] or [1])
+    return (t_pad, lane_cover(max(nq_max, 1)),
+            _pow2(max(1, -(-nq_max // 32))))
 
 
 @functools.lru_cache(maxsize=None)
@@ -404,7 +431,7 @@ class StreamOperands:
     qtok: object                     # (B_pad, nq_pad) int32, -1 pad
     nqs: object                      # (B_pad,) int32
     n_tuples: int                    # T_pad (pow2)
-    nq_pad: int
+    nq_pad: int                      # lane_cover(max |Q|), as cohort_pads
     q_words: int
     _placed: dict = dataclasses.field(default_factory=dict, repr=False)
 
@@ -488,9 +515,7 @@ class WaveRunner:
         (§3.3) this is the only per-plan host->device payload, replacing
         the per-wave event-array uploads (events outnumber tuples by
         the mean posting length)."""
-        t_pad = _pow2(max([len(s) for s in streams] or [1]) or 1)
-        nq_max = max([len(q) for q in queries] or [1])
-        nq_pad = _pow2(max(nq_max, 1))
+        t_pad, nq_pad, q_words = cohort_pads(queries, streams)
         st_tok = np.full((B_pad, t_pad), -1, np.int32)
         st_q = np.zeros((B_pad, t_pad), np.int32)
         st_sim = np.zeros((B_pad, t_pad), np.float32)
@@ -507,7 +532,7 @@ class WaveRunner:
             tok=jnp.asarray(st_tok), q_pos=jnp.asarray(st_q),
             sim=jnp.asarray(st_sim), qtok=jnp.asarray(qtok),
             nqs=jnp.asarray(nqs), n_tuples=t_pad, nq_pad=nq_pad,
-            q_words=_pow2(max(1, -(-nq_max // 32))))
+            q_words=q_words)
 
     # -------------------------------------------------------------- warmup
     def warm(self, index, B_pad: int, n_chunks: int, n_tuples: int,
@@ -612,6 +637,7 @@ class WaveRunner:
             use_kernel=self.use_kernel)
         fn = _wave_fn(cfg)
         instrument.record(f"h2d:wave_dispatch[s{getattr(index, 'sid', 0)}]")
+        instrument.record("wave:solver_cells_padded", solver_cells(cfg))
         out = fn(stream_ops.tok, stream_ops.q_pos, stream_ops.sim,
                  stream_ops.qtok, stream_ops.nqs, theta_dev,
                  table_n, set_tok, sizes32, self.eps,

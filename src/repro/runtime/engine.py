@@ -646,7 +646,7 @@ class RequestEngine:
         if self._runner is None:
             return
         from ..core.types import pow2
-        from ..core.wave import _WAVE_CHUNK_GUARD
+        from ..core.wave import _WAVE_CHUNK_GUARD, cohort_pads
         streams = build_token_stream_batch_cached(
             sample, self.sim, self.params.alpha, self.stream_cache,
             use_kernel=self.params.stream_use_kernel,
@@ -657,10 +657,7 @@ class RequestEngine:
         while True:
             cohort_q, cohort_s = sample[:bs], streams[:bs]
             B_pad = pow2(len(cohort_q))
-            t_pad = pow2(max([len(s) for s in cohort_s] or [1]) or 1)
-            nq_max = max(len(q) for q in cohort_q)
-            nq_pad = pow2(max(nq_max, 1))
-            q_words = pow2(max(1, -(-nq_max // 32)))
+            t_pad, nq_pad, q_words = cohort_pads(cohort_q, cohort_s)
             for shard, cnt in zip(self.partitions, counts):
                 buckets = set()
                 for s in cohort_s:

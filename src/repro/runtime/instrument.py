@@ -15,8 +15,11 @@ counts a finished tile's candidates per filter stage (the paper's
 Tables II/IV/V funnel); ``verify:<quantity>`` counts the host verifier's
 solver work (``verify:solver_rows``, ``verify:solver_cells_logical`` and
 ``verify:solver_cells_padded``, ``verify:solver_steps``: the exact
-solver's lockstep scan iterations).  The benchmark (``bench/run.py``) copies the
-counter into every result; its per-layer metrics read the tags.
+solver's lockstep scan iterations); ``wave:solver_cells_padded`` counts
+the cells of the fused wave's in-trace verification blocks, from the
+launch's static config (``wave.solver_cells``).  The benchmark
+(``bench/run.py``) copies the counter into every result; its per-layer
+metrics read the tags.
 
 :func:`span` marks a phase of the host's work.  Each span is a
 ``jax.profiler.TraceAnnotation`` (so a profiler trace shows it on the

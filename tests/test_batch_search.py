@@ -32,12 +32,57 @@ def test_search_batch_bit_identical(small_world, verifier, partitions):
         assert rs.stats.as_dict() == rb.stats.as_dict()
 
 
+def _long_query(coll, n):
+    """A query of ``n`` distinct tokens, longer than any set of the
+    corpus: the tokens of its largest sets, largest first."""
+    order = np.argsort(-coll.set_sizes, kind="stable")
+    toks = np.concatenate([coll.get_set(int(i)) for i in order])
+    _, first = np.unique(toks, return_index=True)
+    q = toks[np.sort(first)][:n].astype(np.int32)
+    assert len(q) == n > coll.set_sizes.max()
+    return q
+
+
+def _record_launches(monkeypatch):
+    """The ``WaveConfig`` of every wave launched from here on."""
+    from repro.core import wave
+
+    launched, launch = [], wave.WaveRunner.launch_wave
+
+    def spy(self, *a, **kw):
+        out = launch(self, *a, **kw)
+        launched.append(out[0].cfg)
+        return out
+
+    monkeypatch.setattr(wave.WaveRunner, "launch_wave", spy)
+    return launched
+
+
+def _assert_exact(coll, sim, params, q, result):
+    """Top-k ids and scores of ``result`` against the float64 NumPy/SciPy
+    reference over every set of the corpus."""
+    ref = np.array([_reference_so(coll, sim, q, i, params.alpha)
+                    for i in range(coll.num_sets)])
+    # sets sharing no similar token are never candidates
+    top = np.sort(ref[ref > 0])[::-1][:params.k]
+    ids, lb = result.ids, result.lb.astype(np.float64)
+    assert len(ids) == len(top) > 0
+    np.testing.assert_allclose(lb, ref[ids], rtol=0, atol=1e-4)
+    np.testing.assert_allclose(lb, top, rtol=0, atol=1e-4)
+
+
+@pytest.mark.parametrize("longest", [None, 300, 514])
 def test_fused_engine_on_a_dblp_shaped_corpus_matches_the_reference(
-        dblp_world):
+        dblp_world, monkeypatch, longest):
     """Large sets through the fused engine (interpret mode): the
-    candidates pad to the 384 cover, the served batch agrees with a
-    float64 NumPy/SciPy reference in top-k ids and scores, and each
-    query searched alone gives the batch's answer bit for bit."""
+    candidates pad to the 384 cover, and the wave's query rows to the
+    cover of the cohort's longest query (384 for 300 tokens, 640 for 514,
+    where a power of two gave 512 and 1024).  The served batch agrees with
+    a float64 NumPy/SciPy reference in top-k ids and scores, and each
+    query searched alone, fused or on the host path, gives the batch's
+    answer bit for bit."""
+    from repro.core.types import lane_cover
+
     coll, sim = dblp_world
     params = SearchParams(k=5, alpha=0.8, verify_batch=8, fused="interpret")
     sizes = coll.set_sizes
@@ -45,25 +90,50 @@ def test_fused_engine_on_a_dblp_shaped_corpus_matches_the_reference(
     picks = [order[-1], order[np.searchsorted(sizes[order], 130)],
              order[len(order) // 2]]
     queries = [coll.get_set(int(i)) for i in picks]
+    if longest:
+        queries.insert(1, _long_query(coll, longest))
     eng = RequestEngine(coll, sim, params, schedule="fused")
     assert eng.schedule == "fused" and eng.pool._c_pad == 384
     assert eng.partitions[0].wave_operands()[2] == 384
+    launched = _record_launches(monkeypatch)
     served = eng.serve(queries)
+    nq_max = max(len(q) for q in queries)
+    assert {c.nq_pad for c in launched} == {lane_cover(nq_max)}
     search = KoiosSearch(coll, sim, params)
     for q, r in zip(queries, served):
         assert r.status == "ok"
-        ref = np.array([_reference_so(coll, sim, q, i, params.alpha)
-                        for i in range(coll.num_sets)])
-        # sets sharing no similar token are never candidates
-        top = np.sort(ref[ref > 0])[::-1][:params.k]
-        ids, lb = r.result.ids, r.result.lb.astype(np.float64)
-        assert len(ids) == len(top) > 0
-        np.testing.assert_allclose(lb, ref[ids], rtol=0, atol=1e-4)
-        np.testing.assert_allclose(lb, top, rtol=0, atol=1e-4)
-        alone = search.search(q, schedule="fused")
-        assert np.array_equal(alone.ids, r.result.ids)
-        assert np.array_equal(alone.lb, r.result.lb)
-        assert np.array_equal(alone.ub, r.result.ub)
+        _assert_exact(coll, sim, params, q, r.result)
+        for schedule in ("fused", "sequential"):
+            alone = search.search(q, schedule=schedule)
+            assert np.array_equal(alone.ids, r.result.ids), schedule
+            assert np.array_equal(alone.lb, r.result.lb), schedule
+            assert np.array_equal(alone.ub, r.result.ub), schedule
+
+
+def test_a_query_longer_than_every_set_of_its_shard_stays_exact(
+        dblp_world, monkeypatch):
+    """The wave's solver widens its columns to the query rows where the
+    query pad exceeds the shard's candidate cover (a 300-token query pads
+    to 384 rows; each of three shards covers its own largest set, one of
+    them with 256 columns): the answer still equals the float64
+    reference, and the host path's."""
+    from repro.core.types import lane_cover
+
+    coll, sim = dblp_world
+    params = SearchParams(k=5, alpha=0.8, verify_batch=8, fused="interpret")
+    q = _long_query(coll, 300)
+    eng = RequestEngine(coll, sim, params, partitions=3, schedule="fused")
+    assert min(s.wave_operands()[2] for s in eng.partitions) == 256
+    launched = _record_launches(monkeypatch)
+    (r,) = eng.serve([q])
+    assert r.status == "ok"
+    assert all(c.nq_pad == lane_cover(300) == 384 for c in launched)
+    assert any(c.c_pad < c.nq_pad for c in launched)
+    _assert_exact(coll, sim, params, q, r.result)
+    host = KoiosSearch(coll, sim, params, partitions=3).search(
+        q, schedule="sequential")
+    assert np.array_equal(host.ids, r.result.ids)
+    assert np.array_equal(host.lb, r.result.lb)
 
 
 def test_search_batch_k_override(small_world):

@@ -239,3 +239,32 @@ def test_the_served_continuation_builds_every_weight_block_on_the_device(
     assert c["verify:device_weight_rows"] == c["verify:solver_rows"]
     assert not [k for k in c if "weights" in k and k.startswith("d2h:")]
     assert "h2d:pairwise_dispatch" not in c
+
+
+def test_a_wave_launch_counts_its_padded_solver_cells(dblp_world):
+    """``wave:solver_cells_padded`` of one launch is rounds x B x vb x
+    nq_pad x max(nq_pad, c_pad) of its config, the query rows padded to
+    the cover of the cohort's longest query; counting it adds no transfer
+    and no wait for the device."""
+    from repro.core.token_stream import build_token_stream_batch
+    from repro.core.types import lane_cover
+
+    coll, sim = dblp_world
+    params = SearchParams(k=5, alpha=0.8, verify_batch=8, fused="interpret")
+    eng = RequestEngine(coll, sim, params, partitions=2, schedule="fused")
+    queries = sample_queries(coll, 3, seed=5)
+    streams = build_token_stream_batch(queries, sim, params.alpha)
+    shard, runner = eng.partitions[1], eng._runner
+    shard.csr_arrays()                   # the shard's one index upload
+    with instrument.counting() as c:
+        launch, _ = runner.launch_wave(
+            shard, queries, streams,
+            runner.init_theta(np.zeros(3, np.float32), 4))
+    nq_pad = lane_cover(max(len(q) for q in queries))
+    c_pad = shard.wave_operands()[2]
+    assert (launch.cfg.nq_pad, launch.cfg.c_pad) == (nq_pad, c_pad)
+    assert c["wave:solver_cells_padded"] == \
+        params.wave_rounds * 4 * 8 * nq_pad * max(nq_pad, c_pad) > 0
+    assert {k for k in c if k.startswith(("h2d:", "d2h:"))} == \
+        {"h2d:stream_upload", "h2d:wave_dispatch[s1]"}
+    assert not [k for k in c if k.startswith("span")]

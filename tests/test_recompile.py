@@ -154,11 +154,14 @@ def test_lane_cover_is_pow2_up_to_256_then_lane_multiples():
     assert all(n <= lane_cover(n) < n + 128 for n in range(129, 4097))
 
 
-def test_engine_steady_state_zero_recompiles_at_the_lane_cover(dblp_world):
+def test_engine_steady_state_zero_recompiles_at_the_lane_cover(dblp_world,
+                                                              monkeypatch):
     """The steady-state invariant on a corpus whose largest set (260) the
     cover pads to 384, not 512: the pool and every shard's dense operands
     take the cover of their own largest set, and after one pass a second
-    pass of varying batch sizes compiles nothing."""
+    pass of varying batch sizes compiles nothing.  On the fused engine a
+    cohort with a 514-token query pads its wave's query rows to 640, and
+    its launches are keys that the warm-up's grid sweep compiled."""
     from repro.core.types import lane_cover
     from repro.runtime.engine import RequestEngine
 
@@ -181,6 +184,31 @@ def test_engine_steady_state_zero_recompiles_at_the_lane_cover(dblp_world):
     before = _jit_cache_sizes()
     serve_all()
     assert _jit_cache_sizes() == before
+
+    from repro.core import wave
+    from test_batch_search import _long_query
+
+    fused = SearchParams(k=5, alpha=0.8, verify_batch=8, fused="interpret")
+    sample = [_long_query(coll, 514), pool[0]]
+    eng = RequestEngine(coll, sim, fused, partitions=2, schedule="fused")
+    build, seen, grid = wave._wave_fn, [], []
+    monkeypatch.setattr(wave, "_wave_fn",
+                        lambda cfg: seen.append(cfg) or build(cfg))
+    sweep = eng._warmup_wave_grid
+
+    def grid_sweep(queries):
+        n = len(seen)
+        sweep(queries)
+        grid.extend(seen[n:])
+
+    monkeypatch.setattr(eng, "_warmup_wave_grid", grid_sweep)
+    eng.warmup(sample)
+    seen.clear()
+    before = (build.cache_info().currsize, _jit_cache_sizes())
+    eng.serve(sample)
+    assert seen and set(seen) <= set(grid)
+    assert {c.nq_pad for c in seen} == {640}
+    assert (build.cache_info().currsize, _jit_cache_sizes()) == before
 
 
 def test_fused_engine_steady_state_zero_recompiles(small_world):
@@ -259,3 +287,37 @@ def test_fused_wave_variants_shared_across_batches(small_world):
     assert _wave_fn.cache_info().currsize == n_fns
     engine.search_batch(q2, schedule="fused")       # new batch: pow2 reuse
     assert _wave_fn.cache_info().currsize <= n_fns + 2
+
+
+def test_wave_pads_of_twitter_range_cohorts_are_the_pow2_ones():
+    """A cohort whose longest query has at most 256 tokens keeps every
+    pow2 wave key (stream tuples, query rows, bitmask words), so short-set
+    deployments compile the programs they did; above 256 only the query
+    rows take the lane cover.  ``stream_operands`` builds what
+    ``cohort_pads`` says."""
+    from repro.core.types import lane_cover, pow2
+    from repro.core.wave import WaveRunner, cohort_pads
+
+    class Stream:
+        def __init__(self, n):
+            self.token = self.q_pos = np.zeros(n, np.int32)
+            self.sim = np.zeros(n, np.float32)
+
+        def __len__(self):
+            return len(self.token)
+
+    for nq in range(1, 1025):
+        t = 3 * nq + 1
+        qs, ss = [np.zeros(nq, np.int32), np.zeros(1, np.int32)], \
+            [Stream(t), Stream(1)]
+        words = pow2(-(-nq // 32))
+        rows = pow2(nq) if nq <= 256 else lane_cover(nq)
+        assert cohort_pads(qs, ss) == (pow2(t), rows, words)
+    assert cohort_pads([np.zeros(514, np.int32)], [Stream(9)])[1] == 640
+    runner = WaveRunner(None, SearchParams(fused="interpret"))
+    for nq in (151, 256, 300, 514):
+        qs, ss = [np.arange(nq, dtype=np.int32)], [Stream(2 * nq)]
+        ops = runner.stream_operands(qs, ss, 2)
+        assert (ops.n_tuples, ops.nq_pad, ops.q_words) == \
+            cohort_pads(qs, ss)
+        assert ops.qtok.shape == (2, ops.nq_pad)

@@ -137,3 +137,36 @@ def test_rectangular_solver_compiles_at_dblp_widths(shape):
                                  shape((B,), i32)).compile().as_text()
     assert "f32[256,256,640]" in text
     assert "1024,1024" not in text and "640,640" not in text
+
+
+def test_fused_wave_compiles_at_dblp_keys(shape):
+    """One fused wave (Hungarian rounds) at a DBLP shard's widths and the
+    keys of a cohort of 16 with a 514-token query: query rows and
+    candidates both pad to the 640 cover, so the rounds solve 16 x 16
+    blocks of 640 x 640, and no solver tensor has a 1024 axis."""
+    from repro.core.matching.auction import make_eps_schedule
+    from repro.core.types import lane_cover
+    from repro.core.wave import WaveConfig, _wave_fn, cohort_pads
+
+    num_sets, total_slots, vocab = 425, 73_360, 25_159     # one DBLP shard
+    B, T, n_chunks = 16, 4096, 128
+    _, nq_pad, q_words = cohort_pads([np.zeros(514, np.int32)],
+                                     [np.zeros(T - 1)])
+    assert nq_pad == lane_cover(514) == 640 and q_words == 32
+    cfg = WaveConfig(num_sets=num_sets, total_slots=total_slots,
+                     q_words=q_words, k=10, n_chunks=n_chunks, chunk=256,
+                     n_tuples=T, nq_pad=nq_pad, c_pad=640, B=B,
+                     verify_batch=16, rounds=2, ub_mode="sound",
+                     verifier="hungarian", refine_layout="segmented",
+                     alpha=0.8, use_kernel=True)
+    i32, f32 = jnp.int32, jnp.float32
+    n_eps = len(np.asarray(make_eps_schedule(1e-4)))
+    args = (shape((B, T), i32), shape((B, T), i32), shape((B, T), f32),
+            shape((B, nq_pad), i32), shape((B,), i32), shape((B,), f32),
+            shape((vocab, DIM), f32), shape((num_sets, 640), i32),
+            shape((num_sets,), i32), shape((n_eps,), f32),
+            shape((vocab + 1,), i32), shape((total_slots + 1,), i32),
+            shape((total_slots + 1,), i32))
+    text = _wave_fn(cfg).lower(*args).compile().as_text()
+    assert "f32[16,16,640,640]" in text          # B x vb cost blocks
+    assert "[16,16,1024" not in text
