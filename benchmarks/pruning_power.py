@@ -9,11 +9,11 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.core import SearchParams, search_partition
+from repro.core import KoiosSearch, SearchParams
 from repro.data import sample_queries
 from repro.runtime.compile_cache import enable_compile_cache
 
-from .common import index_for, world
+from .common import world
 
 
 def run(datasets=("dblp", "opendata", "twitter", "wdc"), n_queries=3,
@@ -22,7 +22,7 @@ def run(datasets=("dblp", "opendata", "twitter", "wdc"), n_queries=3,
     params = SearchParams(k=k, alpha=alpha, ub_mode=ub_mode)
     for ds in datasets:
         coll, sim = world(ds)
-        index = index_for(ds)
+        engine = KoiosSearch(coll, sim, params)
         if by_cardinality:
             sizes = coll.set_sizes
             qs = np.unique(np.quantile(sizes, [0.25, 0.5, 0.75]))
@@ -37,7 +37,7 @@ def run(datasets=("dblp", "opendata", "twitter", "wdc"), n_queries=3,
             agg = {"candidates": 0, "iub_filtered": 0, "no_em": 0,
                    "em_early": 0, "em_full": 0, "post_ub": 0}
             for q in queries:
-                res = search_partition(index, q, sim, params)
+                res = engine.search(q)
                 st = res.stats
                 agg["candidates"] += st.candidates
                 agg["iub_filtered"] += st.pruned_refinement

@@ -7,11 +7,11 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.core import SearchParams, search_partition
+from repro.core import KoiosSearch, SearchParams
 from repro.data import sample_queries
 from repro.runtime.compile_cache import enable_compile_cache
 
-from .common import index_for, world
+from .common import world
 
 
 def vanilla_topk(coll, query, k):
@@ -28,9 +28,9 @@ def run(datasets=("dblp", "opendata"), n_queries=2, k=10, alpha=0.8):
     params = SearchParams(k=k, alpha=alpha)
     for ds in datasets:
         coll, sim = world(ds)
-        index = index_for(ds)
+        engine = KoiosSearch(coll, sim, params)
         for qi, q in enumerate(sample_queries(coll, n_queries, seed=23)):
-            sem = search_partition(index, q, sim, params)
+            sem = engine.search(q)
             van_ids, van_scores = vanilla_topk(coll, q, k)
             inter = len(set(sem.ids.tolist()) & set(van_ids.tolist()))
             # vanilla overlap of the semantic winners (Lemma 1 check)

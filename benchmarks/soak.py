@@ -40,6 +40,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import hashlib
 import json
 import tempfile
 import time
@@ -55,7 +56,15 @@ from repro.runtime.engine import AdmissionRouter, RouterPolicy
 from repro.runtime.fault import FaultEvent, FaultPlan
 
 from .common import world
-from .response_time import result_hash
+
+
+def result_hash(results) -> str:
+    """Stable digest of a list of SearchResults (ids + score bits)."""
+    h = hashlib.sha256()
+    for r in results:
+        h.update(np.ascontiguousarray(r.ids).tobytes())
+        h.update(np.ascontiguousarray(r.lb).tobytes())
+    return h.hexdigest()[:16]
 
 
 def zipf_trace(coll, n_requests: int, pool: int = 12, zipf_a: float = 1.3,
@@ -333,9 +342,9 @@ def run_live_update(dataset="opendata", replicas=4, partitions=2,
 
 
 def write_bench_json(record: dict, path: str, mode: str) -> None:
-    """BENCH_soak.json — same merge-under-``records[mode]`` layout as
-    the response-time artifact, so every leg's trajectory stays
-    comparable across PRs."""
+    """BENCH_soak.json — each leg's record merged under
+    ``records[mode]``, so every leg's trajectory stays comparable across
+    PRs."""
     if not path:
         return
     doc = {"benchmark": "soak", "records": {}}

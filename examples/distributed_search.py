@@ -1,14 +1,12 @@
 """Partitioned / distributed search (paper §VI scale-out).
 
-Shows the partition scheduler's shared-theta_lb mechanism: every (query x
-partition) tile runs concurrently, verification drains through one global
-queue, and a bound raised by ANY tile immediately re-prunes the others —
-including tiles of earlier partitions, which the sequential running-max
-loop can never reach (on a device mesh the exchange is an all-reduce-max
-over the (pod, data) axes, DESIGN.md §5).  Compares the overlapped
-schedule against the sequential partition loop at 1 and 4 partitions:
-identical results, and the scheduler stats show the bound feedback at
-work.
+Shows the shared-theta_lb mechanism of partitioned search: the plan runs
+one wave per partition over every query, and each query's running k-th
+score is carried from partition to partition, so later partitions prune
+against the bound earlier ones raised (on a device mesh the exchange is
+an all-reduce-max over the (pod, data) axes, DESIGN.md §5).  Searches at
+1 and 4 partitions: identical results, and the theta_lb trace shows the
+bound rising partition by partition.
 
     PYTHONPATH=src python examples/distributed_search.py
 """
@@ -22,37 +20,34 @@ emb = make_embeddings(coll.vocab_size, dim=32, seed=0)
 sim = EmbeddingSimilarity(emb)
 params = SearchParams(k=10, alpha=0.8)
 queries = sample_queries(coll, 4, seed=5)
+longest = int(np.argmax([len(q) for q in queries]))
 
 print(f"corpus: {coll.num_sets} sets, vocab {coll.vocab_size}, "
       f"|Q|={[len(q) for q in queries]}")
 
+reference = None
 for parts in (1, 4):
     engine = KoiosSearch(coll, sim, params, partitions=parts)
-    seq = engine.search_batch(queries, schedule="sequential")
-    ovl = engine.search_batch(queries, schedule="overlap")
-    for a, b in zip(seq, ovl):
+    results = engine.search_batch(queries)
+    if reference is None:
+        reference = results
+    for a, b in zip(reference, results):
         assert np.array_equal(a.ids, b.ids) and np.array_equal(a.lb, b.lb)
-    st = engine.scheduler_stats          # stats of the overlapped run
-    res = ovl[0]
+    st = engine.scheduler_stats
+    res = results[0]
     print(f"\npartitions={parts}: top-3 scores="
           f"{[round(float(s), 2) for s in res.lb[:3]]} "
-          f"(bit-identical to the sequential loop)")
+          f"(bit-identical to 1 partition)")
     print(f"  per-query: candidates={res.stats.candidates} "
           f"pruned={res.stats.pruned_refinement} "
           f"verified={res.stats.exact_matches}")
-    print(f"  scheduler: tiles={st.tiles} rounds={st.rounds} "
-          f"fused_requests={st.fused_requests} "
-          f"bound_raises={st.bound_raises} "
-          f"(backward to earlier partitions: {st.backward_raises})")
-    if st.theta_trace:
-        t0 = st.theta_trace[0]
-        tN = st.theta_trace[-1]
-        print(f"  theta_lb (query 0): {t0[0]:.3f} after refinement "
-              f"exchange -> {tN[0]:.3f} final (monotone over "
-              f"{len(st.theta_trace)} exchange points)")
+    print(f"  scheduler: {st.schedule} waves={st.waves} tiles={st.tiles} "
+          f"rounds={st.rounds} fused_requests={st.fused_requests}")
+    print(f"  theta_lb (query {longest}) after each partition wave: "
+          f"{[round(float(t[longest]), 3) for t in st.theta_trace]}")
 
-print("\noverlapped == sequential is asserted bit-for-bit across "
-      "partitions x batch x verifier modes in tests/test_scheduler.py; "
-      "on a TPU mesh the bound exchange is an all-reduce-max over the "
-      "(pod, data) axes (repro.runtime.sharding.all_reduce_max, "
-      "DESIGN.md §5).")
+print("\nBoth wave kinds (host waves, fused device waves) are asserted "
+      "bit-for-bit equal across partitions x batch x verifier modes in "
+      "tests/test_scheduler.py; on a TPU mesh the bound exchange is an "
+      "all-reduce-max over the (pod, data) axes "
+      "(repro.runtime.sharding.all_reduce_max, DESIGN.md §5).")

@@ -38,8 +38,7 @@ print("=> the paper's claim in action: only "
 
 # 4. Batched serving: many queries through ONE fused pipeline — a single
 #    stacked similarity sweep and a shared cross-query verification queue.
-#    Results are bit-identical to per-query search(); per-query latency
-#    drops >2x at batch size 8 (benchmarks/response_time.py --batched).
+#    Results are bit-identical to per-query search().
 queries = sample_queries(coll, 4, seed=43)
 for i, res in enumerate(engine.search_batch(queries)):
     print(f"batched query {i} (|Q|={len(queries[i])}): "

@@ -16,8 +16,7 @@ from .token_stream import (TokenStreamCache, build_token_stream,
 from .scheduler import (ExecutionPlan, SchedulerStats, run_plan,
                         run_fused_wave, run_wave)
 from .search import (KoiosSearch, KoiosIndex, build_partition_indexes,
-                     partition_ranges, search_partition,
-                     search_partition_batch, merge_topk, merge_topk_batch)
+                     partition_ranges, merge_topk, merge_topk_batch)
 from .baseline import baseline_topk, baseline_plus_topk, brute_force_topk
 
 __all__ = [
@@ -29,7 +28,6 @@ __all__ = [
     "ExecutionPlan", "SchedulerStats", "run_plan", "run_fused_wave",
     "run_wave",
     "KoiosSearch", "KoiosIndex", "build_partition_indexes",
-    "partition_ranges", "search_partition", "search_partition_batch",
-    "merge_topk", "merge_topk_batch",
+    "partition_ranges", "merge_topk", "merge_topk_batch",
     "baseline_topk", "baseline_plus_topk", "brute_force_topk",
 ]

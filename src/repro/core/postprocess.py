@@ -22,7 +22,7 @@ block comes back to the host, only the solver's outputs.
 
 Multi-query serving (the batched pipeline): the loop above is factored into
 a :class:`PostprocessState` state machine that *requests* verification
-batches instead of running them inline.  :func:`run_postprocess_batch`
+batches instead of running them inline.  :func:`drive_states`
 advances B queries' states in lock step and routes every round's pending
 requests through one shared :class:`VerifierPool`, which pads-and-vmaps
 across queries as well as candidates — fewer, fuller ``auction_batch`` /
@@ -34,7 +34,7 @@ to per-query ``search``.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 import jax
@@ -283,7 +283,7 @@ class PostprocessState:
     needed (returning a :class:`VerifyRequest`) or the query is finished
     (returning None); ``apply()`` folds the batch's outcome back in.  The
     request/apply cycle is exactly the inline loop of the single-query
-    path, which is what lets ``run_postprocess_batch`` drive B queries in
+    path, which is what lets :func:`drive_states` drive B queries in
     lock step with bit-identical per-query results.
     """
 
@@ -426,15 +426,15 @@ class PostprocessState:
 
 
 def drive_states(pool: VerifierPool, states: Sequence[PostprocessState],
-                 round_hook=None) -> None:
+                 round_hook: Callable[[int], None]) -> None:
     """THE post-processing drive loop: advance any number of state
     machines in lock step over one shared verification queue.  Each round
     gathers every unfinished state's pending batch, verifies them all in
-    fused solver calls, applies the outcomes, and (optionally) calls
-    ``round_hook(n_active)`` — the scheduler's bound-feedback point —
-    before the states emit their next requests.  Single-query
-    post-processing, the batched pipeline, and the partition scheduler are
-    all this loop with different state lists."""
+    fused solver calls, applies the outcomes, and calls
+    ``round_hook(n_active)`` — the scheduler's round counter — before
+    the states emit their next requests.  Host waves and the fused
+    waves' host continuation are both this loop with different state
+    lists."""
     with span("koios.verify"):
         reqs = {i: st.next_request() for i, st in enumerate(states)}
         while True:
@@ -444,29 +444,6 @@ def drive_states(pool: VerifierPool, states: Sequence[PostprocessState],
             outs = pool.verify_requests([reqs[i] for i in active])
             for i, out in zip(active, outs):
                 states[i].apply(out)
-            if round_hook is not None:
-                round_hook(len(active))
+            round_hook(len(active))
             for i in active:
                 reqs[i] = states[i].next_request()
-
-
-def run_postprocess(coll: SetCollection, query: np.ndarray, sim_provider,
-                    surv_ids: np.ndarray, surv_lb: np.ndarray,
-                    surv_ub: np.ndarray, theta_lb0: float,
-                    params: SearchParams,
-                    stats: SearchStats) -> SearchResult:
-    """Single-query post-processing — :func:`drive_states` with one state
-    (compatibility wrapper)."""
-    state = PostprocessState(query, surv_ids, surv_lb, surv_ub, theta_lb0,
-                             params, stats)
-    return run_postprocess_batch(coll, sim_provider, [state], params)[0]
-
-
-def run_postprocess_batch(coll: SetCollection, sim_provider,
-                          states: Sequence[PostprocessState],
-                          params: SearchParams) -> List[SearchResult]:
-    """B queries in lock step over one shared queue — a thin wrapper that
-    owns the pool and drains the states (see :func:`drive_states`)."""
-    pool = VerifierPool(coll, sim_provider, params)
-    drive_states(pool, states)
-    return [st.result() for st in states]

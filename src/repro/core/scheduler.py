@@ -1,38 +1,25 @@
-"""Partition-scheduled KOIOS execution engine (paper §VI scale-out).
+"""Partition-scheduled KOIOS execution (paper §VI scale-out).
 
 Every search request — single query, request batch, partitioned repository,
 or all three — is one :class:`ExecutionPlan`: a set of (query x partition)
-*tiles* driven through one shared pipeline.  The scheduler replaces the
-historical trio of hand-rolled loops (per-query search, per-partition host
-loop, per-partition batched search) with a single code path:
+*tiles*.  A plan is driven by *waves*, each running a cohort of tiles to
+completion, and there are two kinds of wave:
 
-  overlap (default)
-      All tiles' refinement scans are dispatched before any is
-      materialized (JAX dispatch is async: partition p+1's scan executes
-      on-device while the host expands events for and materializes
-      earlier tiles, with no host round-trip between partitions — the
-      sequential loop instead stalls every partition's refinement behind
-      the previous partition's full post-processing), every tile's
-      verification requests drain through ONE cross-partition/cross-query
-      :class:`VerifierPool` queue (fewer, fuller solver calls), and
-      theta_lb feedback is *bidirectional*: a bound raised by any tile's
-      verification round immediately re-prunes still-queued candidates of
-      every other tile of the same query — including tiles of *earlier*
-      partitions, which the sequential running-max loop could never reach.
-      On a device mesh the per-round bound exchange is an all-reduce-max
-      over the (pod, data) axes (``bound_exchange`` hook; see
-      ``repro.runtime.sharding.all_reduce_max`` and DESIGN.md §5).
+  host wave (:func:`run_wave`)
+      Refinement dispatched for every tile of the cohort, then the
+      verification of all of them drained through one shared
+      :class:`VerifierPool` queue.
+  fused wave (:func:`run_fused_wave`)
+      One device program per partition cohort (refinement, compaction and
+      the first verification rounds, ``core.wave``), then the same host
+      continuation.
 
-  sequential
-      The pre-scheduler reference trajectory: partitions run one after the
-      other, later partitions inheriting the running max of earlier
-      partitions' final k-th scores.  Kept (cheaply — it is the same tile
-      machinery with a different drive order) as the bit-identical
-      baseline for tests and the A/B arm of
-      ``benchmarks/response_time.py --partitions N --overlap``.
-
-Both schedules return exact top-k results; tests assert they are
-bit-identical on every (partitions x batch x verifier) combination.
+``SearchParams.fused`` picks the kind (``core.wave.fused_available``):
+fused on a TPU or with ``'interpret'``, host waves otherwise.  The request
+engine (``runtime.engine``) runs whatever cohort its admission queue
+coalesced; the one-shot :func:`run_plan` runs one wave per partition over
+every query, carrying each query's theta_lb from partition to partition.
+Both kinds return the same exact top-k, bit for bit.
 """
 from __future__ import annotations
 
@@ -65,19 +52,18 @@ def _build_streams(plan: "ExecutionPlan", sim, params: SearchParams,
 
 @dataclasses.dataclass
 class SchedulerStats:
-    """Instrumentation of one plan execution (the overlap/fused story)."""
+    """Instrumentation of one plan execution."""
 
     tiles: int = 0                 # (query x partition) tiles executed
     rounds: int = 0                # host lock-step verification rounds
     fused_requests: int = 0        # verify requests fused across tiles
-    bound_raises: int = 0          # tile thetas raised by another tile
-    backward_raises: int = 0       # ... where the source is a LATER partition
-    schedule: str = ""             # resolved drive order of this plan
+    schedule: str = ""             # wave kind run_plan ran: fused | wave
     waves: int = 0                 # waves executed (fused device programs
-    #                                or the engine's host wave steps)
+    #                                or host waves)
     device_rounds: int = 0         # verification rounds run inside waves
     theta_trace: List[np.ndarray] = dataclasses.field(default_factory=list)
-    # per-query theta_lb after each round (monotone non-decreasing rows)
+    # per-query theta_lb after each partition wave of run_plan (monotone
+    # non-decreasing rows)
 
     def as_dict(self) -> dict:
         d = dataclasses.asdict(self)
@@ -259,36 +245,37 @@ def _finish_tile(tile: _Tile, id_offset: int) -> None:
 
 
 def run_plan(plan: ExecutionPlan, sim_provider, params: SearchParams,
-             schedule: str = "overlap",
              bound_exchange: Optional[Callable] = None,
              streams=None) -> List[List[SearchResult]]:
     """Drive every tile of ``plan`` to completion; returns per-query lists
     of per-partition results (partition order), ids already globalized.
 
-    ``schedule='fused'`` runs the on-device wave pipeline on a TPU
-    backend (or anywhere with ``params.fused == 'interpret'``) and
-    resolves to ``overlap`` for ``fused='off'`` or ``'auto'`` off-TPU;
-    a fused request the wave cannot serve raises (see
-    ``core.wave.fused_available``).  All three schedules return
-    bit-identical exact results.  ``streams`` optionally
-    supplies precomputed per-query token streams (the stream-cache path,
-    DESIGN.md §3.2) instead of building them here."""
-    if schedule == "fused":
-        from .wave import fused_available
-        if not fused_available(params, sim_provider):
-            schedule = "overlap"
-    plan.stats.schedule = schedule
-    if schedule == "fused":
-        _run_fused(plan, sim_provider, params, bound_exchange,
-                   streams=streams)
-    elif schedule == "overlap":
-        _run_overlapped(plan, sim_provider, params, bound_exchange,
-                        streams=streams)
-    elif schedule == "sequential":
-        _run_sequential(plan, sim_provider, params, bound_exchange,
-                        streams=streams)
-    else:
-        raise ValueError(f"unknown schedule {schedule!r}")
+    One wave per partition, in partition order, over every query of the
+    plan: :func:`run_fused_wave` where ``core.wave.fused_available``
+    says the wave program runs, else :func:`run_wave`.  Each query's
+    theta_lb is carried on the host from partition to partition, through
+    ``bound_exchange`` (a mesh all-reduce-max, when configured) after
+    every partition but the last.  A fused request the wave cannot serve
+    raises.  ``streams`` optionally supplies precomputed per-query token
+    streams (the stream-cache path, DESIGN.md §3.2) instead of building
+    them here."""
+    from .wave import fused_available, wave_runner_for
+
+    fused = fused_available(params, sim_provider)
+    streams = _build_streams(plan, sim_provider, params, streams)
+    pool = VerifierPool(plan.pool_coll, sim_provider, params)
+    runner = wave_runner_for(sim_provider, params) if fused else None
+    plan.stats.schedule = "fused" if fused else "wave"
+    theta = plan.theta0.copy()
+    for pi in range(len(plan.indexes)):
+        tiles = [t for t in plan.tiles if t.pi == pi]
+        if runner is not None:
+            run_fused_wave(plan, tiles, streams, theta, pool, params, runner)
+        else:
+            run_wave(plan, tiles, streams, theta, pool, params)
+        if pi < len(plan.indexes) - 1:      # no consumer after the last
+            theta = _exchange(theta, bound_exchange)
+        plan.stats.theta_trace.append(theta.copy())
     return plan.results()
 
 
@@ -303,10 +290,9 @@ def run_wave(plan: ExecutionPlan, tiles: Sequence[_Tile], streams,
     engine (``runtime.engine``) calls it with whatever tile cohort the
     admission queue coalesced for this step (a tile per live request,
     each at its own next partition — continuous batching), while
-    ``_run_sequential`` drives one partition's tiles per wave.  Within
-    the wave, refinement dispatch is pipelined across all tiles and
-    verification drains through the shared ``pool`` queue — the overlap
-    machinery at wave granularity.
+    :func:`run_plan` drives one partition's tiles per wave.  Within the
+    wave, refinement dispatch is pipelined across all tiles and
+    verification drains through the shared ``pool`` queue.
     """
     plan.stats.waves += 1
     for t in tiles:
@@ -325,69 +311,12 @@ def run_wave(plan: ExecutionPlan, tiles: Sequence[_Tile], streams,
                               float(t.result.lb[params.k - 1]))
 
 
-# --------------------------------------------------------------- sequential
-def _run_sequential(plan: ExecutionPlan, sim, params: SearchParams,
-                    bound_exchange: Optional[Callable] = None,
-                    streams=None) -> None:
-    """Partitions one after the other, sharing the running max of final
-    k-th scores — the paper's host reference loop (and the historical
-    ``search``/``search_batch`` trajectory, bit for bit): one
-    :func:`run_wave` per partition.  The bound exchange (when
-    configured) runs once per completed partition, at the loop's single
-    inter-partition communication point."""
-    streams = _build_streams(plan, sim, params, streams)
-    pool = VerifierPool(plan.pool_coll, sim, params)
-    theta = plan.theta0.copy()
-    for pi in range(len(plan.indexes)):
-        run_wave(plan, [t for t in plan.tiles if t.pi == pi], streams,
-                 theta, pool, params)
-        if pi < len(plan.indexes) - 1:      # no consumer after the last
-            theta = _exchange(theta, bound_exchange)
-
-
-# ------------------------------------------------------------------ overlap
-def _run_overlapped(plan: ExecutionPlan, sim, params: SearchParams,
-                    bound_exchange: Optional[Callable],
-                    streams=None) -> None:
-    """All tiles in flight at once: pipelined refinement dispatch across
-    partitions, one global verification queue, bidirectional bounds."""
-    streams = _build_streams(plan, sim, params, streams)
-    # Dispatch EVERY tile's refinement before materializing any: the
-    # device works through later partitions' scans back-to-back while the
-    # host expands and materializes earlier tiles (the sequential loop
-    # instead parks each partition's refinement behind the previous
-    # partition's full post-processing).
-    for t in plan.tiles:
-        _launch_tile(t, streams[t.qi], plan.queries[t.qi], params)
-    live = [t for t in plan.tiles if t.result is None]
-    for t in live:
-        _materialize_tile(t)
-
-    # Initial bound exchange: every tile starts from the best refinement
-    # bound of ANY of its query's tiles (each partition's k-th greedy score
-    # lower-bounds the global k-th SO), not just its own.
-    theta = plan.theta0.copy()
-    _exchange_bounds(plan, live, theta, bound_exchange,
-                     tile_theta=lambda t: t.ref.theta_lb,
-                     raisable=lambda t: True)
-    for t in live:
-        _make_state(t, plan.queries[t.qi], theta[t.qi], params)
-
-    pool = VerifierPool(plan.pool_coll, sim, params)
-    drive_states(pool, [t.state for t in live],
-                 round_hook=lambda n: _feedback_round(plan, live, theta,
-                                                      bound_exchange, n))
-    for t in live:
-        _finish_tile(t, t.index.id_offset)
-
-
 # --------------------------------------------------------------------- fused
 def _wave_tile_state(tile: _Tile, row: int, launch, out, query,
                      theta_q: float, params: SearchParams) -> bool:
     """Resume one tile from a materialized wave's row: build its
     ``PostprocessState`` via ``PostprocessState.from_wave`` (or mark the
-    tile empty).  Returns whether the tile is live.  Shared by the
-    all-partitions fused drive and the engine's single-wave step."""
+    tile empty).  Returns whether the tile is live."""
     meta = launch.tile_meta[row]
     if meta.empty:
         tile.result = _empty_result()
@@ -460,112 +389,9 @@ def run_fused_wave(plan: ExecutionPlan, tiles: Sequence[_Tile], streams,
                                   float(t.result.lb[params.k - 1]))
 
 
-def _run_fused(plan: ExecutionPlan, sim, params: SearchParams,
-               bound_exchange: Optional[Callable],
-               streams=None) -> None:
-    """On-device wave pipeline (DESIGN.md §3): one device program per
-    partition wave — refinement chunk scans, candidate compaction,
-    theta_lb exchange, and the first R verification rounds — with waves
-    chained through a donated on-device theta carry (no host round-trip
-    between partitions).  The host drive loop resumes from each tile's
-    wave state for the remaining verification, with the same global queue
-    and bidirectional bound feedback as the overlap schedule."""
-    from .wave import _pow2, wave_runner_for
-
-    streams = _build_streams(plan, sim, params, streams)
-    runner = wave_runner_for(sim, params)
-    B_pad = _pow2(max(1, len(plan.queries)))
-    theta_dev = runner.init_theta(plan.theta0, B_pad)
-    # ONE host->device payload for the whole plan: the compact stream
-    # tuples (partition-independent) — each wave expands them in-trace
-    # through its partition's device-resident index (DESIGN.md §3.3)
-    stream_ops = runner.stream_operands(plan.queries, streams, B_pad)
-
-    # Dispatch EVERY wave before materializing any (the overlap idea, one
-    # level up): wave p+1's program queues behind wave p on-device while
-    # the host sizes and dispatches later partitions' waves.
-    launches = []
-    for index in plan.indexes:
-        launch, theta_dev = runner.launch_wave(index, plan.queries,
-                                               streams, theta_dev,
-                                               stream_ops=stream_ops)
-        launches.append(launch)
-        plan.stats.waves += 1
-        plan.stats.device_rounds += launch.cfg.rounds
-
-    instrument.record("d2h:theta_materialize")
-    theta = np.maximum(plan.theta0,
-                       np.asarray(theta_dev,
-                                  np.float64)[:len(plan.queries)])
-    plan.stats.theta_trace.append(theta.copy())
-
-    live: List[_Tile] = []
-    for pi, launch in enumerate(launches):
-        out = runner.materialize(launch)
-        for t in (t for t in plan.tiles if t.pi == pi):
-            if _wave_tile_state(t, t.qi, launch, out,
-                                plan.queries[t.qi], theta[t.qi], params):
-                live.append(t)
-
-    # host continuation: same exchange + global queue as overlap
-    _exchange_bounds(plan, live, theta, bound_exchange,
-                     tile_theta=lambda t: t.state.theta_lb,
-                     raisable=lambda t: not t.state.finished())
-    for t in live:
-        if not t.state.finished():
-            t.state.raise_theta(theta[t.qi])
-    pool = VerifierPool(plan.pool_coll, sim, params)
-    drive_states(pool, [t.state for t in live],
-                 round_hook=lambda n: _feedback_round(plan, live, theta,
-                                                      bound_exchange, n))
-    for t in live:
-        _finish_tile(t, t.index.id_offset)
-
-
 def _count_round(plan: ExecutionPlan, n_active: int) -> None:
     plan.stats.rounds += 1
     plan.stats.fused_requests += n_active
-
-
-def _feedback_round(plan: ExecutionPlan, tiles, theta: np.ndarray,
-                    bound_exchange: Optional[Callable],
-                    n_active: int) -> None:
-    """After each lock-step verification round: gather every tile's bound,
-    all-reduce across tiles (and the mesh, when configured), and push the
-    result back into every still-running tile — including tiles of earlier
-    partitions, whose queued candidates are re-pruned on their next step."""
-    _count_round(plan, n_active)
-    _exchange_bounds(plan, tiles, theta, bound_exchange,
-                     tile_theta=lambda t: t.state.theta_lb,
-                     raisable=lambda t: not t.state.finished())
-    for t in tiles:
-        if not t.state.finished():
-            t.state.raise_theta(theta[t.qi])    # no-op unless higher
-
-
-def _exchange_bounds(plan: ExecutionPlan, tiles, theta: np.ndarray,
-                     bound_exchange: Optional[Callable],
-                     tile_theta: Callable, raisable: Callable) -> None:
-    """One exchange point: fold every tile's bound into the per-query
-    ``theta`` vector (in place), all-reduce it, and account raises —
-    ``bound_raises`` for each raisable tile whose own bound is below the
-    exchanged one, ``backward_raises`` when the improving tile sits in a
-    LATER partition than the raised one.  Both overlap exchange points
-    (refinement-time and per verification round) share this accounting."""
-    source_pi = {}
-    for t in tiles:
-        v = tile_theta(t)
-        if v > theta[t.qi]:
-            theta[t.qi] = v
-            source_pi[t.qi] = t.pi
-    new_theta = _exchange(theta, bound_exchange)
-    for t in tiles:
-        if raisable(t) and new_theta[t.qi] > tile_theta(t):
-            plan.stats.bound_raises += 1
-            if source_pi.get(t.qi, t.pi) > t.pi:
-                plan.stats.backward_raises += 1
-    theta[:] = new_theta
-    plan.stats.theta_trace.append(theta.copy())
 
 
 def _exchange(theta: np.ndarray,

@@ -7,15 +7,14 @@ Pipeline per (query x partition) tile:
     w/ Lemma-8 early termination).
 
 All execution — single query, request batch, partitioned repository — is
-one :class:`repro.core.scheduler.ExecutionPlan` driven by the partition
-scheduler: ``search`` IS ``search_batch`` with B=1 IS the scheduler with
-P=1.  The default ``overlap`` schedule runs every tile concurrently (async
-refinement dispatch, one global cross-partition/cross-query verification
-queue, bidirectional theta_lb feedback); ``sequential`` replays the
-paper's host loop over partitions with the running-max shared bound —
-both return bit-identical exact results (asserted in
-tests/test_scheduler.py).  On a device mesh the per-round bound exchange
-is an all-reduce-max over the (pod, data) axes (``bound_exchange``; see
+one :class:`repro.core.scheduler.ExecutionPlan` driven by
+:func:`repro.core.scheduler.run_plan`: ``search`` IS ``search_batch`` with
+B=1.  The plan runs the request engine's per-partition waves — fused
+device waves or host waves, as ``SearchParams.fused`` selects — with the
+running theta_lb carried from partition to partition; both wave kinds
+return bit-identical exact results (tests/test_scheduler.py).  On a device
+mesh the bound exchange between partitions is an all-reduce-max over the
+(pod, data) axes (``bound_exchange``; see
 ``repro.runtime.sharding.all_reduce_max`` and DESIGN.md §5).
 """
 from __future__ import annotations
@@ -44,29 +43,6 @@ class KoiosIndex:
     def build(coll: SetCollection, id_offset: int = 0) -> "KoiosIndex":
         return KoiosIndex(coll=coll, inv=InvertedIndex.build(coll),
                           id_offset=id_offset)
-
-
-def search_partition(index: KoiosIndex, query: np.ndarray, sim_provider,
-                     params: SearchParams,
-                     theta_lb0: float = 0.0) -> SearchResult:
-    """One query against one partition (compatibility wrapper: a 1x1
-    plan); ``theta_lb0`` is the shared global bound."""
-    return search_partition_batch(index, [query], sim_provider, params,
-                                  [theta_lb0])[0]
-
-
-def search_partition_batch(index: KoiosIndex, queries: Sequence[np.ndarray],
-                           sim_provider, params: SearchParams,
-                           theta_lb0s: Sequence[float]
-                           ) -> "list[SearchResult]":
-    """B queries against one partition (compatibility wrapper: a Bx1 plan
-    on the sequential drive — with a single partition the schedules
-    coincide).  Per-query results are bit-identical to B
-    :func:`search_partition` calls."""
-    plan = ExecutionPlan([index], queries, pool_coll=index.coll,
-                         theta0=theta_lb0s, request_id_bases=[0])
-    return [rs[0] for rs in
-            run_plan(plan, sim_provider, params, schedule="sequential")]
 
 
 def partition_ranges(set_sizes: np.ndarray, partitions: int,
@@ -247,14 +223,14 @@ def merge_topk(results: Sequence[SearchResult], k: int) -> SearchResult:
 class KoiosSearch:
     """Public search API over a (possibly partitioned) repository.
 
-    ``schedule`` selects the default drive order of the partition
-    scheduler: 'fused' (default — the on-device wave pipeline on TPU,
-    resolving to 'overlap' off-TPU unless ``params.fused ==
-    'interpret'``), 'overlap', or 'sequential'; all are exact and
-    bit-identical.  ``partition_by`` picks the repository split:
-    'sets' (equal set counts) or 'tokens' (greedy token-count balance —
-    see :func:`partition_ranges`).  ``bound_exchange`` optionally plugs a
-    mesh all-reduce-max into the per-round theta_lb exchange (see
+    ``params.fused`` selects the wave kind (``core.wave.fused_available``):
+    'auto' (default) runs fused device waves on a TPU and host waves
+    elsewhere, 'interpret' runs fused waves on any backend, 'off' runs
+    host waves; all are exact and bit-identical.  ``partition_by`` picks
+    the repository split: 'sets' (equal set counts) or 'tokens' (greedy
+    token-count balance — see :func:`partition_ranges`).
+    ``bound_exchange`` optionally plugs a mesh all-reduce-max into the
+    theta_lb exchange between partitions (see
     ``repro.runtime.sharding.all_reduce_max``).  ``scheduler_stats``
     holds the :class:`SchedulerStats` of the most recent call.
     ``stream_cache`` optionally plugs a
@@ -276,7 +252,7 @@ class KoiosSearch:
 
     def __init__(self, coll: Optional[SetCollection], sim_provider,
                  params: Optional[SearchParams] = None,
-                 partitions: int = 1, schedule: str = "fused",
+                 partitions: int = 1,
                  bound_exchange: Optional[Callable] = None,
                  partition_by: str = "sets",
                  stream_cache=None, collection=None):
@@ -288,7 +264,6 @@ class KoiosSearch:
             collection = ShardedCollection.build(coll, partitions,
                                                  by=partition_by)
         self.collection = collection
-        self.schedule = schedule
         self.bound_exchange = bound_exchange
         self.stream_cache = stream_cache
         self.scheduler_stats: Optional[SchedulerStats] = None
@@ -304,22 +279,20 @@ class KoiosSearch:
     def partitions(self):
         return self.collection.shards
 
-    def search(self, query: np.ndarray, k: Optional[int] = None,
-               schedule: Optional[str] = None) -> SearchResult:
+    def search(self, query: np.ndarray,
+               k: Optional[int] = None) -> SearchResult:
         """Single-query search == ``search_batch`` with B=1."""
-        return self.search_batch([query], k=k, schedule=schedule)[0]
+        return self.search_batch([query], k=k)[0]
 
     def search_batch(self, queries: Sequence[np.ndarray],
-                     k: Optional[int] = None,
-                     schedule: Optional[str] = None
-                     ) -> "list[SearchResult]":
+                     k: Optional[int] = None) -> "list[SearchResult]":
         """Search B queries — one execution plan, every (query x
         partition) tile through the shared pipeline.
 
-        Results are exact and independent of the schedule and of the
+        Results are exact and independent of the wave kind and of the
         batch composition: ``search_batch(qs)[i]`` is bit-identical to
-        ``search(qs[i])`` (same ids, same lb/ub floats — and on the
-        default schedule the same per-phase statistics).
+        ``search(qs[i])`` (same ids, same lb/ub floats, and on host waves
+        the same per-phase statistics).
         """
         params = self.params if k is None else dataclasses.replace(
             self.params, k=k)
@@ -343,7 +316,6 @@ class KoiosSearch:
             plan = ExecutionPlan(epoch.shards, queries,
                                  pool_coll=epoch.coll, epoch=epoch.epoch)
             per_query = run_plan(plan, self.sim, params,
-                                 schedule=schedule or self.schedule,
                                  bound_exchange=self.bound_exchange,
                                  streams=streams)
             self.scheduler_stats = plan.stats

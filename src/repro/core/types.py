@@ -196,10 +196,11 @@ class SearchParams:
     # report exact SO for the returned top-k (extra verifications)
     exact_scores: bool = True
     # --- fused wave execution (DESIGN.md §3) ---
-    # 'auto' = a fused-schedule request runs the wave program on TPU (and
-    # raises there if it cannot) and resolves to overlap on other
-    # backends; 'interpret' = run the wave program on any backend (tests
-    # off the chip), Pallas kernels in interpret mode; 'off' = never fuse
+    # the wave kind (core.wave.fused_available): 'auto' = fused waves on
+    # TPU (raising there if the wave cannot serve the provider) and
+    # resolves to host waves on other backends; 'interpret' = fused waves
+    # on any backend (tests off the chip), Pallas kernels in interpret
+    # mode; 'off' = host waves
     fused: str = "auto"
     # device verification rounds executed inside each wave program before
     # the host drive loop takes over (R in DESIGN.md §3)

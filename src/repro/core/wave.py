@@ -1,13 +1,12 @@
 """On-device fused wave execution (DESIGN.md §3, fused wave program).
 
-One device program per partition *wave* — the partition's (query x
-partition) tiles for the whole request batch — chaining what the overlap
-schedule round-trips through the host (DESIGN.md §9 item 6, resolved):
+One device program per partition *wave* — one partition's (query x
+partition) tiles for a cohort of queries — chaining what host waves
+round-trip through the host (DESIGN.md §9 item 6, resolved):
 
   Stage A0 device-resident event expansion (DESIGN.md §3.3): the wave
            consumes the COMPACT token stream — (token, q, sim) tuples,
-           uploaded once per plan since streams are partition-
-           independent — and expands it to posting-level events
+           uploaded with each wave — and expands it to posting-level events
            *in-trace* through the partition's device-resident CSR
            inverted index (``InvertedIndex.device_arrays``, uploaded
            once per index lifetime), a searchsorted-on-cumsum gather
@@ -30,29 +29,28 @@ schedule round-trips through the host (DESIGN.md §9 item 6, resolved):
            refresh after every round.
 
 A wave program runs on one device: its shard's, or the default one.
-Shards share theta_lb between programs, not inside one: waves chain
-through a donated theta carry (wave p+1 consumes wave p's on-device
-theta output, hopping devices when shards are placed), and the
-scheduler's ``bound_exchange`` hook all-reduces the host-side bounds
-over a mesh at wave boundaries.  The scheduler dispatches every wave
-before materializing any (JAX async dispatch) and the host sees device
-data exactly once per wave.  The host drive loop then resumes from
-``PostprocessState.from_wave`` for whatever verification the R device
-rounds did not finish — the host path stays the bit-identical oracle.
+Shards share theta_lb between programs, not inside one: each wave starts
+from the per-query bounds the host carries (the theta upload hops to the
+shard's device when shards are placed), and the scheduler's
+``bound_exchange`` hook all-reduces the host-side bounds over a mesh at
+wave boundaries.  The host sees device data exactly once per wave, then
+resumes from ``PostprocessState.from_wave`` for whatever verification
+the R device rounds did not finish — host waves stay the bit-identical
+oracle.
 
 Exactness does not depend on the wave reproducing the host trajectory:
 every device step only ever (a) raises certified lower bounds, (b) drops
 candidates whose certified upper bound is strictly below such a bound, or
 (c) records certified [lb, ub] brackets (ambiguous auction brackets are
 resolved exactly on device, mirroring the pool's Hungarian fallback), so
-any schedule of these steps yields the same final top-k — the same
-invariant that makes overlap == sequential (DESIGN.md §3).
+any schedule of these steps yields the same final top-k — the invariant
+that makes fused waves == host waves (DESIGN.md §3).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import List, NamedTuple, Optional, Sequence
+from typing import List, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -134,11 +132,11 @@ def compact_indices(mask: jnp.ndarray):
 
 
 def fused_available(params: SearchParams, sim_provider) -> bool:
-    """Whether a request for the fused schedule runs the wave program.
+    """Whether a request for fused waves runs the wave program.
 
     ``params.fused='off'`` never fuses.  ``'auto'`` fuses on a TPU backend
-    and resolves to the overlap schedule elsewhere (callers record the
-    resolved name: ``SchedulerStats.schedule``, ``RequestEngine.schedule``).
+    and resolves to host waves elsewhere (callers record the resolved
+    name: ``SchedulerStats.schedule``, ``RequestEngine.schedule``).
     ``'interpret'`` fuses on any backend (tests off the chip): Pallas
     kernels run in interpret mode, and the wave's auction rounds take
     their inline jnp form.  Wherever the wave is to run, a provider it
@@ -152,10 +150,9 @@ def fused_available(params: SearchParams, sim_provider) -> bool:
     if (getattr(sim_provider, "name", None) != "cosine"
             or getattr(sim_provider, "table", None) is None):
         raise ValueError(
-            f"the fused schedule needs a dense cosine embedding-table "
+            f"the fused wave needs a dense cosine embedding-table "
             f"provider, got {type(sim_provider).__name__}; request "
-            f"schedule='overlap' or SearchParams(fused='off') to serve it "
-            f"on host waves")
+            f"SearchParams(fused='off') to serve it on host waves")
     return True
 
 
@@ -241,8 +238,8 @@ def cohort_pads(queries: Sequence[np.ndarray], streams
 def _wave_fn(cfg: WaveConfig):
     """Build (and cache) the jitted wave program for one static config.
 
-    The theta carry (argument 6) is donated: waves chain through it, so
-    XLA reuses one buffer for the whole plan's bound vector."""
+    The theta carry (argument 6) is donated: the program writes the
+    raised bounds into the buffer it came in."""
     alpha = jnp.float32(cfg.alpha)
     vb = min(cfg.verify_batch, cfg.num_sets)
 
@@ -421,9 +418,8 @@ class _TileMeta:
 
 @dataclasses.dataclass(frozen=True)
 class StreamOperands:
-    """Device-resident compact stream input of a plan's waves (§3.3):
-    stacked (B_pad, T_pad) stream tuples + query tokens/lengths, built
-    once per plan and shared by every partition wave."""
+    """Device-resident compact stream input of one wave (§3.3): stacked
+    (B_pad, T_pad) stream tuples + query tokens/lengths."""
 
     tok: object                      # (B_pad, T_pad) int32, -1 pad
     q_pos: object                    # (B_pad, T_pad) int32
@@ -433,26 +429,20 @@ class StreamOperands:
     n_tuples: int                    # T_pad (pow2)
     nq_pad: int                      # lane_cover(max |Q|), as cohort_pads
     q_words: int
-    _placed: dict = dataclasses.field(default_factory=dict, repr=False)
 
     def on(self, device) -> "StreamOperands":
-        """This operand set committed to ``device`` (placed-shard waves;
-        one copy per device per plan, cached).  ``device=None`` is the
-        unplaced identity — the degenerate single-place case."""
+        """This operand set committed to ``device`` (placed-shard waves).
+        ``device=None`` is the unplaced identity — the degenerate
+        single-place case."""
         if device is None:
             return self
-        hit = self._placed.get(device)
-        if hit is None:
-            import jax
-
-            instrument.record(f"h2d:stream_upload[{device.id}]")
-            hit = self._placed[device] = dataclasses.replace(
-                self, tok=jax.device_put(self.tok, device),
-                q_pos=jax.device_put(self.q_pos, device),
-                sim=jax.device_put(self.sim, device),
-                qtok=jax.device_put(self.qtok, device),
-                nqs=jax.device_put(self.nqs, device), _placed={})
-        return hit
+        instrument.record(f"h2d:stream_upload[{device.id}]")
+        return dataclasses.replace(
+            self, tok=jax.device_put(self.tok, device),
+            q_pos=jax.device_put(self.q_pos, device),
+            sim=jax.device_put(self.sim, device),
+            qtok=jax.device_put(self.qtok, device),
+            nqs=jax.device_put(self.nqs, device))
 
 
 @dataclasses.dataclass
@@ -481,7 +471,7 @@ class WaveOutputs:
 
 class WaveRunner:
     """Fused-wave context: eps schedule, compiled-program reuse, theta
-    chaining.  Collection device state is NOT owned here: every launch
+    upload.  Collection device state is NOT owned here: every launch
     *borrows* the shard's CSR triplet / dense operands / normalized
     table through the :class:`~repro.runtime.collection.Shard` accessors
     — the ShardedCollection resource is the single owner, so N engines,
@@ -491,8 +481,8 @@ class WaveRunner:
     The runner holds no per-plan state — every launch threads its carry
     explicitly — so ONE runner serves every plan/request that shares a
     (provider, params) pair; obtain it via
-    :func:`wave_runner_for` (the request engine and the fused schedule
-    both do)."""
+    :func:`wave_runner_for` (the request engine and ``run_plan`` both
+    do)."""
 
     def __init__(self, sim_provider, params: SearchParams):
         self.params = params
@@ -508,13 +498,11 @@ class WaveRunner:
     # ------------------------------------------------------------- streams
     def stream_operands(self, queries: Sequence[np.ndarray], streams,
                         B_pad: int) -> "StreamOperands":
-        """Upload the wave input ONCE per plan: the compact stacked
-        stream tuples plus query tokens/lengths.  Streams (and queries)
-        are partition-independent, so every wave of a plan shares these
-        device arrays — with the device-resident index expansion
-        (§3.3) this is the only per-plan host->device payload, replacing
-        the per-wave event-array uploads (events outnumber tuples by
-        the mean posting length)."""
+        """Upload one wave's input: the compact stacked stream tuples
+        plus query tokens/lengths.  With the device-resident index
+        expansion (§3.3) this is the wave's only host->device payload
+        besides theta, replacing an event-array upload (events
+        outnumber tuples by the mean posting length)."""
         t_pad, nq_pad, q_words = cohort_pads(queries, streams)
         st_tok = np.full((B_pad, t_pad), -1, np.int32)
         st_q = np.zeros((B_pad, t_pad), np.int32)
@@ -572,26 +560,21 @@ class WaveRunner:
 
     # -------------------------------------------------------------- launch
     def launch_wave(self, index, queries: Sequence[np.ndarray], streams,
-                    theta_dev,
-                    stream_ops: "Optional[StreamOperands]" = None
-                    ) -> "tuple[WaveLaunch, object]":
-        """Dispatch one partition wave; returns (launch, chained theta).
+                    theta_dev) -> "tuple[WaveLaunch, object]":
+        """Dispatch one partition wave; returns (launch, raised theta).
 
-        Nothing is materialized here — JAX async dispatch lets the next
-        wave queue behind this one on-device while the host sizes and
-        dispatches later waves.  The only per-wave host work left is
-        counting each tile's events from the host CSR counts (to size
-        the pow2 chunk grid); expansion itself runs in-trace from
-        ``stream_ops`` (built here when the caller didn't share one
-        across waves) and the shard's borrowed CSR arrays.
+        Nothing is materialized here (JAX async dispatch).  The host
+        work is uploading the cohort's stream operands and counting
+        each tile's events from the host CSR counts (to size the pow2
+        chunk grid); expansion itself runs in-trace from the stream
+        operands and the shard's borrowed CSR arrays.
 
         ``index`` is a :class:`~repro.runtime.collection.Shard`: its
         CSR triplet, dense operands, and normalized table are borrowed
         views owned by the ShardedCollection.  When the shard is PLACED
-        the wave runs on its device: the shared stream operands get a
+        the wave runs on its device: the stream operands get a
         per-device committed copy and the theta carry hops to the
-        shard's device — that hop IS the cross-shard bound exchange of
-        the carry-chained drive.  Unplaced shards take the identical
+        shard's device.  Unplaced shards take the identical
         code path with every placement a no-op — the degenerate
         single-device case."""
         set_tok, sizes32, c_pad = index.wave_operands()
@@ -600,8 +583,7 @@ class WaveRunner:
         coll = index.coll
         B_pad = theta_dev.shape[0]
         chunk = self.params.chunk_size
-        if stream_ops is None:
-            stream_ops = self.stream_operands(queries, streams, B_pad)
+        stream_ops = self.stream_operands(queries, streams, B_pad)
         device = getattr(index, "device", None)
         if device is not None:
             stream_ops = stream_ops.on(device)
@@ -647,9 +629,8 @@ class WaveRunner:
     # --------------------------------------------------------- materialize
     def materialize(self, launch: WaveLaunch) -> WaveOutputs:
         """One blocking device->host transfer per wave.  The trailing
-        theta output is NOT read — it was donated into the next wave's
-        program (the carry chain) and only the final wave's copy survives
-        (the scheduler reads that one directly)."""
+        theta output is NOT read here: ``launch_wave`` returned it and
+        the scheduler reads it directly."""
         instrument.record("d2h:wave_materialize")
         vals = [np.asarray(x) for x in launch.out[:-1]]
         return WaveOutputs(*vals)

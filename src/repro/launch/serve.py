@@ -95,8 +95,8 @@ class SearchServer:
     and drains it: every response carries its own admit->respond
     latency, queue time, wave count, and stream-cache attribution.
     ``batched=False`` falls back to the per-query one-shot loop
-    (identical results — the A/B baseline of
-    ``benchmarks/response_time.py``).
+    (identical results).  Both follow ``params.fused``: fused device
+    waves on a TPU or with ``'interpret'``, host waves otherwise.
 
     The repository lives in ONE :class:`ShardedCollection` resource
     (built here, optionally placed across ``shards`` devices) shared by
@@ -105,7 +105,7 @@ class SearchServer:
     through an :class:`~repro.runtime.engine.AdmissionRouter` fleet."""
 
     def __init__(self, coll, sim, params: SearchParams, partitions: int,
-                 schedule: str = "overlap", bound_exchange=None,
+                 bound_exchange=None,
                  stream_cache_bytes: int = 64 << 20, replicas: int = 1,
                  shards: int = 0, place: bool = False,
                  shed_deadlines: bool = False, fault_plan=None,
@@ -121,11 +121,10 @@ class SearchServer:
                 devices="auto" if place else None)
         self.collection = collection
         self.one_shot = KoiosSearch(None, sim, params,
-                                    schedule=schedule,
                                     bound_exchange=bound_exchange,
                                     collection=self.collection)
         engine_kwargs = dict(
-            schedule="fused" if schedule == "fused" else "wave",
+            schedule="fused",
             bound_exchange=bound_exchange,
             stream_cache_bytes=stream_cache_bytes,
             shed_deadlines=shed_deadlines)
@@ -200,9 +199,9 @@ def main(argv=None):
                          "one-shot path (A/B baseline for the engine)")
     sched = ap.add_mutually_exclusive_group()
     sched.add_argument("--sequential", action="store_true",
-                       help="serve host waves (and use the sequential "
-                            "one-shot schedule for --per-query) instead of "
-                            "the fused device waves; bit-identical results")
+                       help="serve host waves (SearchParams(fused='off'), "
+                            "--per-query included) instead of the fused "
+                            "device waves; bit-identical results")
     sched.add_argument("--interpret", action="store_true",
                        help="run the fused wave programs off the chip, "
                             "Pallas kernels in interpret mode (without it "
@@ -240,9 +239,9 @@ def main(argv=None):
     coll = dataset_preset(args.dataset, scale=args.scale, seed=0)
     emb = make_embeddings(coll.vocab_size, dim=args.dim, seed=0)
     sim = EmbeddingTableProvider(emb)
-    params = SearchParams(k=args.k, alpha=args.alpha,
-                          fused="interpret" if args.interpret else "auto")
-    schedule = "sequential" if args.sequential else "fused"
+    fused = ("off" if args.sequential else
+             "interpret" if args.interpret else "auto")
+    params = SearchParams(k=args.k, alpha=args.alpha, fused=fused)
     collection = None
     if args.snapshot_dir:
         from ..runtime.collection import ShardedCollection
@@ -252,7 +251,6 @@ def main(argv=None):
             print(f"[serve] restored collection epoch "
                   f"{collection.epoch} from {args.snapshot_dir}")
     server = SearchServer(coll, sim, params, args.partitions,
-                          schedule=schedule,
                           bound_exchange=bound_exchange,
                           replicas=args.replicas, shards=args.shards,
                           place=args.place, shed_deadlines=args.shed,

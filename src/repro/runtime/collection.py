@@ -32,10 +32,10 @@ module makes the collection a first-class **resource object**:
 
 Placement: ``ShardedCollection.build(..., devices=...)`` pins shard *i*'s
 arrays to device *i* (``jax.device_put``); each shard's fused wave then
-runs where its data lives, and the theta_lb carry hops device-to-device
-between waves (the shared-bound exchange of DESIGN.md §5 — the same
-``all_reduce_max`` contract, realised as carry chaining when waves are
-driven from one host).  ``devices=None`` leaves every array uncommitted
+runs where its data lives, and each wave's theta_lb vector hops to its
+device (the shared-bound exchange of DESIGN.md §5 — the same
+``all_reduce_max`` contract, realised as the host's per-query carry when
+waves are driven from one host).  ``devices=None`` leaves every array uncommitted
 on the default device — the single-device case is the degenerate 1-place
 instance of the same code path, not a fork.
 

@@ -130,15 +130,13 @@ class EngineResponse:
 class RequestEngine:
     """Admission-queued, stream-cached, shape-bucketed search runtime.
 
-    ``schedule``: ``"wave"`` drives host waves (works on any backend;
-    ``"overlap"``/``"sequential"`` are accepted aliases — at wave
-    granularity they coincide), ``"fused"`` runs each wave's
-    per-partition groups as fused device programs: always on TPU (a
-    provider the wave cannot serve raises), anywhere with
-    ``params.fused='interpret'``; ``fused='off'``, or ``'auto'`` off-TPU,
-    resolves to host waves (``core.wave.fused_available``).
-    ``self.schedule`` is the resolved name.  Results
-    are bit-identical across all of them and to the one-shot
+    ``schedule``: ``"wave"`` drives host waves (works on any backend),
+    ``"fused"`` runs each wave's per-partition groups as fused device
+    programs: always on TPU (a provider the wave cannot serve raises),
+    anywhere with ``params.fused='interpret'``; ``fused='off'``, or
+    ``'auto'`` off-TPU, resolves to host waves
+    (``core.wave.fused_available``).  ``self.schedule`` is the resolved
+    name.  Results are bit-identical across both and to the one-shot
     ``KoiosSearch.search_batch`` (tests/test_engine.py).
 
     ``clock``/``sleep`` are injectable for deterministic trace-replay
@@ -194,8 +192,6 @@ class RequestEngine:
             else int(max_pending)
         self.partitions = self._epoch.shards
 
-        if schedule in ("overlap", "sequential"):
-            schedule = "wave"
         assert schedule in ("wave", "fused"), schedule
         self._runner = None
         if schedule == "fused":

@@ -1,6 +1,8 @@
 """Batched multi-query pipeline: bit-exact equivalence with per-query
 search across verifier modes, batched token-stream equivalence, and the
 vectorized event expansion."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -99,15 +101,17 @@ def test_fused_engine_on_a_dblp_shaped_corpus_matches_the_reference(
     served = eng.serve(queries)
     nq_max = max(len(q) for q in queries)
     assert {c.nq_pad for c in launched} == {lane_cover(nq_max)}
-    search = KoiosSearch(coll, sim, params)
+    searches = {fused: KoiosSearch(coll, sim,
+                                   dataclasses.replace(params, fused=fused))
+                for fused in ("interpret", "off")}
     for q, r in zip(queries, served):
         assert r.status == "ok"
         _assert_exact(coll, sim, params, q, r.result)
-        for schedule in ("fused", "sequential"):
-            alone = search.search(q, schedule=schedule)
-            assert np.array_equal(alone.ids, r.result.ids), schedule
-            assert np.array_equal(alone.lb, r.result.lb), schedule
-            assert np.array_equal(alone.ub, r.result.ub), schedule
+        for fused, search in searches.items():
+            alone = search.search(q)
+            assert np.array_equal(alone.ids, r.result.ids), fused
+            assert np.array_equal(alone.lb, r.result.lb), fused
+            assert np.array_equal(alone.ub, r.result.ub), fused
 
 
 def test_a_query_longer_than_every_set_of_its_shard_stays_exact(
@@ -130,8 +134,8 @@ def test_a_query_longer_than_every_set_of_its_shard_stays_exact(
     assert all(c.nq_pad == lane_cover(300) == 384 for c in launched)
     assert any(c.c_pad < c.nq_pad for c in launched)
     _assert_exact(coll, sim, params, q, r.result)
-    host = KoiosSearch(coll, sim, params, partitions=3).search(
-        q, schedule="sequential")
+    host = KoiosSearch(coll, sim, dataclasses.replace(params, fused="off"),
+                       partitions=3).search(q)
     assert np.array_equal(host.ids, r.result.ids)
     assert np.array_equal(host.lb, r.result.lb)
 

@@ -1,8 +1,10 @@
 """Continuous-batching request engine (DESIGN.md §3.2): staggered
 arrivals with mid-flight joins are bit-identical to the one-shot
-``search_batch`` path across schedules x partitions x verifiers; the
+``search_batch`` path across wave kinds x partitions x verifiers; the
 stream cache serves bit-identical streams on hits and evicts LRU; the
 engine reports true per-request lifecycle timings."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -28,6 +30,11 @@ def _params(verifier="hungarian", fused=False):
                         fused="interpret" if fused else "auto")
 
 
+def _host(params):
+    """The same parameters on host waves: the one-shot reference."""
+    return dataclasses.replace(params, fused="off")
+
+
 @pytest.mark.parametrize("verifier", ["hungarian", "auction", "hybrid"])
 @pytest.mark.parametrize("partitions", [1, 4])
 @pytest.mark.parametrize("schedule", ["wave", "fused"])
@@ -40,8 +47,8 @@ def test_engine_staggered_bitwise_vs_one_shot(small_world, verifier,
     coll, sim = small_world
     params = _params(verifier, fused=(schedule == "fused"))
     queries = sample_queries(coll, 5, seed=5)
-    one_shot = KoiosSearch(coll, sim, params, partitions=partitions)
-    ref = one_shot.search_batch(queries, schedule="sequential")
+    one_shot = KoiosSearch(coll, sim, _host(params), partitions=partitions)
+    ref = one_shot.search_batch(queries)
 
     clock, advance, sleep = _fake_clock()
     eng = RequestEngine(coll, sim, params, partitions=partitions,
@@ -83,18 +90,19 @@ def test_engine_staggered_bitwise_vs_one_shot(small_world, verifier,
 
 
 def test_engine_serve_matches_every_one_shot_schedule(small_world):
-    """engine == sequential == overlap == fused(one-shot), bitwise."""
+    """engine == one-shot host waves == one-shot fused waves, bitwise."""
     coll, sim = small_world
     params = _params()
     queries = sample_queries(coll, 6, seed=23)
-    one_shot = KoiosSearch(coll, sim, params, partitions=3)
     eng = RequestEngine(coll, sim, params, partitions=3)
     resp = eng.serve(queries)
-    for sched in ("sequential", "overlap"):
-        for r, a in zip(resp, one_shot.search_batch(queries,
-                                                    schedule=sched)):
-            assert np.array_equal(r.result.ids, a.ids)
-            assert np.array_equal(r.result.lb, a.lb)
+    for fused in ("off", "interpret"):
+        one_shot = KoiosSearch(
+            coll, sim, dataclasses.replace(params, fused=fused),
+            partitions=3)
+        for r, a in zip(resp, one_shot.search_batch(queries)):
+            assert np.array_equal(r.result.ids, a.ids), fused
+            assert np.array_equal(r.result.lb, a.lb), fused
 
 
 def test_stream_cache_hit_parity(small_world):
@@ -230,8 +238,8 @@ def test_plan_query_ring_bounded(small_world):
                         clock=clock, sleep=sleep)
     eng.plan.compact_min = 8             # trigger the ring at test scale
     queries = sample_queries(coll, 6, seed=21)
-    one_shot = KoiosSearch(coll, sim, params, partitions=2)
-    ref = one_shot.search_batch(queries, schedule="sequential")
+    one_shot = KoiosSearch(coll, sim, _host(params), partitions=2)
+    ref = one_shot.search_batch(queries)
 
     served, max_len = 0, 0
     for cycle in range(12):

@@ -76,7 +76,7 @@ def test_engine_sweep_compiles_olog(small_world):
     before = (_run_refinement._cache_size(),
               auction_batch._cache_size(), hungarian_batch._cache_size())
     for q in queries:
-        engine.search(q, schedule="overlap")
+        engine.search(q)
     grew = (_run_refinement._cache_size() - before[0],
             auction_batch._cache_size() - before[1],
             hungarian_batch._cache_size() - before[2])
@@ -93,7 +93,7 @@ def test_engine_sweep_compiles_olog(small_world):
     mid = (_run_refinement._cache_size(),
            auction_batch._cache_size(), hungarian_batch._cache_size())
     for q in queries:
-        engine.search(q, schedule="overlap")
+        engine.search(q)
     assert (_run_refinement._cache_size(),
             auction_batch._cache_size(),
             hungarian_batch._cache_size()) == mid
@@ -271,7 +271,7 @@ def test_sharded_engine_warmup_zero_steady_state_recompiles(small_world):
 
 def test_fused_wave_variants_shared_across_batches(small_world):
     """The wave program's static config depends only on pow2-padded
-    shapes: rerunning the fused schedule with a different batch of the
+    shapes: rerunning fused waves with a different batch of the
     same padded size must not recompile."""
     from repro.core.wave import _wave_fn
 
@@ -281,11 +281,11 @@ def test_fused_wave_variants_shared_across_batches(small_world):
     engine = KoiosSearch(coll, sim, params, partitions=2)
     q1 = sample_queries(coll, 3, seed=1)
     q2 = sample_queries(coll, 3, seed=2)
-    engine.search_batch(q1, schedule="fused")
+    engine.search_batch(q1)
     n_fns = _wave_fn.cache_info().currsize
-    engine.search_batch(q1, schedule="fused")       # same shapes: no growth
+    engine.search_batch(q1)       # same shapes: no growth
     assert _wave_fn.cache_info().currsize == n_fns
-    engine.search_batch(q2, schedule="fused")       # new batch: pow2 reuse
+    engine.search_batch(q2)       # new batch: pow2 reuse
     assert _wave_fn.cache_info().currsize <= n_fns + 2
 
 

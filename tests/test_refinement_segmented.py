@@ -3,6 +3,8 @@
 wave's in-trace expansion are bit-identical to the serial host path,
 and the cross-set commutativity the layout rests on holds as a
 property."""
+import dataclasses
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -59,18 +61,18 @@ def test_segmented_matches_serial_bitwise(small_world, ub_mode, chunk):
 @pytest.mark.parametrize("partitions", [1, 2, 4])
 def test_segmented_engine_bitwise(small_world, partitions):
     """End-to-end: an engine on the segmented layout returns results
-    bit-identical to the serial layout on every schedule."""
+    bit-identical to the serial layout on both wave kinds."""
     coll, sim = small_world
     queries = sample_queries(coll, 4, seed=5)
     results = {}
     for layout in ("serial", "segmented"):
-        params = SearchParams(k=5, alpha=0.8, chunk_size=64, verify_batch=8,
-                              refine_layout=layout)
-        engine = KoiosSearch(coll, sim, params, partitions=partitions)
-        for schedule in ("sequential", "overlap"):
-            results[(layout, schedule)] = engine.search_batch(
-                queries, schedule=schedule)
-    base = results[("serial", "sequential")]
+        for fused in ("off", "interpret"):
+            params = SearchParams(k=5, alpha=0.8, chunk_size=64,
+                                  verify_batch=8, refine_layout=layout,
+                                  fused=fused)
+            engine = KoiosSearch(coll, sim, params, partitions=partitions)
+            results[(layout, fused)] = engine.search_batch(queries)
+    base = results[("serial", "off")]
     for key, rs in results.items():
         for a, b in zip(base, rs):
             assert np.array_equal(a.ids, b.ids), key
@@ -88,8 +90,9 @@ def test_fused_device_expansion_bitwise(small_world, layout):
                           fused="interpret", refine_layout=layout)
     engine = KoiosSearch(coll, sim, params, partitions=2)
     queries = sample_queries(coll, 4, seed=5)
-    seq = engine.search_batch(queries, schedule="sequential")
-    fus = engine.search_batch(queries, schedule="fused")
+    seq = KoiosSearch(coll, sim, dataclasses.replace(params, fused="off"),
+                      partitions=2).search_batch(queries)
+    fus = engine.search_batch(queries)
     assert engine.scheduler_stats.schedule == "fused"
     for a, b in zip(seq, fus):
         assert np.array_equal(a.ids, b.ids)

@@ -1,119 +1,129 @@
-"""Partition scheduler: overlapped execution is bit-identical to the
-sequential partition loop, the fused on-device wave schedule is
-bit-identical to both (across partitions x batch x verifier modes), theta_lb
-is monotone over scheduler steps, and the mesh bound exchange changes
-nothing."""
+"""Partition scheduler: the one-shot search (one wave per partition,
+theta_lb carried between partitions) matches the brute-force oracle, fused
+device waves are bit-identical to host waves (across partitions x batch x
+verifier modes), theta_lb is monotone over the partition waves, and the
+mesh bound exchange changes nothing."""
 import dataclasses
 
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (EmbeddingSimilarity, ExecutionPlan, KoiosSearch,
-                        SearchParams, partition_ranges, run_plan)
+from repro.core import (EmbeddingSimilarity, ExecutionPlan, KoiosIndex,
+                        KoiosSearch, SearchParams, brute_force_topk,
+                        partition_ranges, run_plan)
 from repro.data import make_collection, make_embeddings, sample_queries
 
 
-@pytest.mark.parametrize("verifier", ["hungarian", "auction", "hybrid"])
-@pytest.mark.parametrize("partitions", [1, 2, 4])
-@pytest.mark.parametrize("batch", [1, 8])
-def test_overlap_matches_sequential_bitwise(small_world, verifier,
-                                            partitions, batch):
-    """The tentpole guarantee: the overlapped partition schedule (async
-    refinement dispatch, global verify queue, bidirectional bounds)
-    returns the same ids and the same lb/ub floats as the pre-scheduler
-    sequential running-max loop."""
+def _params(**kw):
+    return SearchParams(k=5, alpha=0.8, chunk_size=64, verify_batch=8, **kw)
+
+
+@pytest.fixture(scope="module")
+def brute_force(small_world):
+    """The oracle's top-k of each query batch of the grid below, computed
+    once per batch (it depends on neither verifier nor partitions)."""
     coll, sim = small_world
-    params = SearchParams(k=5, alpha=0.8, chunk_size=64, verify_batch=8,
-                          verifier=verifier)
-    engine = KoiosSearch(coll, sim, params, partitions=partitions)
-    queries = sample_queries(coll, batch, seed=5)
-    seq = engine.search_batch(queries, schedule="sequential")
-    ovl = engine.search_batch(queries, schedule="overlap")
-    for a, b in zip(seq, ovl):
-        assert np.array_equal(a.ids, b.ids)
-        assert np.array_equal(a.lb, b.lb)          # bit-identical floats
-        assert np.array_equal(a.ub, b.ub)
+    index = KoiosIndex.build(coll)
+    return {batch: [brute_force_topk(index, q, sim, _params())
+                    for q in sample_queries(coll, batch, seed=5)]
+            for batch in (1, 8)}
 
 
 @pytest.mark.parametrize("verifier", ["hungarian", "auction", "hybrid"])
 @pytest.mark.parametrize("partitions", [1, 2, 4])
 @pytest.mark.parametrize("batch", [1, 8])
-def test_fused_matches_overlap_and_sequential_bitwise(small_world, verifier,
-                                                      partitions, batch):
-    """The PR-3 tentpole guarantee: the fused on-device wave schedule
-    (refinement chunk scans + compaction + the first R verification
-    rounds as ONE device program per partition wave, interpret mode on
-    CPU) returns the same ids and the same lb/ub floats as both host
-    schedules."""
+def test_one_shot_matches_brute_force(small_world, brute_force, verifier,
+                                      partitions, batch):
+    """The one-shot search on host waves (one wave per partition, the
+    running theta_lb carried from partition to partition) returns the
+    oracle's top-k scores, whatever the verifier and the split."""
     coll, sim = small_world
-    params = SearchParams(k=5, alpha=0.8, chunk_size=64, verify_batch=8,
-                          verifier=verifier, fused="interpret")
-    engine = KoiosSearch(coll, sim, params, partitions=partitions)
+    engine = KoiosSearch(coll, sim, _params(verifier=verifier, fused="off"),
+                         partitions=partitions)
     queries = sample_queries(coll, batch, seed=5)
-    seq = engine.search_batch(queries, schedule="sequential")
-    ovl = engine.search_batch(queries, schedule="overlap")
-    fus = engine.search_batch(queries, schedule="fused")
+    results = engine.search_batch(queries)
+    assert engine.scheduler_stats.schedule == "wave"
+    assert engine.scheduler_stats.waves == partitions
+    for res, ref in zip(results, brute_force[batch]):
+        assert 0 < len(res.lb) <= 5
+        assert np.allclose(np.sort(res.lb), np.sort(ref.lb[:len(res.lb)]),
+                           atol=1e-3)
+
+
+@pytest.mark.parametrize("verifier", ["hungarian", "auction", "hybrid"])
+@pytest.mark.parametrize("partitions", [1, 2, 4])
+@pytest.mark.parametrize("batch", [1, 8])
+def test_fused_matches_host_waves_bitwise(small_world, verifier, partitions,
+                                          batch):
+    """The fused on-device waves (refinement chunk scans + compaction +
+    the first R verification rounds as ONE device program per partition
+    wave, interpret mode on CPU) return the same ids and the same lb/ub
+    floats as host waves."""
+    coll, sim = small_world
+    queries = sample_queries(coll, batch, seed=5)
+    host = KoiosSearch(coll, sim, _params(verifier=verifier, fused="off"),
+                       partitions=partitions).search_batch(queries)
+    engine = KoiosSearch(coll, sim,
+                         _params(verifier=verifier, fused="interpret"),
+                         partitions=partitions)
+    fus = engine.search_batch(queries)
     st = engine.scheduler_stats
     assert st.schedule == "fused"          # really took the wave path
     assert st.waves == partitions
-    for a, b, c in zip(seq, ovl, fus):
+    for a, c in zip(host, fus):
         assert np.array_equal(a.ids, c.ids)
         assert np.array_equal(a.lb, c.lb)          # bit-identical floats
         assert np.array_equal(a.ub, c.ub)
-        assert np.array_equal(b.ids, c.ids)
-        assert np.array_equal(b.lb, c.lb)
-        assert np.array_equal(b.ub, c.ub)
 
 
-def test_fused_falls_back_to_overlap_off_tpu(small_world, monkeypatch):
-    """``fused='auto'`` resolves a fused request to overlap off-TPU, and
-    says so (exact results either way).  On a TPU backend nothing falls
-    back quietly: a fused request the wave cannot serve raises, and only
-    ``fused='off'`` resolves it to overlap."""
+def test_fused_falls_back_to_host_waves_off_tpu(small_world, monkeypatch):
+    """``fused='auto'`` runs host waves off-TPU, and says so (exact
+    results either way).  On a TPU backend nothing falls back quietly: a
+    fused request the wave cannot serve raises, and only ``fused='off'``
+    resolves it to host waves."""
     from repro.core import NGramJaccardSimilarity
 
     coll, sim = small_world
-    params = SearchParams(k=5, alpha=0.8, chunk_size=64, verify_batch=8)
-    engine = KoiosSearch(coll, sim, params, partitions=2)   # schedule=fused
+    params = _params()                                      # fused='auto'
+    engine = KoiosSearch(coll, sim, params, partitions=2)
     q = sample_queries(coll, 1, seed=9)[0]
-    r_fused = engine.search(q)
-    assert engine.scheduler_stats.schedule == "overlap"
-    assert engine.scheduler_stats.waves == 0
-    r_seq = engine.search(q, schedule="sequential")
-    assert np.array_equal(r_fused.ids, r_seq.ids)
-    assert np.array_equal(r_fused.lb, r_seq.lb)
+    r_auto = engine.search(q)
+    assert engine.scheduler_stats.schedule == "wave"
+    assert engine.scheduler_stats.device_rounds == 0
+    r_off = KoiosSearch(coll, sim, dataclasses.replace(params, fused="off"),
+                        partitions=2).search(q)
+    assert np.array_equal(r_auto.ids, r_off.ids)
+    assert np.array_equal(r_auto.lb, r_off.lb)
 
     import jax
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     ngram = NGramJaccardSimilarity(
         (np.random.default_rng(0).random((coll.vocab_size, 64)) > 0.7)
         .astype(np.float32))
-    with pytest.raises(ValueError, match="fused schedule"):
+    with pytest.raises(ValueError, match="fused wave"):
         KoiosSearch(coll, ngram, params, partitions=2).search(q)
     off = KoiosSearch(coll, ngram, dataclasses.replace(params, fused="off"),
                       partitions=2)
     off.search(q)
-    assert off.scheduler_stats.schedule == "overlap"
+    assert off.scheduler_stats.schedule == "wave"
 
 
 def test_fused_with_mesh_exchange_identical(small_world):
-    """The fused schedule with the mesh all-reduce-max bound exchange at
-    its exchange points (single-device mesh: identity) changes no
-    result."""
+    """Fused waves with the mesh all-reduce-max bound exchange between
+    partitions (single-device mesh: identity) change no result."""
     from repro.launch.mesh import bound_exchange_mesh
     from repro.runtime.sharding import bound_exchange_for
 
     coll, sim = small_world
     mesh = bound_exchange_mesh()
-    params = SearchParams(k=5, alpha=0.8, chunk_size=64, verify_batch=8,
-                          fused="interpret")
+    params = _params(fused="interpret")
     host = KoiosSearch(coll, sim, params, partitions=4)
     meshed = KoiosSearch(coll, sim, params, partitions=4,
                          bound_exchange=bound_exchange_for(mesh))
     queries = sample_queries(coll, 3, seed=41)
-    for a, b in zip(host.search_batch(queries, schedule="fused"),
-                    meshed.search_batch(queries, schedule="fused")):
+    for a, b in zip(host.search_batch(queries),
+                    meshed.search_batch(queries)):
         assert np.array_equal(a.ids, b.ids)
         assert np.array_equal(a.lb, b.lb)
 
@@ -170,8 +180,8 @@ def test_search_is_search_batch_is_the_scheduler(small_world):
 
 
 def test_batch_rows_independent_of_batch_composition(small_world):
-    """A query's trajectory through the overlapped scheduler must not
-    depend on which other queries share the plan (per-query bounds, shared
+    """A query's trajectory through the scheduler must not depend on
+    which other queries share the plan (per-query bounds, shared
     execution only)."""
     coll, sim = small_world
     params = SearchParams(k=5, alpha=0.8, chunk_size=64, verify_batch=8)
@@ -189,8 +199,8 @@ def test_batch_rows_independent_of_batch_composition(small_world):
 @given(st.integers(0, 10_000), st.integers(2, 4))
 def test_theta_monotone_over_scheduler_steps(seed, partitions):
     """Property: every query's theta_lb is non-decreasing across the
-    scheduler's exchange points (initial refinement exchange + one per
-    verification round), and the final bound is what the tiles report."""
+    plan's partition waves (one trace row each), and the final bound is
+    what the tiles report."""
     rng = np.random.default_rng(seed)
     coll = make_collection(num_sets=60, vocab_size=300, avg_size=6,
                            max_size=12, seed=seed)
@@ -201,7 +211,7 @@ def test_theta_monotone_over_scheduler_steps(seed, partitions):
     queries = sample_queries(coll, 3, seed=seed)
     results = engine.search_batch(queries)
     trace = engine.scheduler_stats.theta_trace
-    assert len(trace) >= 1
+    assert len(trace) == partitions
     for prev, cur in zip(trace, trace[1:]):
         assert np.all(cur >= prev - 1e-12), (prev, cur)
     for qi, res in enumerate(results):
@@ -241,6 +251,8 @@ def test_scheduler_stats_populated(small_world):
     assert st.tiles == 4 * len(queries)
     assert st.rounds >= 1
     assert st.fused_requests >= st.rounds
-    assert st.backward_raises <= st.bound_raises
+    assert st.waves == 4
+    assert len(st.theta_trace) == 4          # one row per partition wave
     d = st.as_dict()
     assert isinstance(d["theta_trace"], list)
+    assert len(d["theta_trace"]) == 4

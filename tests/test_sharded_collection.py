@@ -1,5 +1,5 @@
 """Sharded collection resource (DESIGN.md §5): the N-shard repository is
-bit-identical to the 1-shard reference across shard counts x schedules x
+bit-identical to the 1-shard reference across shard counts x wave kinds x
 verifiers, theta_lb stays monotone under the cross-shard bound exchange,
 the ShardedCollection is the ONLY owner of collection device state
 (upload-once, every consumer borrows), the device-side top-k merge tree
@@ -46,27 +46,26 @@ def _params(verifier="hungarian", fused=None):
 @pytest.mark.parametrize("shards", [1, 2, 4])
 def test_sharded_matches_one_shard_bitwise(small_world, verifier, shards):
     """The tentpole guarantee: N contiguous-range shards return the same
-    ids and the same lb/ub floats as the unsharded repository, under
-    every schedule (sequential host loop, overlapped scheduler, fused
-    on-device waves + the device-side top-k merge tree)."""
+    ids and the same lb/ub floats as the unsharded repository, on both
+    wave kinds (host waves, fused on-device waves) + the device-side
+    top-k merge tree."""
     coll, sim = small_world
-    params = _params(verifier, fused="interpret")
-    reference = KoiosSearch(None, sim, params,
+    reference = KoiosSearch(None, sim, _params(verifier, fused="off"),
                             collection=ShardedCollection.build(coll, 1))
-    sharded = KoiosSearch(
-        None, sim, params,
-        collection=ShardedCollection.build(coll, shards))
-    assert sharded.collection.num_shards == shards
+    sharded_sc = ShardedCollection.build(coll, shards)
+    assert sharded_sc.num_shards == shards
     queries = sample_queries(coll, 4, seed=5)
-    ref = reference.search_batch(queries, schedule="sequential")
-    for schedule in ("sequential", "overlap", "fused"):
-        got = sharded.search_batch(queries, schedule=schedule)
-        if schedule == "fused":
-            assert sharded.scheduler_stats.schedule == "fused"
+    ref = reference.search_batch(queries)
+    for fused in ("off", "interpret"):
+        sharded = KoiosSearch(None, sim, _params(verifier, fused=fused),
+                              collection=sharded_sc)
+        got = sharded.search_batch(queries)
+        assert sharded.scheduler_stats.schedule == (
+            "fused" if fused == "interpret" else "wave")
         for a, b in zip(ref, got):
-            assert np.array_equal(a.ids, b.ids), schedule
-            assert np.array_equal(a.lb, b.lb), schedule   # bit-identical
-            assert np.array_equal(a.ub, b.ub), schedule
+            assert np.array_equal(a.ids, b.ids), fused
+            assert np.array_equal(a.lb, b.lb), fused   # bit-identical
+            assert np.array_equal(a.ub, b.ub), fused
 
 
 def test_shard_ranges_cover_collection(small_world):
@@ -84,19 +83,19 @@ def test_shard_ranges_cover_collection(small_world):
 
 
 # ------------------------------------------------- theta_lb monotonicity
-@pytest.mark.parametrize("schedule", ["overlap", "fused"])
-def test_theta_monotone_under_cross_shard_exchange(small_world, schedule):
+@pytest.mark.parametrize("fused", ["off", "interpret"])
+def test_theta_monotone_under_cross_shard_exchange(small_world, fused):
     """The shared theta_lb bound is only ever raised as waves cross shard
     boundaries — every exchange point in the trace is >= its
     predecessor, so cross-shard pruning is certified."""
     coll, sim = small_world
     engine = KoiosSearch(
-        None, sim, _params(fused="interpret"),
+        None, sim, _params(fused=fused),
         collection=ShardedCollection.build(coll, 4))
     queries = sample_queries(coll, 3, seed=31)
-    results = engine.search_batch(queries, schedule=schedule)
+    results = engine.search_batch(queries)
     trace = engine.scheduler_stats.theta_trace
-    assert len(trace) >= 1
+    assert len(trace) == 4                   # one row per shard wave
     for prev, cur in zip(trace, trace[1:]):
         assert np.all(cur >= prev - 1e-12), (prev, cur)
     for qi, res in enumerate(results):
@@ -119,11 +118,11 @@ def test_collection_is_sole_owner_upload_once(small_world):
     queries = sample_queries(coll, 2, seed=9)
 
     with instrument.counting() as cold:
-        a.search_batch(queries, schedule="fused")
+        a.search_batch(queries)
     assert cold["h2d:index_upload"] == sc.num_shards     # one per shard
     with instrument.counting() as warm:
-        b.search_batch(queries, schedule="fused")
-        a.search_batch(queries, schedule="fused")
+        b.search_batch(queries)
+        a.search_batch(queries)
     assert warm["h2d:index_upload"] == 0     # second consumer re-borrows
 
     for s in sc.shards:                      # borrows are cached objects
@@ -165,8 +164,8 @@ def test_placed_shards_bitwise_and_pinned(small_world):
     queries = sample_queries(coll, 3, seed=5)
 
     with instrument.counting() as c:
-        got = placed.search_batch(queries, schedule="fused")
-    ref = reference.search_batch(queries, schedule="fused")
+        got = placed.search_batch(queries)
+    ref = reference.search_batch(queries)
     for a, b in zip(ref, got):
         assert np.array_equal(a.ids, b.ids)
         assert np.array_equal(a.lb, b.lb)
@@ -180,7 +179,7 @@ def test_placed_shards_bitwise_and_pinned(small_world):
     if len(devices) > 1:                    # theta carry hopped devices
         assert any(k.startswith("h2d:theta_hop") for k in c)
     with instrument.counting() as warm:     # steady state: no re-upload
-        placed.search_batch(queries, schedule="fused")
+        placed.search_batch(queries)
     assert not any(site in k for k in warm
                    for site in ("index_upload", "operand_upload",
                                 "table_upload")), dict(warm)
