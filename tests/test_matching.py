@@ -1,5 +1,6 @@
 """Matching-layer tests: greedy / Hungarian / auction vs the scipy oracle."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -60,13 +61,25 @@ RECT_CASES = {
 }
 
 
+def _rect_block(case):
+    """The padded batch (w (B, R, C), nqs, ncs) of a ``RECT_CASES`` case."""
+    B, (q_lo, q_hi), (c_lo, c_hi), widest, thresh = RECT_CASES[case]
+    rng = np.random.default_rng(sorted(RECT_CASES).index(case))
+    nqs = rng.integers(q_lo, q_hi + 1, B).astype(np.int32)
+    ncs = rng.integers(c_lo, c_hi + 1, B).astype(np.int32)
+    nqs[0], ncs[0] = q_hi, c_hi
+    R, C = lane_cover(max(q_hi, 1), 8), lane_cover(widest, 8)
+    w = np.zeros((B, R, C), np.float32)
+    for b in range(B):
+        w[b, :nqs[b], :ncs[b]] = _random_weights(rng, nqs[b], ncs[b], thresh)
+    return w, nqs, ncs
+
+
 def _square_solve(w, nq, nc):
     """The square solver ``_hungarian_padded`` ran before its rectangle
     form (kept here as the comparison): -w zero-padded to (n, n),
     n = max(R, C), the first nq rows augmented, each Dijkstra scan taking
     the lowest-index column among ties.  Returns (SO, col4row)."""
-    import jax
-
     n = max(w.shape)
     cost = jnp.zeros((n, n), jnp.float32).at[:w.shape[0], :w.shape[1]].set(
         -w.astype(jnp.float32))
@@ -129,18 +142,11 @@ def test_rectangular_solve_equals_scipy_and_the_square_solve(case):
     of the square solver it replaced (``_square_solve``), and so does the
     weight of its matching; it leaves rows without an edge unmatched.
     Its lockstep scan count is at least one scan per augmented row."""
-    B, (q_lo, q_hi), (c_lo, c_hi), widest, thresh = RECT_CASES[case]
-    rng = np.random.default_rng(sorted(RECT_CASES).index(case))
-    nqs = rng.integers(q_lo, q_hi + 1, B).astype(np.int32)
-    ncs = rng.integers(c_lo, c_hi + 1, B).astype(np.int32)
-    nqs[0], ncs[0] = q_hi, c_hi
-    R, C = lane_cover(max(q_hi, 1), 8), lane_cover(widest, 8)
-    w = np.zeros((B, R, C), np.float32)
-    oracles = []
-    for b in range(B):
-        wb = _random_weights(rng, nqs[b], ncs[b], thresh)
-        w[b, :nqs[b], :ncs[b]] = wb
-        oracles.append(_oracle(wb) if wb.size else 0.0)
+    thresh = RECT_CASES[case][-1]
+    w, nqs, ncs = _rect_block(case)
+    B, R, C = w.shape
+    oracles = [_oracle(w[b, :nqs[b], :ncs[b]]) if nqs[b] * ncs[b] else 0.0
+               for b in range(B)]
     so, col4row, steps = hungarian_batch(jnp.asarray(w), jnp.asarray(nqs),
                                          jnp.asarray(ncs))
     so, col4row = np.asarray(so), np.asarray(col4row)
@@ -160,19 +166,154 @@ def test_rectangular_solve_equals_scipy_and_the_square_solve(case):
         assert not so.any() and int(steps) == 0
 
 
+def _all_ties_block():
+    """The block of ``test_ties_end_each_scan_at_a_free_column``."""
+    B, nq, nc = 2, 40, 64
+    w = np.zeros((B, lane_cover(nq, 8), lane_cover(nc, 8)), np.float32)
+    w[:, :nq, :nc] = 0.9
+    return w, np.full(B, nq, np.int32), np.full(B, nc, np.int32)
+
+
 def test_ties_end_each_scan_at_a_free_column():
     """Every edge of a block weighs the same, so each augmentation's
     distances tie across all columns: a scan that takes a free column
     among the ties ends at once, one scan per row, where taking the
     lowest-index column walks the matched ones first."""
-    B, nq, nc = 2, 40, 64
-    w = np.zeros((B, lane_cover(nq, 8), lane_cover(nc, 8)), np.float32)
-    w[:, :nq, :nc] = 0.9
-    so, _, steps = hungarian_batch(
-        jnp.asarray(w), jnp.full((B,), nq, jnp.int32),
-        jnp.full((B,), nc, jnp.int32))
+    w, nqs, ncs = _all_ties_block()
+    nq = int(nqs[0])
+    so, _, steps = hungarian_batch(jnp.asarray(w), jnp.asarray(nqs),
+                                   jnp.asarray(ncs))
     np.testing.assert_allclose(np.asarray(so), 0.9 * nq, rtol=1e-6)
     assert int(steps) == nq
+
+
+def _gather_dual_solve(w, nqs, ncs):
+    """The rectangle solver as it was before it recorded each row's entry
+    distance (kept here as the comparison): the dual update gathers
+    ``shortest`` through ``col4row``, and the loops read and write at
+    per-row indices.  Returns (SO (B,), col4row (B, R), steps) like
+    ``hungarian_batch``."""
+    from repro.core.matching.hungarian import _INF, _rect_cost
+
+    def solve(w, nq, nc):
+        cost = _rect_cost(w, nq, nc)
+        R, C = cost.shape
+        rows = jnp.arange(R)
+        has_edge = jnp.any(cost < 0, axis=1)
+
+        def augment(cur_row, carry):
+            u, v, row4col, col4row, scans = carry
+            active = (cur_row < nq) & has_edge[cur_row]
+
+            def scan_body(s):
+                shortest, path, SR, SC, sink, i, min_val, n = s
+                SR = SR.at[i].set(True)
+                d = min_val + cost[i, :] - u[i] - v
+                upd = (~SC) & (d < shortest)
+                shortest = jnp.where(upd, d, shortest)
+                path = jnp.where(upd, i, path)
+                masked = jnp.where(SC, _INF, shortest)
+                min_val = jnp.min(masked)
+                at_min = masked == min_val
+                free_min = at_min & (row4col < 0)
+                j = jnp.where(jnp.any(free_min), jnp.argmax(free_min),
+                              jnp.argmax(at_min)).astype(jnp.int32)
+                SC = SC.at[j].set(True)
+                free = row4col[j] < 0
+                return (shortest, path, SR, SC, jnp.where(free, j, -1),
+                        jnp.where(free, i, row4col[j]), min_val, n + 1)
+
+            init = (jnp.full((C,), _INF), jnp.full((C,), -1, jnp.int32),
+                    jnp.zeros((R,), bool), jnp.zeros((C,), bool),
+                    jnp.int32(-1), jnp.int32(cur_row), jnp.float32(0.0),
+                    jnp.int32(0))
+            shortest, path, SR, SC, sink, _, min_val, n = jax.lax.while_loop(
+                lambda s: (s[4] < 0) & active, scan_body, init)
+            u = u + jnp.where(SR, jnp.where(
+                rows == cur_row, min_val,
+                min_val - shortest[jnp.clip(col4row, 0, C - 1)]), 0.0)
+            v = v - jnp.where(SC, min_val - shortest, 0.0)
+
+            def aug_body(s):
+                row4col, col4row, j, _ = s
+                i = path[j]
+                return (row4col.at[j].set(i), col4row.at[i].set(j),
+                        col4row[i], i == cur_row)
+
+            row4col, col4row, _, _ = jax.lax.while_loop(
+                lambda s: ~s[3], aug_body, (row4col, col4row, sink, ~active))
+            return u, v, row4col, col4row, scans.at[cur_row].set(n)
+
+        carry = (jnp.zeros((R,), jnp.float32), jnp.zeros((C,), jnp.float32),
+                 jnp.full((C,), -1, jnp.int32), jnp.full((R,), -1, jnp.int32),
+                 jnp.zeros((R,), jnp.int32))
+        _, _, _, col4row, scans = jax.lax.fori_loop(0, nq, augment, carry)
+        total = jnp.sum(jnp.where(
+            col4row >= 0, cost[rows, jnp.clip(col4row, 0, C - 1)], 0.0))
+        return -total, col4row, scans
+
+    so, col4row, scans = jax.jit(jax.vmap(solve))(w, nqs, ncs)
+    return so, col4row, jnp.sum(jnp.max(scans, axis=0))
+
+
+def _dblp_block():
+    """Two DBLP-shaped blocks: 640 x 640 (the lane cover of a 514-token
+    set), 514 and 300 logical rows against 514 and 420 columns."""
+    rng = np.random.default_rng(18)
+    nqs, ncs = np.int32([514, 300]), np.int32([514, 420])
+    w = np.zeros((2, 640, 640), np.float32)
+    for b in range(2):
+        w[b, :nqs[b], :ncs[b]] = _random_weights(rng, nqs[b], ncs[b], 0.8)
+    return w, nqs, ncs
+
+
+@pytest.mark.parametrize(
+    "case", sorted(RECT_CASES) + ["all_ties", "dblp_640"])
+def test_entry_distance_dual_equals_the_gather(case):
+    """The dual update reads each reached row's entry distance, recorded
+    during the scan, where it gathered ``shortest[col4row]``, and the
+    loops index by masked reductions and selects: the solves agree bit
+    for bit, scores, matchings and lockstep scans."""
+    w, nqs, ncs = {"all_ties": _all_ties_block,
+                   "dblp_640": _dblp_block}.get(
+        case, lambda: _rect_block(case))()
+    args = jnp.asarray(w), jnp.asarray(nqs), jnp.asarray(ncs)
+    got = [np.asarray(x) for x in hungarian_batch(*args)]
+    was = [np.asarray(x) for x in _gather_dual_solve(*args)]
+    for g, e in zip(got, was):
+        assert g.dtype == e.dtype and g.shape == e.shape
+        np.testing.assert_array_equal(g, e)
+
+
+@pytest.fixture(scope="module")
+def solver_hlo():
+    sds = jax.ShapeDtypeStruct
+    return hungarian_batch.lower(
+        sds((4, 64, 64), jnp.float32), sds((4,), jnp.int32),
+        sds((4,), jnp.int32)).compile().as_text().splitlines()
+
+
+def test_the_dual_update_gathers_nothing(solver_hlo):
+    """No instruction of the compiled solver's dual update is a gather:
+    under ``vmap`` the gather through ``col4row`` was an element-wise
+    gather fusion that took 80% of a DBLP shard wave on the chip."""
+    dual = [ln for ln in solver_hlo if "solver.dual" in ln]
+    assert dual, "the solver.dual scope is missing from the compiled solver"
+    assert not [ln for ln in dual if "gather" in ln]
+
+
+@pytest.mark.parametrize("scope,banned", [
+    ("solver.scan", ("scatter",)),
+    ("solver.augment", ("gather", "scatter"))])
+def test_the_solver_loops_index_by_selects(solver_hlo, scope, banned):
+    """Under ``vmap`` every read or write at a per-row index is its own
+    gather or scatter op, with a copy of the array before each scatter:
+    the loops read with masked reductions and write with selects instead,
+    so each augmentation runs fewer, larger device ops.  The scan keeps
+    one gather, the cost row of the row it extends."""
+    ops = [ln for ln in solver_hlo if scope in ln]
+    assert ops, f"the {scope} scope is missing from the compiled solver"
+    assert not [ln for ln in ops if any(b in ln for b in banned)]
 
 
 @settings(max_examples=15, deadline=None)
